@@ -20,7 +20,14 @@ from ..p2p.types import CHANNEL_BLOCKSYNC, ChannelDescriptor, PEER_STATUS_UP, Pe
 from ..proto import messages as pb
 from ..types.block import Block, BlockID
 from ..types.validation import verify_commit_light, verify_commit_light_async
+from ..types.validator_set import NotEnoughVotingPowerError
 from .pool import BlockPool
+
+# What a commit that does not verify raises (types/validation.py). Only
+# these blame the peers that sent the blocks; anything else that escapes
+# verification — a JAX runtime error above all — is this node's own
+# fault and halts it through on_fatal.
+_VERDICT_ERRORS = (ValueError, NotEnoughVotingPowerError)
 
 
 # ------------------------------------------------------------------ messages
@@ -133,9 +140,10 @@ class BlockSyncReactor:
         """on_caught_up(state, blocks_synced) fires when the pool reaches
         the network head — the node switches to consensus
         (ref: reactor.go:370 SwitchToBlockSync / poolRoutine).
-        on_fatal(exc) fires when a VERIFIED block fails to apply — an
-        invariant violation the node must halt on, as the reference's
-        poolRoutine panic does."""
+        on_fatal(exc) fires when a VERIFIED block fails to apply, or
+        when verification itself fails with anything but a verdict (a
+        device compile or runtime error) — faults of this node that it
+        must halt on, as the reference's poolRoutine panic does."""
         self.state = state
         self.block_exec = block_executor
         self.block_store = block_store
@@ -276,8 +284,11 @@ class BlockSyncReactor:
             except Exception as exc:
                 # A verified block failing to apply is a store/app
                 # invariant violation — the reference panics here
-                # (reactor.go poolRoutine). Halt the node via on_fatal
-                # rather than dying silently and stalling half-alive.
+                # (reactor.go poolRoutine) — and a verification that
+                # raised something other than a verdict is a fault of
+                # this node's device plane. Halt the node via on_fatal
+                # rather than dying silently, stalling half-alive, or
+                # banning honest peers in a refetch loop.
                 import traceback
 
                 traceback.print_exc()
@@ -336,7 +347,7 @@ class BlockSyncReactor:
                         second.last_commit,
                     )
             self._dispatch_verify_ahead(second)
-        except Exception as e:
+        except _VERDICT_ERRORS as e:
             # Either sender could be lying (a forged second.LastCommit
             # fails an honest first block): ban BOTH and refetch both
             # heights (ref: reactor.go:592-604 errors both senders).
@@ -453,7 +464,7 @@ class BlockSyncReactor:
         # verification failures report before address/extension ones.
         try:
             complete_votes = verify_commit_async(chain_id, vals, first_id, height, commit)
-        except Exception as e:
+        except _VERDICT_ERRORS as e:
             return ValueError(f"extended commit votes failed verification: {e}")
         # Extension signatures (COMMIT slots only), batched likewise.
         votes = votes_from_extended_commit(ec)
@@ -485,22 +496,15 @@ class BlockSyncReactor:
                     pending_ext = None  # mixed key types: serial below
         try:
             complete_votes()
-        except Exception as e:
+        except _VERDICT_ERRORS as e:
             return ValueError(f"extended commit votes failed verification: {e}")
         if addr_err is not None:
             return addr_err
         if ext_jobs:
             if pending_ext is not None:
-                try:
-                    ok, _ = pending_ext()
-                except Exception:
-                    # Batch/engine failure (mixed key types at collect,
-                    # a dropped device tunnel, a coalesced group sunk by
-                    # another caller's job): the serial host chain is
-                    # authoritative and dependency-free. Escaping here
-                    # would halt the node via on_fatal for a fault that
-                    # only deserves a peer retry.
-                    ok = all(pk.verify_signature(msg, sig) for pk, msg, sig in ext_jobs)
+                # an engine or device failure here is not a verdict on
+                # the peer: it propagates and halts the node (on_fatal)
+                ok, _ = pending_ext()
             else:
                 ok = all(pk.verify_signature(msg, sig) for pk, msg, sig in ext_jobs)
             if not ok:

@@ -9,7 +9,9 @@ invisible, which is exactly how the BENCH_r02/r03 runs died
 undiagnosed. tmdev closes that gap with three feeds:
 
   compiles    a `jax.monitoring` duration listener captures every XLA
-              backend compile. jax's monitoring events carry NO
+              backend compile (a persistent-cache load fires the same
+              event, with the load time; the cache hit/miss events are
+              tallied per attributed fn beside it). jax's monitoring events carry NO
               metadata (no fn, no shape), so attribution comes from a
               thread-local context the ops dispatch sites set around
               their kernel calls (`attribution(fn=..., rows=...)`) —
@@ -103,6 +105,8 @@ _STATE = {
     # route reads a snapshot, never a live metrics object)
     "compiles": 0,
     "compile_seconds": 0.0,
+    # persistent-compile-cache traffic by attributed fn: {fn: {event: n}}
+    "cache_events": {},
     "transfers": {"h2d": 0, "d2h": 0},
     "transfer_bytes": {"h2d": 0, "d2h": 0},
     "residency_samples": 0,
@@ -224,6 +228,10 @@ def _on_event(event, **kw):  # defensive signature
         for suffix in _CACHE_EVENT_SUFFIXES:
             if name.endswith(suffix):
                 _metrics().compile_cache_events.add(1, suffix)
+                fn = str(current_attribution().get("fn") or "unattributed")
+                with _LOCK:
+                    by_fn = _STATE["cache_events"].setdefault(fn, {})
+                    by_fn[suffix] = by_fn.get(suffix, 0) + 1
                 return
     except Exception:  # noqa: BLE001
         pass
@@ -393,6 +401,7 @@ def status(tail: int = 32) -> dict:
             "enabled": True,
             "compiles": _STATE["compiles"],
             "compile_seconds": round(_STATE["compile_seconds"], 6),
+            "cache_events": {fn: dict(ev) for fn, ev in _STATE["cache_events"].items()},
             "transfers": dict(_STATE["transfers"]),
             "transfer_bytes": dict(_STATE["transfer_bytes"]),
             "residency_samples": _STATE["residency_samples"],

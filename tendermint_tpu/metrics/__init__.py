@@ -776,6 +776,10 @@ class EngineMetrics:
         self.autotuned = reg.gauge(
             f"{ns}_autotuned", "1 after the autotune microprobe updated a cutover"
         )
+        self.autotune_failures = reg.counter(
+            f"{ns}_autotune_failures_total",
+            "Autotune microprobes that raised (the default cutovers stayed in force)",
+        )
         self.host_pool_active = reg.gauge(
             f"{ns}_host_pool_active", "Host-plane verifies currently executing"
         )

@@ -1,4 +1,4 @@
-"""Native runtime components (C, built on demand with the system gcc).
+"""Native runtime components (C, built on demand with the system cc).
 
 `prep` — the batch-prep hot path feeding the TPU verify kernel
 (SHA-512 challenges + mod-L reduction + uint8 shaping), libcrypto EVP
@@ -6,6 +6,12 @@ host verify, and the batched SHA-256 / RFC-6962 merkle plane the block
 lifecycle hashes through. Loaded via ctypes from a .so compiled next to
 the source on first use; falls back to the pure-Python paths if no
 compiler is available.
+
+The artefact is named after a hash of prep.c, the compiler flags and
+the CPU the flags target (-march=native), so the library a process
+loads was built from the source it sits beside, for the machine it
+runs on: a .so that travelled with a copied tree from another machine,
+or was built from an older prep.c, is never loaded.
 
 `TM_TPU_NATIVE=0` (also `off`/`false`/`no`) disables the loader
 entirely — every caller takes its pure-Python fallback — for A/B runs
@@ -16,14 +22,17 @@ every load_prep() call so tests can flip it per-case.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import subprocess
 import sys
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "prep.c")
-_SO = os.path.join(_DIR, "prep.so")
+_CFLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-pthread")
 
 _lock = threading.Lock()
 _lib = None
@@ -57,20 +66,43 @@ def _warn_fallback_once(reason: str) -> None:
         pass
 
 
-def _build() -> bool:
-    tmp = _SO + ".tmp"
+def _cpu_identity() -> str:
+    """What -march=native compiles for: the architecture and the CPU's
+    feature flags."""
     try:
-        src_mtime = os.path.getmtime(_SRC)
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= src_mtime:
-            return True
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return platform.machine() + " " + line.strip()
+    except OSError:
+        pass
+    return platform.machine() + " " + platform.processor()
+
+
+def _artifact_path() -> str:
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CFLAGS).encode())
+    h.update(_cpu_identity().encode())
+    return os.path.join(_DIR, f"prep-{h.hexdigest()[:16]}.so")
+
+
+def _build() -> str | None:
+    """Path of the library built from prep.c as it is now, compiling it
+    unless that exact artefact already exists; None when there is no
+    working compiler."""
+    so = _artifact_path()
+    if os.path.exists(so):
+        return so
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
         subprocess.run(
-            ["cc", "-O3", "-march=native", "-shared", "-fPIC", "-pthread", "-o", tmp, _SRC],
-            check=True, capture_output=True,
+            ["cc", *_CFLAGS, "-o", tmp, _SRC], check=True, capture_output=True,
         )
-        os.replace(tmp, _SO)
-        return True
-    except Exception:
-        return False
+        os.replace(tmp, so)
+    except (OSError, subprocess.CalledProcessError):
+        return None
     finally:
         # a failed/killed cc leaves the partial .tmp behind; it is never
         # loaded (os.replace is atomic) but must not accumulate
@@ -79,6 +111,48 @@ def _build() -> bool:
                 os.remove(tmp)
             except OSError:
                 pass
+    for stale in glob.glob(os.path.join(_DIR, "prep*.so")):
+        if stale != so:  # older sources, other machines
+            try:
+                os.remove(stale)
+            except OSError:
+                pass
+    return so
+
+
+_u8p = ctypes.POINTER(ctypes.c_uint8)
+_i64p = ctypes.POINTER(ctypes.c_int64)
+_i64 = ctypes.c_int64
+_buf = ctypes.c_char_p
+
+# Every exported function: name -> (argtypes, restype). The library is
+# always built from the prep.c beside this file, so all of them exist.
+_SIGNATURES = {
+    # pks, sigs, msgs (concatenated), offsets, n, out_a, out_r, out_s, out_k, precheck
+    "prepare_batch": ([_buf, _buf, _buf, _i64p, _i64, _u8p, _u8p, _u8p, _u8p, _buf], None),
+    # z_raw (n*16), s_rows (n*32), k_rows (n*32), n, zk_out (n*32), zs_out (32)
+    "tm_rlc_scalars": ([_buf, _u8p, _u8p, _i64, _u8p, _u8p], None),
+    # pks (n*32), sigs (n*64), msgs (concatenated), offsets (n+1), n, out (n)
+    "tm_host_verify": ([_buf, _buf, _buf, _i64p, _i64, _u8p], ctypes.c_int),
+    # items (concatenated), offsets (n+1), n, out (n*32)
+    "tm_sha256_batch": ([_buf, _i64p, _i64, _u8p], None),
+    # items (concatenated), offsets (n+1), n, out (32)
+    "tm_merkle_root": ([_buf, _i64p, _i64, _u8p], None),
+    # enc (1) / dec (0), key (32), nonce (12), aad, aad_len, in, in_len, out
+    "tm_aead_chacha20poly1305": (
+        [ctypes.c_int, _buf, _buf, _buf, _i64, _buf, _i64, _u8p], _i64,
+    ),
+    # items, offsets (n+1), n, stride (max aunts per item), root_out (32),
+    # leaves_out (n*32), aunts_out (n*stride*32), counts_out (n)
+    "tm_merkle_proofs": (
+        [_buf, _i64p, _i64, _i64, _u8p, _u8p, _u8p, ctypes.POINTER(ctypes.c_int32)], None,
+    ),
+    # items, offsets (n+1), n, indices (k, sorted strictly ascending), k,
+    # root_out (32), leaves_out (k*32), nodes_out (k*ceil(log2 n)*32), n_nodes_out (1)
+    "tm_merkle_multiproof": (
+        [_buf, _i64p, _i64, _i64p, _i64, _u8p, _u8p, _u8p, _i64p], None,
+    ),
+}
 
 
 def load_prep():
@@ -93,117 +167,21 @@ def load_prep():
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not _build():
+        so = _build()
+        if so is None:
             _load_failed = True
             _warn_fallback_once("cc build failed or no compiler")
             return None
         try:
-            lib = ctypes.CDLL(_SO)
-            lib.prepare_batch.argtypes = [
-                ctypes.c_char_p,  # pks
-                ctypes.c_char_p,  # sigs
-                ctypes.c_char_p,  # msgs (concatenated)
-                ctypes.POINTER(ctypes.c_int64),  # offsets
-                ctypes.c_int64,  # n
-                ctypes.POINTER(ctypes.c_uint8),  # out_a
-                ctypes.POINTER(ctypes.c_uint8),  # out_r
-                ctypes.POINTER(ctypes.c_uint8),  # out_s
-                ctypes.POINTER(ctypes.c_uint8),  # out_k
-                ctypes.c_char_p,  # precheck
-            ]
-            lib.prepare_batch.restype = None
-            u8p = ctypes.POINTER(ctypes.c_uint8)
-            i64p = ctypes.POINTER(ctypes.c_int64)
-            # a stale .so may predate tm_rlc_scalars; its absence must
-            # degrade only the RLC path (msm.py falls back per-call),
-            # not poison the whole native prep load
-            if hasattr(lib, "tm_rlc_scalars"):
-                lib.tm_rlc_scalars.argtypes = [
-                    ctypes.c_char_p,  # z_raw (n*16)
-                    u8p,  # s_rows (n*32)
-                    u8p,  # k_rows (n*32)
-                    ctypes.c_int64,  # n
-                    u8p,  # zk_out (n*32)
-                    u8p,  # zs_out (32)
-                ]
-                lib.tm_rlc_scalars.restype = None
-            # a stale .so may predate tm_host_verify; absence degrades
-            # only the host-path batch verify (callers fall back to the
-            # per-signature Python chain)
-            if hasattr(lib, "tm_host_verify"):
-                lib.tm_host_verify.argtypes = [
-                    ctypes.c_char_p,  # pks (n*32)
-                    ctypes.c_char_p,  # sigs (n*64)
-                    ctypes.c_char_p,  # msgs (concatenated)
-                    i64p,  # offsets (n+1)
-                    ctypes.c_int64,  # n
-                    u8p,  # out (n)
-                ]
-                lib.tm_host_verify.restype = ctypes.c_int
-            # hash plane (absence degrades to crypto/merkle's iterative
-            # Python path, byte-identical)
-            if hasattr(lib, "tm_sha256_batch"):
-                lib.tm_sha256_batch.argtypes = [
-                    ctypes.c_char_p,  # items (concatenated)
-                    i64p,  # offsets (n+1)
-                    ctypes.c_int64,  # n
-                    u8p,  # out (n*32)
-                ]
-                lib.tm_sha256_batch.restype = None
-            if hasattr(lib, "tm_merkle_root"):
-                lib.tm_merkle_root.argtypes = [
-                    ctypes.c_char_p,  # items (concatenated)
-                    i64p,  # offsets (n+1)
-                    ctypes.c_int64,  # n
-                    u8p,  # out (32)
-                ]
-                lib.tm_merkle_root.restype = None
-            # libcrypto AEAD for the p2p secret connection (absence
-            # degrades to softcrypto's pure-Python ChaCha20-Poly1305)
-            if hasattr(lib, "tm_aead_chacha20poly1305"):
-                lib.tm_aead_chacha20poly1305.argtypes = [
-                    ctypes.c_int,  # enc (1) / dec (0)
-                    ctypes.c_char_p,  # key (32)
-                    ctypes.c_char_p,  # nonce (12)
-                    ctypes.c_char_p,  # aad
-                    ctypes.c_int64,  # aad_len
-                    ctypes.c_char_p,  # in
-                    ctypes.c_int64,  # in_len
-                    u8p,  # out
-                ]
-                lib.tm_aead_chacha20poly1305.restype = ctypes.c_int64
-            if hasattr(lib, "tm_merkle_proofs"):
-                lib.tm_merkle_proofs.argtypes = [
-                    ctypes.c_char_p,  # items (concatenated)
-                    i64p,  # offsets (n+1)
-                    ctypes.c_int64,  # n
-                    ctypes.c_int64,  # stride (max aunts per item)
-                    u8p,  # root_out (32)
-                    u8p,  # leaves_out (n*32)
-                    u8p,  # aunts_out (n*stride*32)
-                    ctypes.POINTER(ctypes.c_int32),  # counts_out (n)
-                ]
-                lib.tm_merkle_proofs.restype = None
-            # a stale .so may predate tm_merkle_multiproof (tmproof);
-            # absence degrades only the batched multiproof path to the
-            # level-iterative Python fallback, byte-identical
-            if hasattr(lib, "tm_merkle_multiproof"):
-                lib.tm_merkle_multiproof.argtypes = [
-                    ctypes.c_char_p,  # items (concatenated)
-                    i64p,  # offsets (n+1)
-                    ctypes.c_int64,  # n
-                    i64p,  # indices (k, sorted strictly ascending)
-                    ctypes.c_int64,  # k
-                    u8p,  # root_out (32)
-                    u8p,  # leaves_out (k*32)
-                    u8p,  # nodes_out (k*ceil(log2 n)*32)
-                    i64p,  # n_nodes_out (1)
-                ]
-                lib.tm_merkle_multiproof.restype = None
+            lib = ctypes.CDLL(so)
+            for name, (argtypes, restype) in _SIGNATURES.items():
+                fn = getattr(lib, name)
+                fn.argtypes = argtypes
+                fn.restype = restype
             _lib = lib
-        except Exception:
+        except (OSError, AttributeError) as exc:
             _load_failed = True
-            _warn_fallback_once("ctypes load failed")
+            _warn_fallback_once(f"ctypes load failed: {exc}")
     return _lib
 
 
@@ -225,7 +203,7 @@ def sha256_batch(items) -> list[bytes] | None:
     across cores inside C for large totals), or None when the native
     library is unavailable (callers take the hashlib loop)."""
     lib = load_prep()
-    if lib is None or not hasattr(lib, "tm_sha256_batch"):
+    if lib is None:
         return None
     import numpy as np
 
@@ -247,7 +225,7 @@ def sha256_batch(items) -> list[bytes] | None:
 def merkle_root(items) -> bytes | None:
     """RFC-6962 merkle root in one native call, or None (fallback)."""
     lib = load_prep()
-    if lib is None or not hasattr(lib, "tm_merkle_root"):
+    if lib is None:
         return None
     n = len(items)
     blob, offsets = _concat_offsets(items)
@@ -266,7 +244,7 @@ def merkle_proofs(items) -> tuple[bytes, list[bytes], list[list[bytes]]] | None:
     call, or None (fallback). Requires len(items) >= 1 — the n == 0
     shape (empty root, no proofs) is trivial in Python."""
     lib = load_prep()
-    if lib is None or not hasattr(lib, "tm_merkle_proofs"):
+    if lib is None:
         return None
     import numpy as np
 
@@ -310,7 +288,7 @@ def merkle_multiproof(items, indices) -> tuple[bytes, list[bytes], list[bytes]] 
     dispatching here); this wrapper only refuses the trivial shapes the
     C side does not handle (n == 0, k == 0)."""
     lib = load_prep()
-    if lib is None or not hasattr(lib, "tm_merkle_multiproof"):
+    if lib is None:
         return None
     import numpy as np
 
@@ -367,7 +345,7 @@ def host_verify_batch(pubkeys, msgs, sigs):
     ):
         return None
     lib = load_prep()
-    if lib is None or not hasattr(lib, "tm_host_verify"):
+    if lib is None:
         return None
 
     offsets = np.zeros(n + 1, np.int64)
@@ -395,7 +373,7 @@ def aead_chacha20poly1305(enc: bool, key: bytes, nonce: bytes,
     fallback condition (retrying the same bytes in Python would just
     burn CPU re-reaching the same answer)."""
     lib = load_prep()
-    if lib is None or not hasattr(lib, "tm_aead_chacha20poly1305"):
+    if lib is None:
         return None
     out = ctypes.create_string_buffer(len(data) + 16)  # seal grows, open shrinks
     rc = lib.tm_aead_chacha20poly1305(

@@ -25,9 +25,9 @@ arxiv 2112.02229). This module is that pipeline:
                  re-checking only rows OpenSSL rejects — byte-identical
                  acceptance to the serial path.
   autotune     — DEVICE_BATCH_CUTOVER / MSM_BATCH_CUTOVER come from a
-                 one-shot startup microprobe of real launch latency vs
-                 host verify rate when an accelerator is present,
-                 instead of hardcoded constants (env still wins).
+                 one-shot microprobe of real launch latency vs host
+                 verify rate when an accelerator is present, finished
+                 before the first batch is routed (env still wins).
 
 Gating: TM_TPU_ENGINE = auto (default, engine on) | on | off. `off`
 restores the direct per-caller dispatch paths; acceptance is
@@ -74,76 +74,81 @@ def _autotune_enabled() -> bool:
 
 
 def maybe_autotune() -> None:
-    """One-shot cutover microprobe. When a real accelerator is present
-    and the env didn't pin TM_TPU_BATCH_CUTOVER / TM_TPU_MSM_CUTOVER,
+    """One-shot cutover microprobe, finished before the first batch is
+    routed. When the device plane is in use on a real accelerator and
+    the env didn't pin TM_TPU_BATCH_CUTOVER / TM_TPU_MSM_CUTOVER,
     measure (a) the host per-signature verify time and (b) the warm
     end-to-end latency of a tiny device launch, and set the cutovers to
-    the batch size where the device launch actually pays for itself —
-    the hardcoded 64/256 were calibrated on one chip generation and are
-    wrong on both faster tunnels and slower hosts. The probe runs in a
-    DAEMON thread (the tiny launch may compile on a fresh cache, and no
-    caller should stall behind that); the defaults stay in effect until
-    it lands. No accelerator (or TM_TPU_AUTOTUNE=off) leaves the
-    defaults untouched, so CPU test runs stay deterministic."""
+    the batch size where the device launch actually pays for itself.
+    The probe runs under the lock on the first router to arrive — the
+    engine's dispatch worker, or a direct-dispatch caller — and every
+    other router waits for it, so no batch is routed while the
+    cutovers change; callers of VerifyEngine.submit never wait. The
+    tiny launch may compile on a fresh cache; the first routed batch
+    pays that once, beside its own program's compile. A failed probe is
+    logged and counted (engine_autotune_failures_total) and leaves the
+    defaults in force. No accelerator (or TM_TPU_AUTOTUNE=off) leaves
+    the defaults untouched, so CPU test runs stay deterministic."""
     if _AUTOTUNE["done"]:
         return
     with _AUTOTUNE_LOCK:
         if _AUTOTUNE["done"]:
             return
-        _AUTOTUNE["done"] = True
-        if not _autotune_enabled():
-            return
-        dev_pinned = "TM_TPU_BATCH_CUTOVER" in os.environ
-        msm_pinned = "TM_TPU_MSM_CUTOVER" in os.environ
-        if dev_pinned and msm_pinned:
-            return
-        t = threading.Thread(
-            target=_autotune_probe, args=(dev_pinned, msm_pinned),
-            daemon=True, name="tm-engine-autotune",
-        )
-        t.start()
+        try:
+            dev_pinned = "TM_TPU_BATCH_CUTOVER" in os.environ
+            msm_pinned = "TM_TPU_MSM_CUTOVER" in os.environ
+            if _autotune_enabled() and not (dev_pinned and msm_pinned):
+                _autotune_probe(dev_pinned, msm_pinned)
+        except Exception as exc:  # noqa: BLE001 - report, keep the defaults
+            import traceback
+
+            from ..utils.log import new_logger
+
+            _engine_metrics().autotune_failures.add(1)
+            new_logger("engine").error(
+                "autotune probe failed; default cutovers stay in force",
+                err=f"{type(exc).__name__}: {exc}",
+                traceback=traceback.format_exc(),
+            )
+        finally:
+            _AUTOTUNE["done"] = True
 
 
 def _autotune_probe(dev_pinned: bool, msm_pinned: bool) -> None:
-    try:
-        from ..crypto import ed25519 as ed
+    from ..crypto import ed25519 as ed
 
-        if not ed._accelerator_present():
-            return
-        import time
+    if not (ed._use_device() and ed._accelerator_present()):
+        return
+    from ..crypto import ed25519_ref as ref
+    from . import verify as V
 
-        from ..crypto import ed25519_ref as ref
-        from . import verify as V
-
-        sk = ref.gen_privkey(b"\x5a" * 32)
-        pk, msg = sk[32:], b"tm-engine-autotune-probe"
-        sig = ref.sign(sk, msg)
-        t0 = time.perf_counter()
-        for _ in range(16):
-            ed._single_verify(pk, msg, sig)
-        t_host = (time.perf_counter() - t0) / 16
-        jobs = ([pk] * 8, [msg] * 8, [sig] * 8)
-        V.verify_batch(*jobs)  # compile + warm
-        t0 = time.perf_counter()
-        for _ in range(3):
-            V.verify_batch(*jobs)
-        t_launch = (time.perf_counter() - t0) / 3
-        cutover = 8
-        while cutover * t_host < t_launch and cutover < 4096:
-            cutover *= 2
-        if not dev_pinned:
-            ed.DEVICE_BATCH_CUTOVER = cutover
-        if not msm_pinned:
-            # the MSM's Horner/reduce tail is a roughly constant extra
-            # launch cost; it amortizes ~4x past the point a plain
-            # launch does
-            ed.MSM_BATCH_CUTOVER = max(64, min(4 * cutover, 8192))
-        m = _engine_metrics()
-        m.autotuned.set(1)
-        m.device_batch_cutover.set(ed.DEVICE_BATCH_CUTOVER)
-        m.msm_batch_cutover.set(ed.MSM_BATCH_CUTOVER)
-    except Exception:  # noqa: BLE001 - a failed probe keeps the defaults
-        pass
+    sk = ref.gen_privkey(b"\x5a" * 32)
+    pk, msg = sk[32:], b"tm-engine-autotune-probe"
+    sig = ref.sign(sk, msg)
+    t0 = _time.perf_counter()
+    for _ in range(16):
+        ed._single_verify(pk, msg, sig)
+    t_host = (_time.perf_counter() - t0) / 16
+    jobs = ([pk] * 8, [msg] * 8, [sig] * 8)
+    V.verify_batch(*jobs)  # compile + warm
+    t0 = _time.perf_counter()
+    for _ in range(3):
+        V.verify_batch(*jobs)
+    t_launch = (_time.perf_counter() - t0) / 3
+    cutover = 8
+    while cutover * t_host < t_launch and cutover < 4096:
+        cutover *= 2
+    if not dev_pinned:
+        ed.DEVICE_BATCH_CUTOVER = cutover
+    if not msm_pinned:
+        # the MSM's Horner/reduce tail is a roughly constant extra
+        # launch cost; it amortizes ~4x past the point a plain
+        # launch does
+        ed.MSM_BATCH_CUTOVER = max(64, min(4 * cutover, 8192))
+    m = _engine_metrics()
+    m.autotuned.set(1)
+    m.device_batch_cutover.set(ed.DEVICE_BATCH_CUTOVER)
+    m.msm_batch_cutover.set(ed.MSM_BATCH_CUTOVER)
 
 
 # ------------------------------------------------------------------- engine
@@ -344,7 +349,6 @@ class VerifyEngine:
             job.result = []
             job.event.set()
             return JobHandle(job)
-        maybe_autotune()
         self._ensure_started()
         job.t_submit = _time.monotonic()
         if _trace.enabled():
@@ -412,6 +416,7 @@ class VerifyEngine:
                 sp.annotate(journeys=journeys)
             try:
                 with sp:
+                    maybe_autotune()  # no-op after the first group
                     thunk, path = self._dispatch_group(group, seq)
                     sp.annotate(path=path)
             except BaseException as e:  # noqa: BLE001 - deliver, don't die

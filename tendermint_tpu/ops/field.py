@@ -40,6 +40,12 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 
+from . import enable_compile_cache
+
+# Every kernel module imports this one first, so this is where a process
+# that will compile a curve program gets its persistent compile cache.
+enable_compile_cache()
+
 LIMBS = 32
 
 P_INT = 2**255 - 19
@@ -129,13 +135,13 @@ for _i in range(LIMBS):
             _FOLD[_i * LIMBS + _j, _k - LIMBS] = 38
 del _i, _j, _k
 
-# Default is the slice formulation, decided by the on-chip A/B
-# (2026-07-31, TPU v5 lite): slice 53.6k sigs/s @256 / 73.6k @1024
-# device-only vs dot's measured ~34k ceiling — the dot form's int32
-# contraction cannot use the MXU (a bf16/int8 engine) and lowers to
-# ~32x more VPU work. Slice also compiles safely on TPU since the r4
-# graph work (41k StableHLO lines @256, 74s compile). TM_TPU_FE_MUL=dot
-# keeps the compact-graph fallback selectable.
+# Default is the slice formulation: the dot form's int32 contraction
+# cannot use the MXU (a bf16/int8 engine) and lowers to ~32x more VPU
+# multiply-accumulates. On-chip rate of either form: not measured;
+# compile seconds per program are in PERF.md (chip_smoke readings).
+# XLA:CPU executes the slice form's Toeplitz slices pathologically, so
+# the tests pin TM_TPU_FE_MUL=dot (tests/conftest.py); the two forms are
+# bit-identical (tests/test_field.py).
 _FE_MUL_MODE = os.environ.get("TM_TPU_FE_MUL", "slice")
 
 
@@ -144,9 +150,9 @@ def _fe_mul_dot(x, y):
     to (1024, batch) contracted with the constant (1024, 32) fold matrix
     — a single int32 dot per field mul. NB the MXU is a bf16/int8
     engine, so this int32 contraction still executes on the VPU with
-    ~32x the slice form's MAC count (measured ~34k vs 53-74k sigs/s on
-    chip); its value is the compact graph (23.6k vs 41k StableHLO
-    lines), which compiles ~2x faster. Same bounds as the slice form."""
+    ~32x the slice form's MAC count (on-chip rate: not measured); its
+    value is the compact graph (23.6k vs 41k StableHLO lines at batch
+    256). Same bounds as the slice form."""
     rank = max(x.ndim, y.ndim) - 1
     x = _with_batch_rank(x, rank)
     y = _with_batch_rank(y, rank)

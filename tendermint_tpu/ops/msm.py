@@ -307,7 +307,7 @@ def _rlc_scalars(s_rows, k_rows, n, z_raw):
     from ..native import load_prep
 
     lib = load_prep()
-    if lib is None or not hasattr(lib, "tm_rlc_scalars"):
+    if lib is None:
         return _rlc_scalars_py(s_rows, k_rows, n, z_raw)
     import ctypes
 

@@ -12,8 +12,9 @@ full array, but in multi-controller each process holds only its local
 shard and must assemble the global array with
 `jax.make_array_from_process_local_data`. This module provides that
 path; on a single controller it degenerates to the plain sharded call,
-which is how it is tested in-container (the driver validates the
-single-host mesh separately via __graft_entry__.dryrun_multichip).
+which is how it is tested in-container (the single-host mesh is
+checked on a virtual CPU mesh by tests/test_batch_verify.py and on
+four real chips by chip_smoke.py's sharded-4 phase).
 """
 
 from __future__ import annotations

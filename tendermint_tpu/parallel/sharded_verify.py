@@ -15,9 +15,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
+from .. import devobs as _devobs
 from .. import trace as _trace
 from ..metrics import engine_metrics as _engine_metrics
 from ..ops import verify as V
@@ -51,6 +52,13 @@ def _local_verify_with(kernel_impl):
 
 
 _FN_CACHE: dict[tuple, object] = {}
+
+
+def _placement(arrays) -> list[list[int]]:
+    """Device ids holding a shard (or replica) of each array, for the
+    sharded.verify span: the trace shows where every input and the
+    bitmap actually lived."""
+    return [sorted(sh.device.id for sh in x.addressable_shards) for x in arrays]
 
 _SCALAR_POOL = None
 _SCALAR_POOL_LOCK = threading.Lock()
@@ -156,7 +164,7 @@ def verify_batch_sharded_cached(mesh: Mesh, pubkeys, msgs, sigs, key_type: str =
         return verify_batch_sharded(mesh, pubkeys, msgs, sigs, key_type)
     _engine_metrics().sharded_launches.add(1, "cached")
     with _trace.span("sharded.verify", "parallel", path="cached",
-                     rows=n, shards=mesh.devices.size):
+                     rows=n, shards=mesh.devices.size) as sp:
         _, r_enc, s_bytes, k_bytes, precheck = plane.prepare_batch(pubkeys, msgs, sigs)
         n_dev = mesh.devices.size
         per_dev = -(-n // n_dev)
@@ -179,15 +187,20 @@ def verify_batch_sharded_cached(mesh: Mesh, pubkeys, msgs, sigs, key_type: str =
         fn = sharded_cached_verify_fn(mesh, kern)
         shard = NamedSharding(mesh, P(AXIS))
         repl = NamedSharding(mesh, P())
+        # host arrays go straight to their shards: jnp.asarray first
+        # would commit the whole batch to device 0 and copy from there
         args = [
             jax.device_put(tables, repl),
             jax.device_put(oks, repl),
-            jax.device_put(jnp.asarray(slots), shard),
-            jax.device_put(jnp.asarray(r_enc), shard),
-            jax.device_put(jnp.asarray(s_bytes), shard),
-            jax.device_put(jnp.asarray(k_bytes), shard),
+            jax.device_put(slots, shard),
+            jax.device_put(r_enc, shard),
+            jax.device_put(s_bytes, shard),
+            jax.device_put(k_bytes, shard),
         ]
-        bitmap, device_all_valid = fn(*args)
+        with _devobs.attribution(fn=f"{key_type}_sharded_cached", rows=per_dev):
+            bitmap, device_all_valid = fn(*args)
+        if _trace.enabled():
+            sp.annotate(placement=_placement([*args, bitmap]))
         bitmap = np.asarray(bitmap)[:n] & precheck
         return bitmap, bool(device_all_valid) and bool(precheck.all())
 
@@ -277,13 +290,13 @@ def verify_batch_sharded_rlc(mesh: Mesh, pubkeys, msgs, sigs, z_raw: bytes | Non
         r_enc = np.pad(r_enc, ((0, pad), (0, 0)))
     fn = sharded_rlc_fn(mesh)
     sharding = NamedSharding(mesh, P(AXIS))
-    args = [
-        jax.device_put(jnp.asarray(x), sharding)
-        for x in (a_enc, r_enc, zk, z_rows, zs_shards)
-    ]
+    args = [jax.device_put(x, sharding) for x in (a_enc, r_enc, zk, z_rows, zs_shards)]
     with _trace.span("sharded.verify", "parallel", path="rlc",
-                     rows=n, shards=n_dev):
-        return bool(fn(*args))
+                     rows=n, shards=n_dev) as sp:
+        if _trace.enabled():
+            sp.annotate(placement=_placement(args))
+        with _devobs.attribution(fn="ed25519_sharded_rlc", rows=per_dev):
+            return bool(fn(*args))
 
 
 def verify_batch_sharded(mesh: Mesh, pubkeys, msgs, sigs, key_type: str = "ed25519"):
@@ -321,10 +334,13 @@ def verify_batch_sharded(mesh: Mesh, pubkeys, msgs, sigs, key_type: str = "ed255
         k_bytes = np.pad(k_bytes, ((0, pad), (0, 0)))
     fn = sharded_verify_fn(mesh, kernel_impl)
     sharding = NamedSharding(mesh, P(AXIS))
-    args = [jax.device_put(jnp.asarray(x), sharding) for x in (a_enc, r_enc, s_bytes, k_bytes)]
+    args = [jax.device_put(x, sharding) for x in (a_enc, r_enc, s_bytes, k_bytes)]
     with _trace.span("sharded.verify", "parallel", path="bitmap",
-                     rows=n, shards=n_dev):
-        bitmap, device_all_valid = fn(*args)
+                     rows=n, shards=n_dev) as sp:
+        with _devobs.attribution(fn=f"{key_type}_sharded_bitmap", rows=per_dev):
+            bitmap, device_all_valid = fn(*args)
+        if _trace.enabled():
+            sp.annotate(placement=_placement([*args, bitmap]))
     bitmap = np.asarray(bitmap)[:n] & precheck
     # The ICI-reduced verdict covers device checks (padded rows verify
     # true by construction); AND with the host prechecks for the final

@@ -5,6 +5,8 @@ from __future__ import annotations
 import threading
 import time
 
+import pytest
+
 from helpers import make_genesis_doc, make_keys
 from test_consensus import fast_params, make_node, wait_for_height
 from tendermint_tpu.blocksync import BlockSyncReactor, blocksync_channel_descriptor
@@ -324,6 +326,70 @@ def test_blocksync_verify_ahead_detects_tampering():
     assert errors and errors[0].node_id == peer
 
 
+def test_blocksync_device_failure_is_fatal_not_a_lying_peer(monkeypatch):
+    """Only a verification verdict blames the peers. Anything else that
+    escapes commit verification — a JAX runtime error above all — is
+    this node's own fault: it goes to on_fatal, bans nobody and refetches
+    nothing (it used to ban both senders and refetch, forever)."""
+    from tendermint_tpu.blocksync import fixture
+    from tendermint_tpu.blocksync import reactor as reactor_mod
+
+    chain = fixture.build_chain(5, 4, 3)
+    state, executor, _, block_store = fixture._executor(chain.gen_doc)
+    errors, fatal = [], []
+
+    class _Chan:
+        def send_to(self, *a, **k):
+            return True
+
+        def send_error(self, e):
+            errors.append(e)
+
+    class _PM:
+        def subscribe(self, cb):
+            pass
+
+        def unsubscribe(self, cb):
+            pass
+
+    reactor = BlockSyncReactor(state, executor, block_store, _Chan(), _PM(),
+                               on_fatal=fatal.append)
+    peer = "cc" * 20
+    reactor.pool.set_peer_range(peer, 1, 3)
+    reactor.pool._fill_requests()
+    for h in (1, 2, 3):
+        reactor.pool.add_block(peer, chain.block_store.load_block(h))
+
+    class XlaRuntimeError(RuntimeError):
+        pass
+
+    def broken_device(*a, **k):
+        raise XlaRuntimeError("RESOURCE_EXHAUSTED: out of memory while compiling")
+
+    monkeypatch.setattr(reactor_mod, "verify_commit_light", broken_device)
+    reactor._pool_routine()  # returns by itself: the fatal path ends the loop
+    assert len(fatal) == 1 and isinstance(fatal[0], XlaRuntimeError)
+    assert reactor.sync_error is True
+    assert errors == [] and peer in reactor.pool.peers
+    assert reactor.pool.height == 1 and block_store.height() == 0
+
+    # the same failure arriving through the verify-ahead completion
+    monkeypatch.undo()
+    fatal.clear()
+    reactor = BlockSyncReactor(state, executor, block_store, _Chan(), _PM(),
+                               on_fatal=fatal.append)
+    reactor.pool.set_peer_range(peer, 1, 3)
+    reactor.pool._fill_requests()
+    for h in (1, 2, 3):
+        reactor.pool.add_block(peer, chain.block_store.load_block(h))
+    monkeypatch.setattr(reactor_mod, "verify_commit_light_async",
+                        lambda *a, **k: broken_device)
+    assert reactor._try_sync_one() is True  # height 1 verifies; 2 is dispatched ahead
+    reactor._pool_routine()
+    assert len(fatal) == 1 and isinstance(fatal[0], XlaRuntimeError)
+    assert errors == [] and peer in reactor.pool.peers and block_store.height() == 1
+
+
 def test_blocksync_carries_extended_commits():
     """Blocks synced through extension-enabled heights arrive with their
     ExtendedCommit and the syncing node persists it, so it can itself
@@ -462,6 +528,20 @@ def test_validate_ext_commit_cryptographic():
         object(), e, height, block_id, vset, chain_id
     )
     assert check(ec) is None  # honest EC verifies
+
+    # an engine/device failure while collecting is not a verdict on the
+    # peer: it propagates (the pool routine hands it to on_fatal)
+    from tendermint_tpu.crypto.ed25519 import Ed25519BatchVerifier
+
+    def sunk(self):
+        def complete():
+            raise RuntimeError("UNAVAILABLE: TPU backend setup/compile error")
+        return complete
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(Ed25519BatchVerifier, "verify_async", sunk)
+        with pytest.raises(RuntimeError, match="UNAVAILABLE"):
+            check(ec)
 
     import copy
 

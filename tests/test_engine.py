@@ -14,6 +14,7 @@ between TPU windows.
 import os
 import sys
 import threading
+import time
 
 import pytest
 
@@ -241,6 +242,75 @@ def test_autotune_off_env_disables_probe(monkeypatch):
     monkeypatch.setattr(ed, "_accelerator_present", lambda: calls.append(1) or True)
     E.maybe_autotune()
     assert not calls  # off: never even probes for an accelerator
+
+
+def _unpinned_probe(monkeypatch):
+    """A process with a (faked) accelerator, no cutover pinned, and the
+    probe not yet run; monkeypatch restores the cutovers afterwards."""
+    monkeypatch.setitem(E._AUTOTUNE, "done", False)
+    for var in ("TM_TPU_AUTOTUNE", "TM_TPU_BATCH_CUTOVER", "TM_TPU_MSM_CUTOVER"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(ed, "_accelerator_present", lambda: True)
+    monkeypatch.setattr(ed, "DEVICE_BATCH_CUTOVER", 64)
+    monkeypatch.setattr(ed, "MSM_BATCH_CUTOVER", 256)
+
+
+def test_autotune_probe_failure_is_counted_not_swallowed(monkeypatch):
+    """A probe launch that fails on the device leaves the defaults in
+    force, once, and says so: logged and counted."""
+    from tendermint_tpu.metrics import engine_metrics
+    from tendermint_tpu.ops import verify as V
+
+    _unpinned_probe(monkeypatch)
+
+    def unavailable(*a):
+        raise RuntimeError("UNAVAILABLE: TPU backend setup/compile error")
+
+    monkeypatch.setattr(V, "verify_batch", unavailable)
+    failures = _counter_value(engine_metrics().autotune_failures)
+    E.maybe_autotune()
+    assert (ed.DEVICE_BATCH_CUTOVER, ed.MSM_BATCH_CUTOVER) == (64, 256)
+    assert _counter_value(engine_metrics().autotune_failures) == failures + 1
+    assert E._AUTOTUNE["done"] is True
+    E.maybe_autotune()  # one shot: no second attempt
+    assert _counter_value(engine_metrics().autotune_failures) == failures + 1
+
+
+def test_autotune_finishes_before_the_first_batch_is_routed(monkeypatch):
+    """The probe runs on the dispatch worker ahead of the first group:
+    submit() does not wait for it, and no batch is routed under
+    cutovers that are about to change."""
+    import types
+
+    from tendermint_tpu.ops import verify as V
+
+    _unpinned_probe(monkeypatch)
+    clock = [0.0]
+
+    def tick(dt):
+        def fake(*a, **k):
+            clock[0] += dt
+            return True
+        return fake
+
+    # 1 time unit per host verify, 20 per tiny launch: the launch pays
+    # for itself at 32 rows (8, 16 < 20 <= 32), MSM at 4x that
+    monkeypatch.setattr(ed, "_single_verify", tick(1.0))
+    monkeypatch.setattr(V, "verify_batch", tick(20.0))
+    monkeypatch.setattr(E, "_time", types.SimpleNamespace(
+        perf_counter=lambda: clock[0], monotonic=time.monotonic))
+    seen = []
+    real = E.VerifyEngine._dispatch_group
+
+    def spy(self, group, seq=0):
+        seen.append((ed.DEVICE_BATCH_CUTOVER, ed.MSM_BATCH_CUTOVER, E._AUTOTUNE["done"]))
+        return real(self, group, seq)
+
+    monkeypatch.setattr(E.VerifyEngine, "_dispatch_group", spy)
+    monkeypatch.setitem(E._HOST_VERIFY, "ed25519", lambda pks, msgs, sigs: [True] * len(sigs))
+    handle = E.get_engine().submit("ed25519", [b"k" * 32] * 2, [b"m"] * 2, [b"s" * 64] * 2)
+    assert handle.result(timeout=60) == [True, True]
+    assert seen == [(32, 128, True)]
 
 
 # ------------------------------------------- ADVICE r5 regression pins

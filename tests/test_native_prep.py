@@ -3,6 +3,8 @@ mod-L + shaping) must agree bit-for-bit with the Python oracle."""
 
 from __future__ import annotations
 
+import os
+
 import numpy as np
 import pytest
 
@@ -139,3 +141,39 @@ def test_native_rlc_scalars_matches_python_oracle():
     assert (zk_n == zk_p).all()
     assert (z_n == z_p).all()
     assert (zs_n == zs_p).all()
+
+
+def test_loader_builds_from_the_source_it_sits_beside(tmp_path, monkeypatch):
+    """The artefact is keyed on prep.c, the flags and the CPU: a library
+    that travelled in from elsewhere (the old prep.so name, or another
+    key) is never loaded, and a changed prep.c is rebuilt."""
+    import shutil
+
+    from tendermint_tpu import native
+
+    src = tmp_path / "prep.c"
+    shutil.copy(native._SRC, src)
+    foreign = [tmp_path / "prep.so", tmp_path / "prep-0123456789abcdef.so"]
+    for f in foreign:
+        f.write_bytes(b"\x7fELF built for some other machine")
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_load_failed", False)
+
+    first = native._artifact_path()
+    assert os.path.dirname(first) == str(tmp_path) and not os.path.exists(first)
+    lib1 = native.load_prep()
+    assert lib1 is not None and lib1._name == first
+    assert all(hasattr(lib1, name) for name in native._SIGNATURES)
+    assert not any(f.exists() for f in foreign)  # swept, never opened
+
+    with open(src, "a") as f:
+        f.write("\n/* edited */\n")
+    second = native._artifact_path()
+    assert second != first
+    monkeypatch.setattr(native, "_lib", None)
+    lib2 = native.load_prep()
+    assert lib2 is not None and lib2._name == second
+    assert os.path.exists(second) and not os.path.exists(first)
+    assert native.sha256_batch([b"abc"])[0].hex().startswith("ba7816bf")
