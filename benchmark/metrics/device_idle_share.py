@@ -1,0 +1,8 @@
+"""Device: 1 - the union of the device's operation intervals over the
+traced slice."""
+
+
+def read(ctx):
+    if ctx["device"] is None:
+        return None
+    return 100.0 * (1.0 - ctx["device"]["busy_s"] / ctx["device"]["window_s"])
