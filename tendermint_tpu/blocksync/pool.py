@@ -14,6 +14,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+from .. import trace as _trace
+
 REQUEST_INTERVAL = 0.01  # pool.go requestIntervalMS = 2ms
 MAX_PENDING_REQUESTS_PER_PEER = 20  # pool.go maxPendingRequestsPerPeer
 MAX_TOTAL_REQUESTERS = 600  # pool.go maxTotalRequesters
@@ -63,6 +65,7 @@ class BlockPool:
         self.last_sync_rate = 0.0
         self.settle_seconds = STATUS_SETTLE_SECONDS
         self._started_at = time.monotonic()
+        self._settle_traced = False  # blocksync.settle is emitted once a start
 
     def reanchor(self, height: int) -> None:
         """Move the next-height cursor after a handshake replay or a
@@ -84,6 +87,7 @@ class BlockPool:
 
     def start(self) -> None:
         self._started_at = time.monotonic()
+        self._settle_traced = False
         self._stop.clear()
         self._thread = threading.Thread(target=self._make_requests_routine, daemon=True, name="blockpool")
         self._thread.start()
@@ -219,8 +223,15 @@ class BlockPool:
         with self._lock:
             if not self.peers:
                 return False
-            if time.monotonic() - self._started_at < self.settle_seconds:
+            waited = time.monotonic() - self._started_at
+            if waited < self.settle_seconds:
                 return False
+            if not self._settle_traced:
+                # the window, in hindsight: from the start to its end
+                self._settle_traced = True
+                _trace.complete("blocksync.settle", "blocksync",
+                                _trace.now_us() - waited * 1e6, self.settle_seconds * 1e6,
+                                seconds=self.settle_seconds)
             return self.height >= self.max_peer_height
 
     def status(self) -> tuple[int, int, float]:

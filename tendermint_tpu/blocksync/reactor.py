@@ -257,6 +257,7 @@ class BlockSyncReactor:
     def _pool_routine(self) -> None:
         """The verify loop (ref: reactor.go:477 poolRoutine)."""
         last_switch_check = 0.0
+        starved_us, polls = None, 0  # since when the loop has had no block to apply
         while not self._stop.is_set():
             now = time.monotonic()
             if now - last_switch_check > self.SWITCH_CHECK_INTERVAL:
@@ -296,8 +297,17 @@ class BlockSyncReactor:
                 self.pool.stop()
                 self.on_fatal(exc)
                 return
-            if not advanced:
-                time.sleep(0.01)
+            if advanced:
+                if starved_us is not None:
+                    _trace.complete("blocksync.starved", "blocksync", starved_us,
+                                    _trace.now_us() - starved_us, polls=polls)
+                    starved_us, polls = None, 0
+                continue
+            if _trace.enabled():
+                if starved_us is None:
+                    starved_us = _trace.now_us()
+                polls += 1
+            time.sleep(0.01)
 
     def _can_switch_to_consensus(self) -> bool:
         """ref: reactor.go:485-507: when vote extensions were enabled at
@@ -314,10 +324,20 @@ class BlockSyncReactor:
         return self.block_store.load_extended_commit_proto(h) is not None
 
     def _try_sync_one(self) -> bool:
+        """One block, everything on the reactor's thread, under one
+        span: the root of what runs below it. A poll that found nothing
+        is a span of microseconds with applied false."""
+        with _trace.span("blocksync.try_sync", "blocksync") as sp:
+            applied = self._sync_one()
+            sp.annotate(applied=applied)
+        return applied
+
+    def _sync_one(self) -> bool:
         """ref: reactor.go:536-616 (the trySync block)."""
         first, second = self.pool.peek_two_blocks()
         if first is None or second is None:
             return False
+        _trace.annotate(height=first.header.height)
         first_parts = None
         try:
             # ★ the north-star call (reactor.go:582): batched verify of
@@ -335,8 +355,9 @@ class BlockSyncReactor:
                 first_parts, first_id = ahead[4], ahead[5]  # reuse dispatch-time work
                 ahead[6]()  # completes the dispatched kernel; raises as sync would
             else:
-                first_parts = first.make_part_set()
-                first_id = BlockID(hash=first.hash(), part_set_header=first_parts.header)
+                with _trace.span("blocksync.parts", "blocksync", height=first.header.height):
+                    first_parts = first.make_part_set()
+                    first_id = BlockID(hash=first.hash(), part_set_header=first_parts.header)
                 with _trace.span("blocksync.verify_commit", "blocksync",
                                  height=first.header.height):
                     verify_commit_light(
@@ -385,9 +406,10 @@ class BlockSyncReactor:
         # separate writes would leave a block whose restart
         # reconstruction (consensus/state.py) requires an EC that is
         # not there — a permanent halt.
-        self.block_store.save_block(
-            first, first_parts, second.last_commit, extended_commit=ec
-        )
+        with _trace.span("blocksync.save_block", "blocksync", height=height):
+            self.block_store.save_block(
+                first, first_parts, second.last_commit, extended_commit=ec
+            )
         with _trace.span("blocksync.apply", "blocksync", height=height):
             self.state = self.block_exec.apply_block(self.state, first_id, first)
         self.blocks_synced += 1
@@ -523,24 +545,27 @@ class BlockSyncReactor:
         third = self.pool.peek_third_block()
         if third is None:
             return
-        next_vals = self.state.next_validators
-        second_parts = second_id = None
-        try:
-            second_parts = second.make_part_set()
-            second_id = BlockID(hash=second.hash(), part_set_header=second_parts.header)
-            complete = verify_commit_light_async(
-                self.state.chain_id,
-                next_vals,
-                second_id,
-                second.header.height,
-                third.last_commit,
+        height = second.header.height
+        with _trace.span("blocksync.verify_ahead", "blocksync", height=height):
+            next_vals = self.state.next_validators
+            second_parts = second_id = None
+            try:
+                with _trace.span("blocksync.parts", "blocksync", height=height):
+                    second_parts = second.make_part_set()
+                    second_id = BlockID(hash=second.hash(), part_set_header=second_parts.header)
+                complete = verify_commit_light_async(
+                    self.state.chain_id,
+                    next_vals,
+                    second_id,
+                    height,
+                    third.last_commit,
+                )
+            except Exception as e:
+                def complete(e=e):
+                    raise e
+            # parts/id carried along so the consuming iteration reuses the
+            # serialization + merkle work instead of redoing it
+            self._verify_ahead = (
+                height, second, third, next_vals.hash(),
+                second_parts, second_id, complete,
             )
-        except Exception as e:
-            def complete(e=e):
-                raise e
-        # parts/id carried along so the consuming iteration reuses the
-        # serialization + merkle work instead of redoing it
-        self._verify_ahead = (
-            second.header.height, second, third, next_vals.hash(),
-            second_parts, second_id, complete,
-        )

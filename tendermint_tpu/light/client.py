@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+from .. import trace as _trace
 from ..types.evidence import LightClientAttackEvidence
 from ..types.light_block import LightBlock
 from ..types.validation import Fraction
@@ -88,24 +89,29 @@ class LightClient:
         existing = self.store.latest_light_block()
         if existing is not None:
             return  # restored from a previous run
-        lb = self.primary.light_block(self.trust_options.height)
-        lb.validate_basic(self.chain_id)
-        if lb.signed_header.hash() != self.trust_options.hash:
-            raise LightClientError(
-                f"expected header's hash {self.trust_options.hash.hex()}, "
-                f"but got {lb.signed_header.hash().hex()}"
-            )
-        # initial trust: 2/3 of its own validator set signed it (client.go:318)
-        from ..types.validation import verify_commit_light
+        height = self.trust_options.height
+        # a client's trust root is an update too: fetched, checked, stored
+        with _trace.span("light.update", "light", height=height, mode="root"):
+            with _trace.span("light.fetch", "light", provider="primary", height=height):
+                lb = self.primary.light_block(height)
+                lb.validate_basic(self.chain_id)
+            if lb.signed_header.hash() != self.trust_options.hash:
+                raise LightClientError(
+                    f"expected header's hash {self.trust_options.hash.hex()}, "
+                    f"but got {lb.signed_header.hash().hex()}"
+                )
+            # initial trust: 2/3 of its own validator set signed it (client.go:318)
+            from ..types.validation import verify_commit_light
 
-        verify_commit_light(
-            self.chain_id,
-            lb.validator_set,
-            lb.signed_header.commit.block_id,
-            lb.signed_header.header.height,
-            lb.signed_header.commit,
-        )
-        self.store.save_light_block(lb)
+            verify_commit_light(
+                self.chain_id,
+                lb.validator_set,
+                lb.signed_header.commit.block_id,
+                lb.signed_header.header.height,
+                lb.signed_header.commit,
+            )
+            with _trace.span("light.store", "light", blocks=1):
+                self.store.save_light_block(lb)
 
     # ------------------------------------------------------------- queries
 
@@ -120,43 +126,49 @@ class LightClient:
     def update(self, now: Time | None = None) -> LightBlock | None:
         """Verify the primary's latest header (ref: client.go:380 Update)."""
         now = now or self.now()
-        latest = self.primary.light_block(0)
-        trusted = self.store.latest_light_block()
-        if trusted is not None and latest.height <= trusted.height:
-            # A primary serving a DIFFERENT header at our trusted height
-            # is a conflict signal, not a no-op (ref: client.go Update
-            # errors on same-height hash mismatch).
-            if (
-                latest.height == trusted.height
-                and latest.signed_header.hash() != trusted.signed_header.hash()
-            ):
-                raise LightClientError(
-                    f"primary returned a conflicting header at trusted height "
-                    f"{trusted.height}"
-                )
-            return trusted
-        # verify the block already in hand — no refetch round-trip
-        latest.validate_basic(self.chain_id)
-        self._verify_light_block(latest, now)
-        return latest
+        with _trace.span("light.update", "light", height=0, mode=self.mode) as sp:
+            with _trace.span("light.fetch", "light", provider="primary", height=0):
+                latest = self.primary.light_block(0)
+            sp.annotate(height=latest.height)
+            trusted = self.store.latest_light_block()
+            if trusted is not None and latest.height <= trusted.height:
+                # A primary serving a DIFFERENT header at our trusted height
+                # is a conflict signal, not a no-op (ref: client.go Update
+                # errors on same-height hash mismatch).
+                if (
+                    latest.height == trusted.height
+                    and latest.signed_header.hash() != trusted.signed_header.hash()
+                ):
+                    raise LightClientError(
+                        f"primary returned a conflicting header at trusted height "
+                        f"{trusted.height}"
+                    )
+                return trusted
+            # verify the block already in hand — no refetch round-trip
+            with _trace.span("light.fetch", "light", provider="primary", height=latest.height):
+                latest.validate_basic(self.chain_id)
+            self._verify_light_block(latest, now)
+            return latest
 
     def verify_light_block_at_height(self, height: int, now: Time | None = None) -> LightBlock:
         """ref: client.go:413 VerifyLightBlockAtHeight."""
         if height <= 0:
             raise ValueError("height must be positive")
         now = now or self.now()
-        cached = self.store.light_block(height)
-        if cached is not None:
-            return cached
-        latest = self.store.latest_light_block()
-        if latest is None:
-            raise LightClientError("light client not initialized")
-        if height < latest.height:
-            return self._verify_backwards(height, latest, now)
-        lb = self.primary.light_block(height)
-        lb.validate_basic(self.chain_id)
-        self._verify_light_block(lb, now)
-        return lb
+        with _trace.span("light.update", "light", height=height, mode=self.mode):
+            cached = self.store.light_block(height)
+            if cached is not None:
+                return cached
+            latest = self.store.latest_light_block()
+            if latest is None:
+                raise LightClientError("light client not initialized")
+            if height < latest.height:
+                return self._verify_backwards(height, latest, now)
+            with _trace.span("light.fetch", "light", provider="primary", height=height):
+                lb = self.primary.light_block(height)
+                lb.validate_basic(self.chain_id)
+            self._verify_light_block(lb, now)
+            return lb
 
     def _verify_light_block(self, new_lb: LightBlock, now: Time) -> None:
         """ref: client.go:497 verifyLightBlock. Nothing is persisted
@@ -170,14 +182,56 @@ class LightClient:
         else:
             verified = self._verify_skipping_against_primary(closest, new_lb, now)
         self._detect_divergence(new_lb, now)
-        for lb in verified:
-            self.store.save_light_block(lb)
-        self.store.save_light_block(new_lb)
-        self.store.prune(self.pruning_size)
+        with _trace.span("light.store", "light", blocks=len(verified) + 1):
+            for lb in verified:
+                self.store.save_light_block(lb)
+            self.store.save_light_block(new_lb)
+            self.store.prune(self.pruning_size)
 
     def _closest_trusted_below(self, height: int) -> LightBlock | None:
         lb = self.store.light_block_before(height + 1)
         return lb
+
+    def _verify_step(self, trusted: LightBlock, new_lb: LightBlock, now: Time) -> None:
+        """One trust step, both of its commits: adjacent heights take
+        the hash-chain rule, a skip the trust-level rule. The span's
+        outcome tells a step that asks for a bisection from one refused;
+        the verifier wraps whatever a commit check raised (a device
+        fault too) in ErrInvalidHeader, so `error` names what it was."""
+        adjacent = new_lb.height == trusted.height + 1
+        with _trace.span("light.verify_step", "light", to=new_lb.height, adjacent=adjacent,
+                         **{"from": trusted.height}) as sp:
+            try:
+                if adjacent:
+                    vf.verify_adjacent(
+                        self.chain_id,
+                        trusted.signed_header,
+                        new_lb.signed_header,
+                        new_lb.validator_set,
+                        self.trust_options.period_ns,
+                        now,
+                        self.max_clock_drift_ns,
+                    )
+                else:
+                    vf.verify_non_adjacent(
+                        self.chain_id,
+                        trusted.signed_header,
+                        trusted.validator_set,
+                        new_lb.signed_header,
+                        new_lb.validator_set,
+                        self.trust_options.period_ns,
+                        now,
+                        self.max_clock_drift_ns,
+                        self.trust_options.trust_level,
+                    )
+            except Exception as e:
+                sp.annotate(
+                    outcome="bisect" if isinstance(e, vf.ErrNewValSetCantBeTrusted)
+                    else "invalid" if isinstance(e, vf.ErrInvalidHeader) else "error",
+                    error=type(e.__context__ or e).__name__,
+                )
+                raise
+            sp.annotate(outcome="ok")
 
     def _verify_sequential(self, trusted: LightBlock, new_lb: LightBlock, now: Time) -> list[LightBlock]:
         """Verify every height in (trusted, new]; returns the verified
@@ -186,15 +240,7 @@ class LightClient:
         verified: list[LightBlock] = []
         for h in range(trusted.height + 1, new_lb.height + 1):
             lb = new_lb if h == new_lb.height else self._fetch(self.primary, h)
-            vf.verify_adjacent(
-                self.chain_id,
-                current.signed_header,
-                lb.signed_header,
-                lb.validator_set,
-                self.trust_options.period_ns,
-                now,
-                self.max_clock_drift_ns,
-            )
+            self._verify_step(current, lb, now)
             if h != new_lb.height:
                 verified.append(lb)
             current = lb
@@ -213,28 +259,7 @@ class LightClient:
             current = verified[-1]
             candidate = pending[-1]
             try:
-                if candidate.height == current.height + 1:
-                    vf.verify_adjacent(
-                        self.chain_id,
-                        current.signed_header,
-                        candidate.signed_header,
-                        candidate.validator_set,
-                        self.trust_options.period_ns,
-                        now,
-                        self.max_clock_drift_ns,
-                    )
-                else:
-                    vf.verify_non_adjacent(
-                        self.chain_id,
-                        current.signed_header,
-                        current.validator_set,
-                        candidate.signed_header,
-                        candidate.validator_set,
-                        self.trust_options.period_ns,
-                        now,
-                        self.max_clock_drift_ns,
-                        self.trust_options.trust_level,
-                    )
+                self._verify_step(current, candidate, now)
                 verified.append(candidate)
                 pending.pop()
                 depth = 0  # progress made — only CONSECUTIVE failures count
@@ -271,8 +296,10 @@ class LightClient:
         last_err = None
         for _ in range(MAX_RETRY_ATTEMPTS):
             try:
-                lb = provider.light_block(height)
-                lb.validate_basic(self.chain_id)
+                with _trace.span("light.fetch", "light", height=height,
+                                 provider="primary" if provider is self.primary else "witness"):
+                    lb = provider.light_block(height)
+                    lb.validate_basic(self.chain_id)
                 return lb
             except ErrLightBlockNotFound as e:
                 raise
@@ -288,6 +315,11 @@ class LightClient:
         (ref: light/detector.go:33 detectDivergence)."""
         if not self.witnesses:
             return
+        with _trace.span("light.detect_divergence", "light", witnesses=len(self.witnesses)):
+            self._cross_reference(new_lb)
+
+    def _cross_reference(self, new_lb: LightBlock) -> None:
+        """detect_divergence's body, under its span."""
         primary_hash = new_lb.signed_header.hash()
         # A witness merely LAGGING the head (ErrLightBlockNotFound: it
         # has not stored the freshly-committed height yet) gets bounded
@@ -310,7 +342,9 @@ class LightClient:
             lagging = []
             for witness in remaining:
                 try:
-                    w_lb = witness.light_block(new_lb.height)
+                    with _trace.span("light.fetch", "light", provider="witness",
+                                     height=new_lb.height):
+                        w_lb = witness.light_block(new_lb.height)
                 except ErrLightBlockNotFound:
                     lagging.append(witness)
                     continue
@@ -344,6 +378,7 @@ class LightClient:
                     )
                 # tmcheck: ok[shared-mutation] last-slot publication: an atomic reference store consumers read once; last evidence wins
                 self.latest_attack_evidence = ev
+                _trace.annotate(cross_referenced=cross_referenced, diverged=witness.id())
                 for p in [self.primary] + self.witnesses:
                     try:
                         p.report_evidence(ev)
@@ -356,6 +391,7 @@ class LightClient:
             remaining = lagging
             if not remaining:
                 break
+        _trace.annotate(cross_referenced=cross_referenced)
         if cross_referenced == 0:
             # Every configured witness was unreachable: accepting the
             # primary's header with ZERO cross-checks is exactly the

@@ -13,6 +13,7 @@ Both commit checks run through the batched TPU verification plane
 
 from __future__ import annotations
 
+from .. import trace as _trace
 from ..types.light_block import SignedHeader
 from ..types.validation import (
     Fraction,
@@ -58,32 +59,34 @@ def _verify_new_header_and_vals(
     chain_id: str,
 ) -> None:
     """ref: verifier.go:196 verifyNewHeaderAndVals."""
-    try:
-        untrusted_header.validate_basic(chain_id)
-    except ErrInvalidHeader:
-        raise
-    except Exception as e:
-        raise ErrInvalidHeader(str(e))
-    if untrusted_header.header.height <= trusted_header.header.height:
-        raise ErrInvalidHeader(
-            f"expected new header height {untrusted_header.header.height} to be greater than "
-            f"one of old header {trusted_header.header.height}"
-        )
-    if untrusted_header.header.time.unix_ns() <= trusted_header.header.time.unix_ns():
-        raise ErrInvalidHeader(
-            f"expected new header time {untrusted_header.header.time} to be after old header time "
-            f"{trusted_header.header.time}"
-        )
-    if untrusted_header.header.time.unix_ns() >= now.unix_ns() + max_clock_drift_ns:
-        raise ErrInvalidHeader(
-            f"new header has a time from the future {untrusted_header.header.time} (now: {now})"
-        )
-    untrusted_vals_hash = untrusted_vals.hash()  # memoized (types/validator_set.py)
-    if untrusted_header.header.validators_hash != untrusted_vals_hash:
-        raise ErrInvalidHeader(
-            f"expected new header validators ({untrusted_header.header.validators_hash.hex()}) to match "
-            f"those that were supplied ({untrusted_vals_hash.hex()}) at height {untrusted_header.header.height}"
-        )
+    with _trace.span("light.header_checks", "light",
+                     height=untrusted_header.header.height, vals=untrusted_vals.size()):
+        try:
+            untrusted_header.validate_basic(chain_id)
+        except ErrInvalidHeader:
+            raise
+        except Exception as e:
+            raise ErrInvalidHeader(str(e))
+        if untrusted_header.header.height <= trusted_header.header.height:
+            raise ErrInvalidHeader(
+                f"expected new header height {untrusted_header.header.height} to be greater than "
+                f"one of old header {trusted_header.header.height}"
+            )
+        if untrusted_header.header.time.unix_ns() <= trusted_header.header.time.unix_ns():
+            raise ErrInvalidHeader(
+                f"expected new header time {untrusted_header.header.time} to be after old header time "
+                f"{trusted_header.header.time}"
+            )
+        if untrusted_header.header.time.unix_ns() >= now.unix_ns() + max_clock_drift_ns:
+            raise ErrInvalidHeader(
+                f"new header has a time from the future {untrusted_header.header.time} (now: {now})"
+            )
+        untrusted_vals_hash = untrusted_vals.hash()  # memoized (types/validator_set.py)
+        if untrusted_header.header.validators_hash != untrusted_vals_hash:
+            raise ErrInvalidHeader(
+                f"expected new header validators ({untrusted_header.header.validators_hash.hex()}) to match "
+                f"those that were supplied ({untrusted_vals_hash.hex()}) at height {untrusted_header.header.height}"
+            )
 
 
 def verify_non_adjacent(

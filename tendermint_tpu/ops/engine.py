@@ -157,7 +157,7 @@ def _autotune_probe(dev_pinned: bool, msm_pinned: bool) -> None:
 class _Job:
     __slots__ = (
         "plane", "pks", "msgs", "sigs", "n", "event", "result", "error",
-        "flow", "t_submit", "journey",
+        "flow", "span", "req", "t_submit", "journey",
     )
 
     def __init__(self, plane, pks, msgs, sigs, journey=None):
@@ -173,6 +173,10 @@ class _Job:
         # dispatch/collect spans of whichever coalesced launch carries
         # it (0 when tracing is off — new_flow() skipped)
         self.flow = 0
+        # the submitter's engine.submit span and its request (trace
+        # args.span / args.req; 0 when tracing is off): what the
+        # workers' spans name as their parent on another thread
+        self.span = self.req = 0
         self.t_submit = 0.0
         # tmpath journey tag (trace.journey_key string or None): rides
         # the job through coalescing so the launch's dispatch/collect
@@ -212,6 +216,19 @@ class JobHandle:
                 err = self._job.error
             raise err
         return self._job.result
+
+
+def _caused_by(group) -> dict:
+    """Trace args for a worker's span over a group: the oldest job's
+    submit span as parent, its request, and every request served when
+    the group was coalesced. Empty for jobs submitted with tracing off."""
+    first = group[0]
+    if not first.span:
+        return {}
+    caused = {"parent": first.span, "req": first.req}
+    if len(group) > 1:
+        caused["reqs"] = [j.req for j in group]
+    return caused
 
 
 def _fail_jobs(jobs, exc: BaseException) -> None:
@@ -356,8 +373,8 @@ class VerifyEngine:
             sub_args = {"plane": plane, "rows": job.n, "flow": job.flow}
             if journey:
                 sub_args["journey"] = journey
-            with _trace.span("engine.submit", "engine", **sub_args):
-                pass
+            with _trace.span("engine.submit", "engine", **sub_args) as sp:
+                job.span, job.req = sp.id, sp.req
         m = _engine_metrics()
         m.submitted_jobs.add(1, plane)
         m.submitted_sigs.add(job.n, plane)
@@ -409,7 +426,7 @@ class VerifyEngine:
             sp = _trace.span(
                 "engine.dispatch", "engine",
                 plane=group[0].plane, jobs=len(group), rows=rows,
-                flow=group[0].flow,
+                flow=group[0].flow, **_caused_by(group),
             )
             journeys = sorted({j.journey for j in group if j.journey})
             if journeys:
@@ -441,6 +458,7 @@ class VerifyEngine:
 
         plane = group[0].plane
         flow = group[0].flow
+        caused = _caused_by(group)
         pks, msgs, sigs = [], [], []
         for j in group:
             pks += j.pks
@@ -457,7 +475,7 @@ class VerifyEngine:
                 t0 = _time.monotonic()
                 try:
                     with _trace.span("engine.host_verify", "engine",
-                                     plane=plane, rows=total, flow=flow):
+                                     plane=plane, rows=total, flow=flow, **caused):
                         return host_fn(pks, msgs, sigs)
                 finally:
                     # metric writes never raise; nothing here can mask
@@ -524,7 +542,8 @@ class VerifyEngine:
             t0 = _time.monotonic()
             try:
                 c_args = {"plane": group[0].plane, "jobs": len(group),
-                          "rows": rows, "path": path, "flow": group[0].flow}
+                          "rows": rows, "path": path, "flow": group[0].flow,
+                          **_caused_by(group)}
                 journeys = sorted({j.journey for j in group if j.journey})
                 if journeys:
                     c_args["journeys"] = journeys

@@ -345,6 +345,47 @@ def _ensure_z_raw(n: int, z_raw: bytes | None) -> bytes:
     return z_raw
 
 
+def _prep_rlc(prepare, pubkeys, msgs, sigs, n):
+    """`ops.prep`: the plane's host prep (native/prep.c when built) and
+    its precheck. None when a row is malformed: the RLC path refuses
+    the batch and the caller's bitmap plane localizes."""
+    with _trace.span("ops.prep", "ops", rows=n):
+        a_enc, r_enc, s_rows, k_rows, precheck = prepare(pubkeys, msgs, sigs)
+        refused = not precheck.all()
+    if refused:
+        _trace.annotate(refused="precheck")  # on the dispatch span, the innermost open
+        return None
+    return a_enc, r_enc, s_rows, k_rows
+
+
+def _scalars_rlc(s_rows, k_rows, n, z_raw):
+    """`ops.rlc_scalars`: the randomizer draw and the scalar arithmetic."""
+    with _trace.span("ops.rlc_scalars", "ops", rows=n):
+        z_raw = _ensure_z_raw(n, z_raw)
+        return _rlc_scalars(s_rows, k_rows, n, z_raw)
+
+
+def _launch_rlc(fn, kernel, head, slots, rows, zs_row, n, fid):
+    """`ops.launch`: pad the per-row arrays to the program's row count,
+    stage them (`device.h2d`, its child) and make the asynchronous
+    kernel call (and a compile, when one happens). `head` are the
+    kernel's leading device-resident arguments and `slots` its cache
+    slots (the cached plane), or () and None."""
+    padded = _pad_pow2(n)
+    with _trace.span("ops.launch", "ops", rows=n, padded=padded):
+        rows = pad_pow2_rows(rows, n, churnable=False)
+        if slots is not None:
+            # padded rows carry zero scalars (identity contributions), but their
+            # slot must point at a VALID cached key: slot 0 may hold a key whose
+            # encoding fails decode, which would sink all_ok for a valid batch
+            rows = [np.pad(slots, (0, len(rows[0]) - n), mode="edge"), *rows]
+        nbytes = sum(a.nbytes for a in rows) + zs_row.nbytes
+        with _devobs.transfer_span("h2d", nbytes, flow=fid):
+            dev_args = [jnp.asarray(a) for a in (*rows, zs_row)]
+        with _devobs.attribution(fn=fn, rows=padded, flow=fid):
+            return kernel(*head, *dev_args)
+
+
 def _dispatch_rlc(prepare, kernel, pubkeys, msgs, sigs, z_raw):
     """Shared RLC dispatch for both signature planes: prep, precheck
     refusal (None -> caller goes straight to its bitmap plane, exactly
@@ -354,24 +395,13 @@ def _dispatch_rlc(prepare, kernel, pubkeys, msgs, sigs, z_raw):
     if n == 0:
         return None
     fid = _devobs.next_flow() if _devobs.enabled() else 0
-    with _trace.span("ops.msm_dispatch", "ops", kernel="rlc", rows=n, flow=fid) as sp:
-        a_enc, r_enc, s_rows, k_rows, precheck = prepare(pubkeys, msgs, sigs)
-        if not precheck.all():
-            sp.annotate(refused="precheck")
+    with _trace.span("ops.msm_dispatch", "ops", kernel="rlc", rows=n, flow=fid):
+        prepped = _prep_rlc(prepare, pubkeys, msgs, sigs, n)
+        if prepped is None:
             return None
-        z_raw = _ensure_z_raw(n, z_raw)
-        zk, z_out, zs_row = _rlc_scalars(s_rows, k_rows, n, z_raw)
-        a_enc, r_enc, zk, z_out = pad_pow2_rows(
-            [a_enc, r_enc, zk, z_out], n, churnable=False,
-        )
-        nbytes = a_enc.nbytes + r_enc.nbytes + zk.nbytes + z_out.nbytes + zs_row.nbytes
-        with _devobs.transfer_span("h2d", nbytes, flow=fid):
-            dev_args = (
-                jnp.asarray(a_enc), jnp.asarray(r_enc),
-                jnp.asarray(zk), jnp.asarray(z_out), jnp.asarray(zs_row),
-            )
-        with _devobs.attribution(fn="rlc", rows=_pad_pow2(n), flow=fid):
-            handle = kernel(*dev_args)
+        a_enc, r_enc, s_rows, k_rows = prepped
+        zk, z_out, zs_row = _scalars_rlc(s_rows, k_rows, n, z_raw)
+        handle = _launch_rlc("rlc", kernel, (), None, [a_enc, r_enc, zk, z_out], zs_row, n, fid)
     _engine_metrics().kernel_launches.add(1, "rlc")
     return handle
 
@@ -404,46 +434,26 @@ def verify_batch_rlc_cached_async(pubkeys, msgs, sigs, z_raw: bytes | None = Non
         # live validator keys) for a batch that never verifies. The bitmap
         # cached path legitimately inserts first — it verifies malformed
         # rows masked, not refused.
-        a_enc, r_enc, s_rows, k_rows, precheck = prepare_batch(pubkeys, msgs, sigs)
-        if not precheck.all():
-            sp.annotate(refused="precheck")
+        prepped = _prep_rlc(prepare_batch, pubkeys, msgs, sigs, n)
+        if prepped is None:
             return None
+        a_enc, r_enc, s_rows, k_rows = prepped
         keys = [pk if len(pk) == 32 else b"\x00" * 32 for pk in pubkeys]
         slots, tables, oks = cache.ensure_snapshot(keys)
-        z_raw = _ensure_z_raw(n, z_raw)
-        zk, z_out, zs_row = _rlc_scalars(s_rows, k_rows, n, z_raw)
+        zk, z_out, zs_row = _scalars_rlc(s_rows, k_rows, n, z_raw)
         if slots is None:
             # more distinct keys than the cache holds: take the uncached
             # kernel, reusing the prep + scalar math already done instead
             # of re-dispatching through verify_batch_rlc_async
             sp.annotate(cache="overflow")
-            a_enc, r_enc, zk, z_out = pad_pow2_rows(
-                [a_enc, r_enc, zk, z_out], n, churnable=False,
-            )
-            nbytes = a_enc.nbytes + r_enc.nbytes + zk.nbytes + z_out.nbytes + zs_row.nbytes
-            with _devobs.transfer_span("h2d", nbytes, flow=fid):
-                dev_args = (
-                    jnp.asarray(a_enc), jnp.asarray(r_enc),
-                    jnp.asarray(zk), jnp.asarray(z_out), jnp.asarray(zs_row),
-                )
-            with _devobs.attribution(fn="rlc", rows=_pad_pow2(n), flow=fid):
-                handle = msm_verify_kernel(*dev_args)
-            _engine_metrics().kernel_launches.add(1, "rlc")
-            return handle
-        r_enc, zk, z_out = pad_pow2_rows([r_enc, zk, z_out], n, churnable=False)
-        # padded rows carry zero scalars (identity contributions), but their
-        # slot must point at a VALID cached key: slot 0 may hold a key whose
-        # encoding fails decode, which would sink all_ok for a valid batch
-        slots = np.pad(slots, (0, len(r_enc) - n), mode="edge")
-        nbytes = slots.nbytes + r_enc.nbytes + zk.nbytes + z_out.nbytes + zs_row.nbytes
-        with _devobs.transfer_span("h2d", nbytes, flow=fid):
-            dev_args = (
-                jnp.asarray(slots), jnp.asarray(r_enc),
-                jnp.asarray(zk), jnp.asarray(z_out), jnp.asarray(zs_row),
-            )
-        with _devobs.attribution(fn="rlc_cached", rows=_pad_pow2(n), flow=fid):
-            handle = msm_verify_kernel_cached(tables, oks, *dev_args)
-    _engine_metrics().kernel_launches.add(1, "rlc_cached")
+            fn = "rlc"
+            handle = _launch_rlc(fn, msm_verify_kernel, (), None,
+                                 [a_enc, r_enc, zk, z_out], zs_row, n, fid)
+        else:
+            fn = "rlc_cached"
+            handle = _launch_rlc(fn, msm_verify_kernel_cached, (tables, oks), slots,
+                                 [r_enc, zk, z_out], zs_row, n, fid)
+    _engine_metrics().kernel_launches.add(1, fn)
     return handle
 
 
@@ -451,6 +461,10 @@ def collect_rlc(dispatched) -> bool:
     """Block on a verify_batch_rlc_async handle -> all-valid bool."""
     if dispatched is None:
         return False
+    # the wait for the kernel apart from the read-back: device.d2h
+    # times the transfer alone
+    with _trace.span("device.wait", "device"):
+        dispatched.block_until_ready()
     with _devobs.transfer_span("d2h", int(getattr(dispatched, "nbytes", 1) or 1)):
         return bool(dispatched)
 

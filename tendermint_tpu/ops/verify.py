@@ -454,16 +454,19 @@ def verify_batch_async(pubkeys, msgs, sigs):
         return None, np.zeros((0,), bool), 0, 0
     fid = _devobs.next_flow() if _devobs.enabled() else 0
     with _trace.span("ops.verify_dispatch", "ops", kernel="bitmap", rows=n, flow=fid):
-        a_enc, r_enc, s_bytes, k_bytes, precheck = prepare_batch(pubkeys, msgs, sigs)
-        a_enc, r_enc, s_bytes, k_bytes = pad_pow2_rows([a_enc, r_enc, s_bytes, k_bytes], n)
-        nbytes = a_enc.nbytes + r_enc.nbytes + s_bytes.nbytes + k_bytes.nbytes
-        with _devobs.transfer_span("h2d", nbytes, flow=fid):
-            a_dev, r_dev, s_dev, k_dev = (
-                jnp.asarray(a_enc), jnp.asarray(r_enc),
-                jnp.asarray(s_bytes), jnp.asarray(k_bytes),
-            )
-        with _devobs.attribution(fn="ed25519_bitmap", rows=_pad_pow2(n), flow=fid):
-            ok_dev = verify_kernel(a_dev, r_dev, s_dev, k_dev)
+        with _trace.span("ops.prep", "ops", rows=n):
+            a_enc, r_enc, s_bytes, k_bytes, precheck = prepare_batch(pubkeys, msgs, sigs)
+        padded = _pad_pow2(n)
+        with _trace.span("ops.launch", "ops", rows=n, padded=padded):
+            a_enc, r_enc, s_bytes, k_bytes = pad_pow2_rows([a_enc, r_enc, s_bytes, k_bytes], n)
+            nbytes = a_enc.nbytes + r_enc.nbytes + s_bytes.nbytes + k_bytes.nbytes
+            with _devobs.transfer_span("h2d", nbytes, flow=fid):
+                a_dev, r_dev, s_dev, k_dev = (
+                    jnp.asarray(a_enc), jnp.asarray(r_enc),
+                    jnp.asarray(s_bytes), jnp.asarray(k_bytes),
+                )
+            with _devobs.attribution(fn="ed25519_bitmap", rows=padded, flow=fid):
+                ok_dev = verify_kernel(a_dev, r_dev, s_dev, k_dev)
     _engine_metrics().kernel_launches.add(1, "bitmap")
     return ok_dev, precheck, n, fid
 
@@ -474,9 +477,17 @@ def collect(dispatched) -> np.ndarray:
     if n == 0:
         return np.zeros((0,), bool)
     fid = dispatched[3] if len(dispatched) > 3 else 0
+    return read_back(ok_dev, n, fid)[:n] & precheck
+
+
+def read_back(ok_dev, n: int, fid: int) -> np.ndarray:
+    """A launch's bitmap on the host (shared by the ed25519 and sr25519
+    planes): `device.wait` is the collect thread blocked on the kernel,
+    `device.d2h` the read-back alone."""
+    with _trace.span("device.wait", "device", flow=fid):
+        ok_dev.block_until_ready()
     with _devobs.transfer_span("d2h", int(getattr(ok_dev, "nbytes", n) or n), flow=fid):
-        host = np.asarray(ok_dev)
-    return host[:n] & precheck
+        return np.asarray(ok_dev)
 
 
 def verify_batch(pubkeys, msgs, sigs) -> np.ndarray:
@@ -506,17 +517,20 @@ def dispatch_cached(cache, prepare, cached_kernel, uncached_async, pubkeys, msgs
         if slots is None:
             sp.annotate(cache="overflow")
             return uncached_async(pubkeys, msgs, sigs)
-        _, r_enc, s_bytes, k_bytes, precheck = prepare(pubkeys, msgs, sigs)
-        r_enc, s_bytes, k_bytes = pad_pow2_rows([r_enc, s_bytes, k_bytes], n)
-        slots = np.pad(slots, (0, len(r_enc) - n))
-        nbytes = slots.nbytes + r_enc.nbytes + s_bytes.nbytes + k_bytes.nbytes
-        with _devobs.transfer_span("h2d", nbytes, flow=fid):
-            slots_dev, r_dev, s_dev, k_dev = (
-                jnp.asarray(slots), jnp.asarray(r_enc),
-                jnp.asarray(s_bytes), jnp.asarray(k_bytes),
-            )
-        with _devobs.attribution(fn=fn_label, rows=_pad_pow2(n), flow=fid):
-            ok_dev = cached_kernel(tables, oks, slots_dev, r_dev, s_dev, k_dev)
+        with _trace.span("ops.prep", "ops", rows=n):
+            _, r_enc, s_bytes, k_bytes, precheck = prepare(pubkeys, msgs, sigs)
+        padded = _pad_pow2(n)
+        with _trace.span("ops.launch", "ops", rows=n, padded=padded):
+            r_enc, s_bytes, k_bytes = pad_pow2_rows([r_enc, s_bytes, k_bytes], n)
+            slots = np.pad(slots, (0, len(r_enc) - n))
+            nbytes = slots.nbytes + r_enc.nbytes + s_bytes.nbytes + k_bytes.nbytes
+            with _devobs.transfer_span("h2d", nbytes, flow=fid):
+                slots_dev, r_dev, s_dev, k_dev = (
+                    jnp.asarray(slots), jnp.asarray(r_enc),
+                    jnp.asarray(s_bytes), jnp.asarray(k_bytes),
+                )
+            with _devobs.attribution(fn=fn_label, rows=padded, flow=fid):
+                ok_dev = cached_kernel(tables, oks, slots_dev, r_dev, s_dev, k_dev)
     _engine_metrics().kernel_launches.add(1, "bitmap_cached")
     return ok_dev, precheck, n, fid
 

@@ -211,15 +211,13 @@ def verify_batch_cached(pubkeys, msgs, sigs) -> np.ndarray:
 
 def collect(dispatched) -> np.ndarray:
     """Block on a verify_batch_async result and fold in the precheck."""
-    from .. import devobs as _devobs
+    from .verify import read_back
 
     ok_dev, precheck, n = dispatched[:3]
     if n == 0:
         return np.zeros((0,), bool)
     fid = dispatched[3] if len(dispatched) > 3 else 0
-    with _devobs.transfer_span("d2h", int(getattr(ok_dev, "nbytes", n) or n), flow=fid):
-        host = np.asarray(ok_dev)
-    return host[:n] & precheck
+    return read_back(ok_dev, n, fid)[:n] & precheck
 
 
 def verify_batch(pubkeys, msgs, sigs) -> np.ndarray:

@@ -22,14 +22,36 @@ Design constraints:
     submit span, the dispatch worker's coalesce/launch spans, and the
     collect worker's demux span share it. Export adds Chrome-trace
     flow events (ph s/f) per flow id so Perfetto draws the arrows.
+  - the span that caused it: on the enabled path every event (span,
+    complete, instant) carries three ints in its args. `span` is its
+    own id, from one process-wide counter; `parent` the id of the
+    innermost span open on this thread when it started (0 for none);
+    `req` the id of the root of that stack, inherited downwards, so
+    the spans of one request share it. A span handed `parent=` and
+    `req=` keeps them: that is how the engine's workers name the
+    submitter's span on another thread (engine.dispatch,
+    engine.host_verify and engine.collect take the oldest job's
+    engine.submit span as parent, and list every job's req as `reqs`
+    when a group was coalesced). A layer's self time is its span's
+    duration less what the spans naming it as parent cover.
 
 Span catalog (docs/observability.md): consensus.step (instant) /
 consensus.finalize_commit, state.apply_block / state.validate_block /
-state.finalize_block / state.abci_commit, verify.commit_dispatch /
-verify.commit_collect / verify.direct_host, blocksync.verify_commit /
-blocksync.apply, engine.submit / engine.coalesce / engine.dispatch /
+state.finalize_block / state.abci_commit,
+light.update / light.fetch / light.verify_step / light.header_checks /
+light.detect_divergence / light.store (one light-client update, from
+the caller to the store), verify.commit_walk (address lookup,
+sign-bytes, tally) / verify.commit_dispatch / verify.commit_collect /
+verify.direct_host, blocksync.try_sync (one block on the reactor's
+thread) / blocksync.parts / blocksync.verify_commit /
+blocksync.verify_ahead / blocksync.save_block / blocksync.apply /
+blocksync.starved / blocksync.settle (both retrospective),
+engine.submit / engine.coalesce / engine.dispatch /
 engine.host_verify / engine.collect, ops.verify_dispatch /
-ops.msm_dispatch / ops.pk_cache_fill, sharded.verify,
+ops.msm_dispatch with ops.prep / ops.rlc_scalars / ops.launch under
+them, ops.pk_cache_fill, device.h2d (the staging calls) /
+device.wait (the collect thread blocked on the kernel) / device.d2h
+(the read-back alone) / device.compile, sharded.verify,
 mempool.admit_batch (coalesced tx admission: n/admitted/failed),
 journey.proposal_build / journey.proposal / journey.block_assembled /
 journey.quorum / journey.send / journey.recv (tmpath block-journey
@@ -63,7 +85,6 @@ __all__ = [
     "journey_key",
     "now_us",
     "complete",
-    "counter",
     "clear",
     "export",
     "export_json",
@@ -88,6 +109,7 @@ except ValueError:
 _EVENTS: deque = deque(maxlen=_CAPACITY)
 _LOCK = threading.Lock()
 _FLOW_IDS = itertools.count(1)
+_SPAN_IDS = itertools.count(1)  # args.span; 0 means "no span"
 _LOCAL = threading.local()
 
 
@@ -136,10 +158,26 @@ def _stack() -> list:
     return st
 
 
+def _stamp(args: dict) -> int:
+    """Give an event's args its own id, its parent's and its
+    request's (enabled path only). `parent` and `req` already in args
+    were handed across threads by the caller and stay."""
+    sid = args["span"] = next(_SPAN_IDS)
+    st = _stack()
+    top = st[-1] if st else None
+    if "parent" not in args:
+        args["parent"] = top.id if top is not None else 0
+    if "req" not in args:
+        args["req"] = top.req if top is not None else sid
+    return sid
+
+
 class _NoopSpan:
-    """Shared disabled-path span: no state, no clock, no lock."""
+    """Shared disabled-path span: no state, no clock, no lock, no id
+    drawn (id and req read 0, the "no span" sentinel)."""
 
     __slots__ = ()
+    id = req = 0
 
     def __enter__(self):
         return self
@@ -155,7 +193,7 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "_t0", "_tid", "_tname")
+    __slots__ = ("name", "cat", "args", "id", "req", "_t0", "_tid", "_tname")
 
     def __init__(self, name: str, cat: str, args: dict):
         self.name = name
@@ -166,6 +204,8 @@ class _Span:
         t = threading.current_thread()
         self._tid = t.ident or 0
         self._tname = t.name
+        self.id = _stamp(self.args)
+        self.req = self.args["req"]
         _stack().append(self)
         self._t0 = _now_us()
         return self
@@ -183,9 +223,8 @@ class _Span:
             "dur": t1 - self._t0,
             "tid": self._tid,
             "tname": self._tname,
+            "args": self.args,
         }
-        if self.args:
-            ev["args"] = self.args
         with _LOCK:
             _EVENTS.append(ev)
         return False
@@ -196,7 +235,9 @@ class _Span:
 
 def span(name: str, cat: str = "", **args):
     """Context manager recording one complete ("X") event. Disabled
-    path returns the shared no-op after a single dict lookup."""
+    path returns the shared no-op after a single dict lookup. Its
+    parent is the innermost span open on this thread, unless `parent=`
+    and `req=` name a span of another thread (see the module doc)."""
     if not _STATE["on"]:
         return _NOOP
     return _Span(name, cat, args)
@@ -216,6 +257,7 @@ def instant(name: str, cat: str = "", **args) -> None:
     if not _STATE["on"]:
         return
     t = threading.current_thread()
+    _stamp(args)
     ev = {
         "name": name,
         "cat": cat or "tm",
@@ -224,9 +266,8 @@ def instant(name: str, cat: str = "", **args) -> None:
         "ts": _now_us(),
         "tid": t.ident or 0,
         "tname": t.name,
+        "args": args,
     }
-    if args:
-        ev["args"] = args
     with _LOCK:
         _EVENTS.append(ev)
 
@@ -237,10 +278,12 @@ def complete(name: str, cat: str, ts_us: float, dur_us: float, **args) -> None:
     first vote's arrival becomes the span start once 2/3 is reached;
     part reassembly: the first part's arrival once the set completes).
     `ts_us` must come from now_us() so the event shares the ring's
-    clock."""
+    clock. Its parent is the span open on this thread when it is
+    emitted, which is where the hindsight was had."""
     if not _STATE["on"]:
         return
     t = threading.current_thread()
+    _stamp(args)
     ev = {
         "name": name,
         "cat": cat or "tm",
@@ -249,28 +292,10 @@ def complete(name: str, cat: str, ts_us: float, dur_us: float, **args) -> None:
         "dur": max(0.0, dur_us),
         "tid": t.ident or 0,
         "tname": t.name,
+        "args": args,
     }
-    if args:
-        ev["args"] = args
     with _LOCK:
         _EVENTS.append(ev)
-
-
-def counter(name: str, value: float, cat: str = "") -> None:
-    """One counter ("C") sample — queue depths over time."""
-    if not _STATE["on"]:
-        return
-    t = threading.current_thread()
-    with _LOCK:
-        _EVENTS.append({
-            "name": name,
-            "cat": cat or "tm",
-            "ph": "C",
-            "ts": _now_us(),
-            "tid": t.ident or 0,
-            "tname": t.name,
-            "args": {"value": value},
-        })
 
 
 def clear() -> None:

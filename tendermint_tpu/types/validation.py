@@ -148,54 +148,58 @@ def _verify_commit_batch(
     With defer=True the kernel is dispatched asynchronously and a no-arg
     completion callable is returned (raising with the same errors the
     synchronous path would); host-side failures still raise immediately."""
-    proposer = vals.get_proposer()
-    bv = crypto_batch.create_batch_verifier(proposer.pub_key)
-    if _trace.enabled():
-        # tmpath journey tag: rides the engine submit so the coalesced
-        # launch's dispatch/collect spans list this commit's height —
-        # the height attribution lens/journey.py splits verify time by
-        bv.journey = _trace.journey_key(commit.height, commit.round, "verify", "")
-    tallied = 0
-    seen_vals: dict[int, int] = {}
-    batch_sig_idxs: list[int] = []
+    with _trace.span("verify.commit_walk", "verify", height=commit.height) as walk:
+        proposer = vals.get_proposer()
+        bv = crypto_batch.create_batch_verifier(proposer.pub_key)
+        if _trace.enabled():
+            # tmpath journey tag: rides the engine submit so the coalesced
+            # launch's dispatch/collect spans list this commit's height —
+            # the height attribution lens/journey.py splits verify time by
+            bv.journey = _trace.journey_key(commit.height, commit.round, "verify", "")
+        tallied = 0
+        seen_vals: dict[int, int] = {}
+        batch_sig_idxs: list[int] = []
 
-    for idx, commit_sig in enumerate(commit.signatures):
-        if ignore_sig(commit_sig):
-            continue
-        if look_up_by_index:
-            val = vals.validators[idx]
-        else:
-            val_idx, val = vals.get_by_address(commit_sig.validator_address)
-            if val is None:
+        for idx, commit_sig in enumerate(commit.signatures):
+            if ignore_sig(commit_sig):
                 continue
-            if val_idx in seen_vals:
-                raise ValueError(f"double vote from {val} ({seen_vals[val_idx]} and {idx})")
-            seen_vals[val_idx] = idx
-        vote_sign_bytes = commit.vote_sign_bytes(chain_id, idx)
-        try:
-            bv.add(val.pub_key, vote_sign_bytes, commit_sig.signature)
-        except ValueError:
-            # Mixed key types: this key cannot join the proposer-typed
-            # batch. The reference returns the Add error outright
-            # (validation.go:211), rejecting commits that are in fact
-            # valid; we deliberately fall back to serial verification
-            # instead — acceptance still requires every signature to
-            # verify, so no invalid commit is admitted.
-            single = _verify_commit_single(
-                chain_id, vals, commit, voting_power_needed,
-                ignore_sig, count_sig, count_all_signatures, look_up_by_index,
-            )
-            if defer:
-                return lambda: single
-            return single
-        batch_sig_idxs.append(idx)
-        if count_sig(commit_sig):
-            tallied += val.voting_power
-        if not count_all_signatures and tallied > voting_power_needed:
-            break
+            if look_up_by_index:
+                val = vals.validators[idx]
+            else:
+                val_idx, val = vals.get_by_address(commit_sig.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen_vals:
+                    raise ValueError(f"double vote from {val} ({seen_vals[val_idx]} and {idx})")
+                seen_vals[val_idx] = idx
+            vote_sign_bytes = commit.vote_sign_bytes(chain_id, idx)
+            try:
+                bv.add(val.pub_key, vote_sign_bytes, commit_sig.signature)
+            except ValueError:
+                # Mixed key types: this key cannot join the proposer-typed
+                # batch. The reference returns the Add error outright
+                # (validation.go:211), rejecting commits that are in fact
+                # valid; we deliberately fall back to serial verification
+                # instead — acceptance still requires every signature to
+                # verify, so no invalid commit is admitted.
+                walk.annotate(fallback="single")
+                single = _verify_commit_single(
+                    chain_id, vals, commit, voting_power_needed,
+                    ignore_sig, count_sig, count_all_signatures, look_up_by_index,
+                )
+                if defer:
+                    return lambda: single
+                return single
+            batch_sig_idxs.append(idx)
+            if count_sig(commit_sig):
+                tallied += val.voting_power
+            if not count_all_signatures and tallied > voting_power_needed:
+                break
 
-    if tallied <= voting_power_needed:
-        raise NotEnoughVotingPowerError(got=tallied, needed=voting_power_needed)
+        # the batch path has two signatures at least: idx is the last one walked
+        walk.annotate(nsigs=len(batch_sig_idxs), walked=idx + 1)
+        if tallied <= voting_power_needed:
+            raise NotEnoughVotingPowerError(got=tallied, needed=voting_power_needed)
 
     with _trace.span("verify.commit_dispatch", "verify",
                      height=commit.height, nsigs=len(batch_sig_idxs)):

@@ -39,6 +39,17 @@ from tendermint_tpu.metrics import DeviceMetrics, Registry
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+@pytest.fixture(autouse=True)
+def _starts_uninstalled():
+    """Every test here starts from an uninstalled observatory and leaves
+    one: the installed flag is the process's, and another file's test on
+    this worker (tests/test_perf.py runs `tmperf record`, whose device-obs
+    stage installs) must not decide what these see."""
+    devobs.uninstall()
+    yield
+    devobs.uninstall()
+
+
 @pytest.fixture
 def observatory():
     """Installed devobs for one test, always uninstalled after (the

@@ -321,7 +321,26 @@ def _tmperf_main():
     return mod.main
 
 
-def test_cli_rc_contract_record_bless_gate_trend(tmp_path, capsys):
+@pytest.fixture
+def recording_in_process():
+    """`tmperf record` with every stage, in this process: its last stage
+    (device-obs) brings up jax's backend and installs the device
+    observatory, and nothing takes either back. The backend is brought
+    up first, so that every record of the test carries one fingerprint
+    whatever ran on this worker before it, and the observatory is left
+    as it was found (tests/test_devobs.py expects it uninstalled)."""
+    import jax
+
+    from tendermint_tpu import devobs
+
+    jax.devices()
+    was_installed = devobs.enabled()
+    yield
+    if not was_installed:
+        devobs.uninstall()
+
+
+def test_cli_rc_contract_record_bless_gate_trend(tmp_path, capsys, recording_in_process):
     main = _tmperf_main()
     ledger = str(tmp_path / "ledger.jsonl")
     baselines = str(tmp_path / "baselines.json")
