@@ -8,35 +8,37 @@ reference for sign-bytes (types/canonical.go, proto/tendermint/types/canonical.p
   - non-nullable embedded messages always emitted; nullable ones omitted
     when None
   - repeated scalar numeric fields packed; repeated messages/bytes unpacked
+
+A class's `fields` list is compiled once, on the first use of that class,
+into the source of its decode, encode and `__init__` functions (as
+`dataclasses` generates `__init__`); `_Codec` holds them on the class.
+Nothing interprets `fields` per message.
 """
 
 from __future__ import annotations
 
+import keyword
+import threading
+
 from . import wire
 
-_SCALAR_DEFAULTS = {
-    "int32": 0,
-    "int64": 0,
-    "uint32": 0,
-    "uint64": 0,
-    "sint32": 0,
-    "sint64": 0,
-    "bool": False,
-    "enum": 0,
-    "sfixed64": 0,
-    "fixed64": 0,
-    "sfixed32": 0,
-    "fixed32": 0,
-    "double": 0.0,
-    "bytes": b"",
-    "string": "",
+# ftype -> (wire type, zero value, kind); the kind selects the generated code.
+_SCALARS = {
+    "int32": (wire.WIRE_VARINT, 0, "signed"),
+    "int64": (wire.WIRE_VARINT, 0, "signed"),
+    "uint32": (wire.WIRE_VARINT, 0, "unsigned"),
+    "uint64": (wire.WIRE_VARINT, 0, "unsigned"),
+    "enum": (wire.WIRE_VARINT, 0, "unsigned"),
+    "bool": (wire.WIRE_VARINT, False, "bool"),
+    "sint32": (wire.WIRE_VARINT, 0, "zigzag"),
+    "sint64": (wire.WIRE_VARINT, 0, "zigzag"),
+    "sfixed64": (wire.WIRE_FIXED64, 0, "fixed64"),
+    "fixed64": (wire.WIRE_FIXED64, 0, "fixed64"),
+    "sfixed32": (wire.WIRE_FIXED32, 0, "fixed32"),
+    "fixed32": (wire.WIRE_FIXED32, 0, "fixed32"),
+    "bytes": (wire.WIRE_BYTES, b"", "bytes"),
+    "string": (wire.WIRE_BYTES, "", "string"),
 }
-
-_VARINT_TYPES = {"int32", "int64", "uint32", "uint64", "bool", "enum"}
-_ZIGZAG_TYPES = {"sint32", "sint64"}
-_FIXED64_TYPES = {"sfixed64", "fixed64", "double"}
-_FIXED32_TYPES = {"sfixed32", "fixed32"}
-_PACKABLE = _VARINT_TYPES | _ZIGZAG_TYPES | _FIXED64_TYPES | _FIXED32_TYPES
 
 
 class Field:
@@ -58,145 +60,54 @@ class Field:
             cls = cls()  # lazy thunk for recursive schemas
         return cls
 
-    def default(self):
-        if self.repeated:
-            return []
-        if self.ftype == "message":
-            if self.always_emit:
-                return self.message_class()()
-            return None
-        return _SCALAR_DEFAULTS[self.ftype]
 
-
-def _encode_scalar(ftype: str, value) -> bytes:
-    if ftype in _VARINT_TYPES:
-        return wire.encode_varint(int(value))
-    if ftype in _ZIGZAG_TYPES:
-        return wire.encode_zigzag(int(value))
-    if ftype == "sfixed64" or ftype == "fixed64":
-        return wire.encode_fixed64(int(value))
-    if ftype == "sfixed32" or ftype == "fixed32":
-        return wire.encode_fixed32(int(value))
-    if ftype == "bytes":
-        return wire.encode_bytes(bytes(value))
-    if ftype == "string":
-        return wire.encode_bytes(value.encode("utf-8"))
-    raise TypeError(f"unknown scalar type {ftype}")
-
-
-def _wire_type(ftype: str) -> int:
-    if ftype in _VARINT_TYPES or ftype in _ZIGZAG_TYPES:
-        return wire.WIRE_VARINT
-    if ftype in _FIXED64_TYPES:
-        return wire.WIRE_FIXED64
-    if ftype in _FIXED32_TYPES:
-        return wire.WIRE_FIXED32
-    return wire.WIRE_BYTES  # bytes, string, message
+def _scalar(ftype: str):
+    try:
+        return _SCALARS[ftype]
+    except KeyError:
+        raise TypeError(f"unknown scalar type {ftype}") from None
 
 
 class Message:
     """Base class; subclasses set `fields = [Field(...), ...]`."""
 
     fields: list[Field] = []
+    _codec: _Codec | None = None
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        cls._codec = None  # a subclass never runs its parent's plan
 
     def __init__(self, **kwargs):
         cls = type(self)
-        for f in cls.fields:
-            setattr(self, f.name, kwargs.pop(f.name, None))
-            if getattr(self, f.name) is None and not (f.ftype == "message" and not f.repeated and not f.always_emit):
-                setattr(self, f.name, f.default())
-        if kwargs:
-            raise TypeError(f"{cls.__name__}: unknown fields {sorted(kwargs)}")
+        (cls._codec or _codec_of(cls)).init(self, kwargs)
 
     # -- encoding ---------------------------------------------------------
 
     def encode(self) -> bytes:
-        out = bytearray()
-        for f in sorted(type(self).fields, key=lambda f: f.number):
-            value = getattr(self, f.name)
-            out += self._encode_field(f, value)
-        return bytes(out)
+        cls = type(self)
+        return (cls._codec or _codec_of(cls)).encode(self)
 
     def encode_delimited(self) -> bytes:
         return wire.marshal_delimited(self.encode())
 
-    @staticmethod
-    def _encode_field(f: Field, value) -> bytes:
-        if f.repeated:
-            if not value:
-                return b""
-            if f.ftype in _PACKABLE:
-                payload = b"".join(_encode_scalar(f.ftype, v) for v in value)
-                return wire.encode_tag(f.number, wire.WIRE_BYTES) + wire.encode_bytes(payload)
-            out = bytearray()
-            for v in value:
-                if f.ftype == "message":
-                    out += wire.encode_tag(f.number, wire.WIRE_BYTES)
-                    out += wire.encode_bytes(v.encode())
-                else:
-                    out += wire.encode_tag(f.number, _wire_type(f.ftype))
-                    out += _encode_scalar(f.ftype, v)
-            return bytes(out)
-        if f.ftype == "message":
-            if value is None:
-                return b""
-            body = value.encode()
-            if not body and not f.always_emit:
-                # nullable-but-present empty message still emits (gogo writes
-                # tag+len for non-nil pointers); value is None when absent.
-                pass
-            return wire.encode_tag(f.number, wire.WIRE_BYTES) + wire.encode_bytes(body)
-        # proto3 zero-value omission
-        if value == f.default():
-            return b""
-        return wire.encode_tag(f.number, _wire_type(f.ftype)) + _encode_scalar(f.ftype, value)
+    @classmethod
+    def encode_field(cls, name: str, value) -> bytes:
+        """What `encode` emits for the field `name` alone when it holds `value`."""
+        return (cls._codec or _codec_of(cls)).field_encoder(name)(value)
 
     # -- decoding ---------------------------------------------------------
 
     @classmethod
     def decode(cls, buf: bytes):
-        msg = cls()
-        by_number = {f.number: f for f in cls.fields}
-        pos = 0
-        n = len(buf)
-        while pos < n:
-            num, wt, pos = wire.decode_tag(buf, pos)
-            f = by_number.get(num)
-            if f is None:
-                pos = _skip(buf, pos, wt)
-                continue
-            pos = cls._decode_field(msg, f, wt, buf, pos)
-        return msg
+        if buf.__class__ is not bytes:
+            buf = bytes(buf)  # slices of it become the `bytes` fields
+        return (cls._codec or _codec_of(cls)).decode(buf, 0, len(buf))
 
     @classmethod
     def decode_delimited(cls, buf: bytes, offset: int = 0):
         body, pos = wire.unmarshal_delimited(buf, offset)
         return cls.decode(body), pos
-
-    @staticmethod
-    def _decode_field(msg, f: Field, wt: int, buf: bytes, pos: int) -> int:
-        if f.ftype == "message":
-            body, pos = wire.decode_bytes(buf, pos)
-            sub = f.message_class().decode(body)
-            if f.repeated:
-                getattr(msg, f.name).append(sub)
-            else:
-                setattr(msg, f.name, sub)
-            return pos
-        if f.repeated and f.ftype in _PACKABLE and wt == wire.WIRE_BYTES:
-            body, pos = wire.decode_bytes(buf, pos)
-            sub = 0
-            vals = getattr(msg, f.name)
-            while sub < len(body):
-                v, sub = _decode_scalar(f.ftype, body, sub)
-                vals.append(v)
-            return pos
-        v, pos = _decode_scalar(f.ftype, buf, pos)
-        if f.repeated:
-            getattr(msg, f.name).append(v)
-        else:
-            setattr(msg, f.name, v)
-        return pos
 
     # -- niceties ---------------------------------------------------------
 
@@ -222,39 +133,271 @@ class Message:
         return type(self).decode(self.encode())
 
 
-def _decode_scalar(ftype: str, buf: bytes, pos: int):
-    if ftype in _VARINT_TYPES:
-        raw, pos = wire.decode_varint(buf, pos)
-        if ftype in ("int32", "int64"):
-            raw = wire.varint_to_int64(raw)
-            if ftype == "int32":
-                raw = int(raw)
-        elif ftype == "bool":
-            raw = bool(raw)
-        return raw, pos
-    if ftype in _ZIGZAG_TYPES:
-        return wire.decode_zigzag(buf, pos)
-    if ftype in _FIXED64_TYPES:
-        return wire.decode_fixed64(buf, pos)
-    if ftype in _FIXED32_TYPES:
-        return wire.decode_fixed32(buf, pos)
-    if ftype == "bytes":
-        return wire.decode_bytes(buf, pos)
-    if ftype == "string":
-        b, pos = wire.decode_bytes(buf, pos)
-        return b.decode("utf-8"), pos
-    raise TypeError(f"unknown scalar type {ftype}")
+# -- the per-class plan -----------------------------------------------------
+
+_compile_lock = threading.Lock()
 
 
-def _skip(buf: bytes, pos: int, wt: int) -> int:
+def _codec_of(cls) -> _Codec:
+    with _compile_lock:
+        if cls._codec is None:
+            cls._codec = _Codec(cls)
+        return cls._codec
+
+
+def _skip(buf: bytes, pos: int, end: int, wt: int) -> int:
     if wt == wire.WIRE_VARINT:
-        _, pos = wire.decode_varint(buf, pos)
-        return pos
+        return wire.decode_varint(buf, pos, end)[1]
     if wt == wire.WIRE_FIXED64:
         return pos + 8
     if wt == wire.WIRE_FIXED32:
         return pos + 4
     if wt == wire.WIRE_BYTES:
-        _, pos = wire.decode_bytes(buf, pos)
-        return pos
+        n, pos = wire.decode_varint(buf, pos, end)
+        if pos + n > end:
+            raise ValueError("truncated length-delimited field")
+        return pos + n
     raise ValueError(f"cannot skip wire type {wt}")
+
+
+class _Codec:
+    """The decode, encode and init functions generated from one class's `fields`.
+
+    `decode(buf, pos, end)` reads a message in place, so a nested message
+    is never copied out of its parent's buffer; `encode(msg)` joins the
+    message's parts once; `init(msg, kwargs)` is `Message.__init__`. A
+    sub-message class is compiled when the first message of it is met:
+    until then the name the generated code calls is a stub that compiles
+    the class and rebinds itself, which is also what lets recursive
+    schemas resolve.
+    """
+
+    def __init__(self, cls):
+        for f in cls.fields:
+            if not f.name.isidentifier() or keyword.iskeyword(f.name):
+                raise TypeError(f"{cls.__name__}: field name {f.name!r} is not an identifier")
+        self.cls = cls
+        self._field_encoders = {}
+        self._ns = {
+            "cls": cls,
+            "new": object.__new__,
+            "varint": wire.decode_varint,
+            "uvarint": wire.encode_varint,
+            "skip": _skip,
+            "fixed64": wire.decode_fixed64,
+            "fixed32": wire.decode_fixed32,
+            "ufixed64": wire.encode_fixed64,
+            "ufixed32": wire.encode_fixed32,
+            "uzigzag": wire.encode_zigzag,
+            "B1": wire.ONE_BYTE,
+            "join": b"".join,
+        }
+        for i, f in enumerate(cls.fields):
+            if f.ftype == "message":
+                self._bind_sub(i, f.message_class())
+        self.decode = self._compile("decode", self._decode_source())
+        self.encode = self._compile("encode", self._encode_source())
+        self.init = self._compile("init", self._init_source())
+
+    def _compile(self, name: str, source: str):
+        code = compile(source, f"<{self.cls.__module__}.{self.cls.__qualname__} codec>", "exec")
+        exec(code, self._ns)  # noqa: S102 - source is generated from `fields` alone
+        return self._ns.pop(name)
+
+    def _bind_sub(self, i: int, sub: type) -> None:
+        """Field i's message class as `M{i}`, and its codec's functions as
+        `dec{i}` and `enc{i}` unless the class has a `decode` or `encode`
+        of its own, which is then what the generated code calls."""
+        ns = self._ns
+        ns[f"M{i}"] = sub
+        for key, attr, own in (
+            (f"dec{i}", "decode", sub.decode.__func__ is not Message.decode.__func__),
+            (f"enc{i}", "encode", sub.encode is not Message.encode),
+        ):
+            if own:
+                continue
+
+            def stub(*args, key=key, attr=attr):
+                fn = ns[key] = getattr(sub._codec or _codec_of(sub), attr)
+                return fn(*args)
+
+            ns[key] = stub
+
+    def _absent(self, i: int, f: Field) -> str:
+        """What field i holds when nothing set it: a fresh list, a fresh
+        `always_emit` message, None for a nullable one, the scalar's zero."""
+        if f.repeated:
+            return "[]"
+        if f.ftype == "message":
+            return f"M{i}()" if f.always_emit else "None"
+        return repr(_scalar(f.ftype)[1])
+
+    def _init_source(self) -> str:
+        out = ["def init(self, kwargs):", "    pop = kwargs.pop"]
+        for i, f in enumerate(self.cls.fields):
+            absent = self._absent(i, f)
+            out.append(f"    v = pop({f.name!r}, None)")
+            out.append(f"    self.{f.name} = v" if absent == "None" else f"    self.{f.name} = {absent} if v is None else v")
+        out.append("    if kwargs:")
+        out.append("        raise TypeError(f'{cls.__name__}: unknown fields {sorted(kwargs)}')")
+        return "\n".join(out)
+
+    # -- decode -------------------------------------------------------------
+
+    def _decode_source(self) -> str:
+        fields = self.cls.fields
+        out = ["def decode(buf, pos, end):"]
+        if any(f.ftype != "message" and _scalar(f.ftype)[2] in ("fixed64", "fixed32") for f in fields):
+            out.append("    start = pos")
+        # a message the buffer does not carry is made after the loop, not before it and thrown away
+        late = {i for i, f in enumerate(fields) if f.ftype == "message" and f.always_emit and not f.repeated}
+        for i, f in enumerate(fields):
+            out.append(f"    f{i} = None" if i in late else f"    f{i} = {self._absent(i, f)}")
+        out += [
+            "    while pos < end:",
+            "        tag = buf[pos]",
+            "        if tag < 128:",
+            "            pos += 1",
+            "        else:",
+            "            tag, pos = varint(buf, pos, end)",
+        ]
+        # a field is chosen by its number alone; of two with one number the later
+        by_number = {f.number: (i, f) for i, f in enumerate(fields)}
+        if by_number:
+            out.append("        num = tag >> 3")
+        branch = "if"
+        for number in sorted(by_number):
+            out.append(f"        {branch} num == {number}:")
+            out += self._decode_field_lines(*by_number[number], " " * 12)
+            branch = "elif"
+        skip = "pos = skip(buf, pos, end, tag & 7)"
+        out += ["        else:", f"            {skip}"] if by_number else [f"        {skip}"]
+        out.append("    msg = new(cls)")
+        for i, f in enumerate(fields):
+            out.append(f"    msg.{f.name} = M{i}() if f{i} is None else f{i}" if i in late else f"    msg.{f.name} = f{i}")
+        out.append("    return msg")
+        return "\n".join(out)
+
+    def _decode_field_lines(self, i: int, f: Field, ind: str) -> list[str]:
+        if f.ftype == "message":
+            value = f"dec{i}(buf, pos, e)" if f"dec{i}" in self._ns else f"M{i}.decode(buf[pos:e])"
+            store = f"f{i}.append({value})" if f.repeated else f"f{i} = {value}"
+            return _read_length(ind, "end") + [ind + store, f"{ind}pos = e"]
+        kind = _scalar(f.ftype)[2]
+        if not f.repeated:
+            return _read_scalar(kind, ind, "end", "start", f"f{i}")
+        single = _read_scalar(kind, ind, "end", "start", "v") + [f"{ind}f{i}.append(v)"]
+        if kind in ("bytes", "string"):  # every other scalar may come packed
+            return single
+        packed = _read_length(ind + "    ", "end")
+        packed += [f"{ind}    body = pos", f"{ind}    while pos < e:"]
+        packed += _read_scalar(kind, ind + " " * 8, "e", "body", "v") + [f"{ind}        f{i}.append(v)"]
+        packed.append(f"{ind}    pos = e")
+        return [f"{ind}if tag & 7 == 2:"] + packed + [f"{ind}else:"] + [f"    {line}" for line in single]
+
+    # -- encode -------------------------------------------------------------
+
+    def _encode_source(self) -> str:
+        fields = sorted(enumerate(self.cls.fields), key=lambda item: item[1].number)
+        if not fields:
+            return 'def encode(msg):\n    return b""'
+        out = ["def encode(msg):", "    parts = []", "    add = parts.append"]
+        for i, f in fields:
+            out.append(f"    v = msg.{f.name}")
+            out += self._encode_field_lines(i, f)
+        out.append("    return join(parts)")
+        return "\n".join(out)
+
+    def field_encoder(self, name: str):
+        """encode(value) -> what `encode` emits for the field `name` alone."""
+        enc = self._field_encoders.get(name)
+        if enc is None:
+            i, f = next((i, f) for i, f in enumerate(self.cls.fields) if f.name == name)
+            lines = ["def encode_field(v):", "    parts = []", "    add = parts.append"]
+            lines += self._encode_field_lines(i, f) + ["    return join(parts)"]
+            enc = self._field_encoders[name] = self._compile("encode_field", "\n".join(lines))
+        return enc
+
+    def _encode_field_lines(self, i: int, f: Field) -> list[str]:
+        """Lines that add field i's wire bytes for the value in `v`."""
+        self._ns[f"T{i}"] = wire.encode_tag(f.number, wire.WIRE_BYTES)
+        delimited = [f"add(T{i})", "n = len(b)", "add(B1[n] if n < 128 else uvarint(n))", "add(b)"]
+
+        def each(convert: str, present: str) -> list[str]:
+            # b = convert(x) for every x of a repeated field, or for v itself
+            if f.repeated:
+                body = ["if v:", "    for x in v:", "        b = " + convert.format(x="x")]
+                body += [f"        {s}" for s in delimited]
+            else:
+                body = [f"if {present}:", "    b = " + convert.format(x="v")] + [f"    {s}" for s in delimited]
+            return [f"    {s}" for s in body]
+
+        if f.ftype == "message":
+            # a present message is emitted even when empty (gogo writes
+            # tag+len for non-nil pointers); `always_emit` only decides
+            # what an absent one defaults to.
+            if f"enc{i}" in self._ns:
+                return each(f"enc{i}({{x}}) if {{x}}.__class__ is M{i} else {{x}}.encode()", "v is not None")
+            return each("{x}.encode()", "v is not None")
+        wt, zero, kind = _scalar(f.ftype)
+        if kind == "string":
+            return each("{x}.encode('utf-8')", f"v != {zero!r}")
+        if kind == "bytes":
+            return each("{x} if {x}.__class__ is bytes else bytes({x})", f"v != {zero!r}")
+        payload = {
+            "signed": "uvarint({x} if {x}.__class__ is int else int({x}))",
+            "unsigned": "uvarint({x} if {x}.__class__ is int else int({x}))",
+            "bool": "uvarint(int({x}))",
+            "zigzag": "uzigzag(int({x}))",
+            "fixed64": "ufixed64(int({x}))",
+            "fixed32": "ufixed32(int({x}))",
+        }[kind]
+        if f.repeated:
+            body = ["if v:", f"    b = join([{payload.format(x='x')} for x in v])"] + [f"    {s}" for s in delimited]
+        else:
+            self._ns[f"S{i}"] = wire.encode_tag(f.number, wt)
+            body = [f"if v != {zero!r}:", f"    add(S{i})", f"    add({payload.format(x='v')})"]
+        return [f"    {s}" for s in body]
+
+
+def _read_varint(ind: str, var: str, end: str) -> list[str]:
+    """One byte inline, the general loop for the rest; 128 stands for 'none left'."""
+    return [
+        f"{ind}{var} = buf[pos] if pos < {end} else 128",
+        f"{ind}if {var} < 128:",
+        f"{ind}    pos += 1",
+        f"{ind}else:",
+        f"{ind}    {var}, pos = varint(buf, pos, {end})",
+    ]
+
+
+def _read_length(ind: str, end: str) -> list[str]:
+    return _read_varint(ind, "n", end) + [
+        f"{ind}e = pos + n",
+        f"{ind}if e > {end}:",
+        f"{ind}    raise ValueError('truncated length-delimited field')",
+    ]
+
+
+def _read_scalar(kind: str, ind: str, end: str, start: str, v: str) -> list[str]:
+    """Lines that read one scalar at `pos` into `v`. `start` and `end` bound
+    the buffer the scalar lies in: a short fixed-width read raises what
+    `struct` raises on that buffer alone."""
+    if kind in ("fixed64", "fixed32"):
+        return [
+            f"{ind}if pos + {8 if kind == 'fixed64' else 4} > {end}:",
+            f"{ind}    {kind}(buf[{start}:{end}], pos - {start})",
+            f"{ind}{v}, pos = {kind}(buf, pos)",
+        ]
+    if kind in ("bytes", "string"):
+        tail = ".decode('utf-8')" if kind == "string" else ""
+        return _read_length(ind, end) + [f"{ind}{v} = buf[pos:e]{tail}", f"{ind}pos = e"]
+    lines = _read_varint(ind, v, end)
+    if kind == "signed":  # int32 too: a negative one is a sign-extended 10-byte varint
+        lines.append(f"{ind}    if {v} > 0x7FFFFFFFFFFFFFFF:")
+        lines.append(f"{ind}        {v} -= 0x10000000000000000")
+    elif kind == "bool":
+        lines.append(f"{ind}{v} = {v} != 0")
+    elif kind == "zigzag":
+        lines.append(f"{ind}{v} = ({v} >> 1) ^ -({v} & 1)")
+    return lines

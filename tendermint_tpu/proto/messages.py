@@ -7,6 +7,7 @@ types/vote_test.go vectors).
 
 from __future__ import annotations
 
+from . import wire
 from .message import Field, Message
 
 # -- enums (proto/tendermint/types/types.proto) ---------------------------
@@ -78,19 +79,15 @@ class PublicKey(Message):
             raise TypeError(f"PublicKey: unknown fields {sorted(kwargs)}")
 
     def encode(self) -> bytes:
-        from . import wire
-
         # oneof: emit whichever arm is set, even if empty bytes.
-        for num, name in ((1, "ed25519"), (2, "secp256k1"), (3, "sr25519")):
+        for tag, name in ((b"\x0a", "ed25519"), (b"\x12", "secp256k1"), (b"\x1a", "sr25519")):
             v = getattr(self, name)
             if v is not None:
-                return wire.encode_tag(num, wire.WIRE_BYTES) + wire.encode_bytes(bytes(v))
+                return tag + wire.encode_bytes(bytes(v))
         return b""
 
     @classmethod
     def decode(cls, buf: bytes):
-        from . import wire
-
         msg = cls()
         pos = 0
         while pos < len(buf):

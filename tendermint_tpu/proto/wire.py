@@ -17,29 +17,40 @@ WIRE_FIXED32 = 5
 
 _U64_MASK = (1 << 64) - 1
 
+# every one-byte string: a varint under 128, a short length, a low tag
+ONE_BYTE = [bytes((i,)) for i in range(256)]
+
 
 def encode_varint(value: int) -> bytes:
     """Encode an unsigned (or two's-complement negative int64) varint."""
     if value < 0:
         value &= _U64_MASK  # negative int64 → 10-byte varint, proto semantics
-    out = bytearray()
-    while True:
-        b = value & 0x7F
+    if value < 0x80:
+        return ONE_BYTE[value]
+    if value < 0x4000:
+        return bytes((value & 0x7F | 0x80, value >> 7))
+    out = []
+    while value > 0x7F:
+        out.append(value & 0x7F | 0x80)
         value >>= 7
-        if value:
-            out.append(b | 0x80)
-        else:
-            out.append(b)
-            return bytes(out)
+    out.append(value)
+    return bytes(out)
 
 
-def decode_varint(buf: bytes, offset: int = 0) -> tuple[int, int]:
-    """Decode a varint at `offset`; returns (value, new_offset)."""
+def decode_varint(buf: bytes, offset: int = 0, end: int | None = None) -> tuple[int, int]:
+    """Decode a varint at `offset`; returns (value, new_offset). The
+    buffer is taken to stop at `end` (its length when None)."""
+    if end is None:
+        end = len(buf)
+    if offset < end:
+        b = buf[offset]
+        if b < 0x80:
+            return b, offset + 1
     result = 0
     shift = 0
     pos = offset
     while True:
-        if pos >= len(buf):
+        if pos >= end:
             raise ValueError("truncated varint")
         b = buf[pos]
         pos += 1

@@ -9,7 +9,6 @@ from __future__ import annotations
 
 from ..proto import messages as pb
 from ..proto import wire
-from ..proto.message import Message, _encode_scalar
 
 
 def canonicalize_block_id(bid: pb.BlockID | None) -> pb.CanonicalBlockID | None:
@@ -83,29 +82,26 @@ def vote_sign_bytes_template(chain_id: str, type_: int, height: int, round_: int
     dominates at 10k-validator commit scale (types/validation.py's
     batch loop). Parity is pinned by tests/test_types.py.
     """
-    fields = {f.name: f for f in pb.CanonicalVote.fields}
-    proto = pb.CanonicalVote(
-        type=type_,
-        height=height,
-        round=round_,
-        block_id=canonicalize_block_id(block_id),
-        timestamp=pb.Timestamp(),
-        chain_id=chain_id,
-    )
+    encode_field = pb.CanonicalVote.encode_field
     prefix = b"".join(
-        Message._encode_field(fields[name], getattr(proto, name))
-        for name in ("type", "height", "round", "block_id")
+        encode_field(name, value)
+        for name, value in (
+            ("type", type_),
+            ("height", height),
+            ("round", round_),
+            ("block_id", canonicalize_block_id(block_id)),
+        )
     )
-    suffix = Message._encode_field(fields["chain_id"], chain_id)
-    ts_tag = wire.encode_tag(fields["timestamp"].number, wire.WIRE_BYTES)
+    suffix = encode_field("chain_id", chain_id)
+    ts_tag = b"\x2a"  # CanonicalVote.timestamp: field 5, length-delimited
     encode_varint = wire.encode_varint
 
     def make(seconds: int, nanos: int) -> bytes:
         tsb = b""
         if seconds:
-            tsb += b"\x08" + _encode_scalar("int64", seconds)
+            tsb += b"\x08" + encode_varint(seconds)
         if nanos:
-            tsb += b"\x10" + _encode_scalar("int32", nanos)
+            tsb += b"\x10" + encode_varint(nanos)
         body = prefix + ts_tag + encode_varint(len(tsb)) + tsb + suffix
         return encode_varint(len(body)) + body
 
