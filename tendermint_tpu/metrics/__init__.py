@@ -776,6 +776,21 @@ class EngineMetrics:
         self.autotuned = reg.gauge(
             f"{ns}_autotuned", "1 after the autotune microprobe updated a cutover"
         )
+        self.autotune_host_sig_seconds = reg.gauge(
+            f"{ns}_autotune_host_sig_seconds",
+            "Host price the autotune probe drew the cutovers from: seconds a "
+            "signature, single verifications one at a time (unset: no probe ran)",
+        )
+        self.autotune_launch_seconds = reg.gauge(
+            f"{ns}_autotune_launch_seconds",
+            "Launch price the autotune probe drew the cutovers from: seconds of a "
+            "warm 8-row per-signature launch end to end (unset: no probe ran)",
+        )
+        self.autotune_host_route_sig_seconds = reg.gauge(
+            f"{ns}_autotune_host_route_sig_seconds",
+            "Price of the host route as the engine runs it, measured beside the "
+            "probe and deciding nothing: seconds a signature of one 64-row batch",
+        )
         self.autotune_failures = reg.counter(
             f"{ns}_autotune_failures_total",
             "Autotune microprobes that raised (the default cutovers stayed in force)",
@@ -795,6 +810,17 @@ class EngineMetrics:
             f"{ns}_kernel_launches_total",
             "Device kernel dispatches by kernel (cache fills included)",
             labels=("kernel",),
+        )
+        self.pk_cache_rows = reg.counter(
+            f"{ns}_pk_cache_rows_total",
+            "Rows whose public key was looked up in the device pubkey cache",
+            labels=("plane",),
+        )
+        self.pk_cache_missed_rows = reg.counter(
+            f"{ns}_pk_cache_missed_rows_total",
+            "Looked-up rows whose key's table was not on the device: built "
+            "before the launch, or the batch fell back to the uncached kernel",
+            labels=("plane",),
         )
 
     def observe_path(self, plane: str, path: str, bools) -> None:

@@ -80,6 +80,9 @@ def maybe_autotune() -> None:
     measure (a) the host per-signature verify time and (b) the warm
     end-to-end latency of a tiny device launch, and set the cutovers to
     the batch size where the device launch actually pays for itself.
+    Both prices, and that of the host route as the engine runs it (one
+    coalesced batch through the C loop; it decides nothing yet), are
+    published as gauges (engine_autotune_*_seconds).
     The probe runs under the lock on the first router to arrive — the
     engine's dispatch worker, or a direct-dispatch caller — and every
     other router waits for it, so no batch is routed while the
@@ -135,6 +138,14 @@ def _autotune_probe(dev_pinned: bool, msm_pinned: bool) -> None:
     for _ in range(3):
         V.verify_batch(*jobs)
     t_launch = (_time.perf_counter() - t0) / 3
+    # The host route as _dispatch_group runs it (the C loop over one
+    # coalesced batch), priced beside the two prices above and deciding
+    # nothing yet: the first call loads libcrypto, the second is timed.
+    rows = ([pk] * 64, [msg] * 64, [sig] * 64)
+    _HOST_VERIFY["ed25519"](*rows)
+    t0 = _time.perf_counter()
+    _HOST_VERIFY["ed25519"](*rows)
+    t_host_route = (_time.perf_counter() - t0) / 64
     cutover = 8
     while cutover * t_host < t_launch and cutover < 4096:
         cutover *= 2
@@ -149,6 +160,9 @@ def _autotune_probe(dev_pinned: bool, msm_pinned: bool) -> None:
     m.autotuned.set(1)
     m.device_batch_cutover.set(ed.DEVICE_BATCH_CUTOVER)
     m.msm_batch_cutover.set(ed.MSM_BATCH_CUTOVER)
+    m.autotune_host_sig_seconds.set(t_host)
+    m.autotune_launch_seconds.set(t_launch)
+    m.autotune_host_route_sig_seconds.set(t_host_route)
 
 
 # ------------------------------------------------------------------- engine

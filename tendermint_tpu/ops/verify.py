@@ -219,6 +219,7 @@ class PubkeyCache:
             with self._lock:
                 distinct = list(dict.fromkeys(pubkeys))
                 if len(distinct) > self.capacity:
+                    self._count(len(pubkeys), len(pubkeys))
                     return None, self.tables, self.oks
                 waits = {
                     self._pending[pk] for pk in distinct if pk in self._pending
@@ -236,6 +237,7 @@ class PubkeyCache:
                         slots = np.fromiter(
                             (self._lru[pk] for pk in pubkeys), np.int32
                         )
+                        self._count(len(pubkeys), 0)
                         return slots, self.tables, self.oks
                     free = self.capacity - len(self._lru)
                     evictable = [
@@ -247,6 +249,7 @@ class PubkeyCache:
                         # every eviction candidate is mid-fill by other
                         # threads: fall back to the uncached kernel
                         # instead of waiting on unrelated fills
+                        self._count(len(pubkeys), len(pubkeys))
                         return None, self.tables, self.oks
                     for pk in evictable[:need]:
                         del self._lru[pk]
@@ -305,7 +308,17 @@ class PubkeyCache:
                 slots = np.fromiter((self._lru[pk] for pk in pubkeys), np.int32)
                 tables, oks = self.tables, self.oks
             event.set()
+            built = set(missing)
+            self._count(len(pubkeys), sum(pk in built for pk in pubkeys))
             return slots, tables, oks
+
+    def _count(self, rows: int, missed: int) -> None:
+        """One batch's rows looked up, and those of them whose table was
+        not on the device (all of them where the batch fell back to the
+        uncached kernel): a hit share can be read over any interval."""
+        m = _engine_metrics()
+        m.pk_cache_rows.add(rows, self.plane)
+        m.pk_cache_missed_rows.add(missed, self.plane)
 
     def _unpin(self, keys) -> None:
         """Drop one eviction pin per key (lock held by caller)."""
@@ -512,8 +525,9 @@ def dispatch_cached(cache, prepare, cached_kernel, uncached_async, pubkeys, msgs
         return None, np.zeros((0,), bool), 0, 0
     fid = _devobs.next_flow() if _devobs.enabled() else 0
     with _trace.span("ops.verify_dispatch", "ops", kernel="bitmap_cached", rows=n, flow=fid) as sp:
-        keys = [pk if len(pk) == 32 else b"\x00" * 32 for pk in pubkeys]
-        slots, tables, oks = cache.ensure_snapshot(keys)
+        with _trace.span("ops.pk_cache_lookup", "ops", rows=n):
+            keys = [pk if len(pk) == 32 else b"\x00" * 32 for pk in pubkeys]
+            slots, tables, oks = cache.ensure_snapshot(keys)
         if slots is None:
             sp.annotate(cache="overflow")
             return uncached_async(pubkeys, msgs, sigs)

@@ -49,7 +49,9 @@ blocksync.starved / blocksync.settle (both retrospective),
 engine.submit / engine.coalesce / engine.dispatch /
 engine.host_verify / engine.collect, ops.verify_dispatch /
 ops.msm_dispatch with ops.prep / ops.rlc_scalars / ops.launch under
-them, ops.pk_cache_fill, device.h2d (the staging calls) /
+them, ops.pk_cache_lookup (the cached kernel's slot lookup) with
+ops.pk_cache_fill (a miss's table build) under it, device.h2d (the
+staging calls) /
 device.wait (the collect thread blocked on the kernel) / device.d2h
 (the read-back alone) / device.compile, sharded.verify,
 mempool.admit_batch (coalesced tx admission: n/admitted/failed),
