@@ -149,7 +149,10 @@ DEVICE_BATCH_CUTOVER = int(os.environ.get("TM_TPU_BATCH_CUTOVER", "64"))
 # runs first and the per-signature bitmap kernel only on failure — the
 # reference's two-phase shape (types/validation.go:245-255). Below it
 # the MSM's Horner/reduce tail isn't amortized. TM_TPU_MSM=off disables
-# the fast path entirely. Autotuned like DEVICE_BATCH_CUTOVER above.
+# the fast path entirely. The env value pins it; otherwise it is a
+# DEFAULT that ops/engine.maybe_autotune replaces, on a device kind it
+# has an entry for, with the measured crossover of the two device
+# programs (ops/engine.MSM_CUTOVER_ROWS): no probe, a table look-up.
 MSM_BATCH_CUTOVER = int(os.environ.get("TM_TPU_MSM_CUTOVER", "256"))
 
 

@@ -771,19 +771,20 @@ class EngineMetrics:
         )
         self.msm_batch_cutover = reg.gauge(
             f"{ns}_msm_batch_cutover",
-            "Live two-phase-MSM cutover (env pin or autotune result)",
+            "Live two-phase-MSM cutover (env pin, else the measured crossover of the "
+            "two device programs for the device kind in use, else the default)",
         )
         self.autotuned = reg.gauge(
             f"{ns}_autotuned", "1 after the autotune microprobe updated a cutover"
         )
         self.autotune_host_sig_seconds = reg.gauge(
             f"{ns}_autotune_host_sig_seconds",
-            "Host price the autotune probe drew the cutovers from: seconds a "
+            "Host price the autotune probe drew the device cutover from: seconds a "
             "signature, single verifications one at a time (unset: no probe ran)",
         )
         self.autotune_launch_seconds = reg.gauge(
             f"{ns}_autotune_launch_seconds",
-            "Launch price the autotune probe drew the cutovers from: seconds of a "
+            "Launch price the autotune probe drew the device cutover from: seconds of a "
             "warm 8-row per-signature launch end to end (unset: no probe ran)",
         )
         self.autotune_host_route_sig_seconds = reg.gauge(

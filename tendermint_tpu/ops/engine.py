@@ -24,10 +24,14 @@ arxiv 2112.02229). This module is that pipeline:
                  threaded across cores, with the ZIP-215 oracle
                  re-checking only rows OpenSSL rejects — byte-identical
                  acceptance to the serial path.
-  autotune     — DEVICE_BATCH_CUTOVER / MSM_BATCH_CUTOVER come from a
-                 one-shot microprobe of real launch latency vs host
-                 verify rate when an accelerator is present, finished
-                 before the first batch is routed (env still wins).
+  autotune     — DEVICE_BATCH_CUTOVER comes from a one-shot microprobe
+                 of real launch latency vs host verify rate when an
+                 accelerator is present, finished before the first
+                 batch is routed. MSM_BATCH_CUTOVER, the choice between
+                 the two device programs, is not probed (one program
+                 load costs 10-30 s): it is looked up by device kind in
+                 MSM_CUTOVER_ROWS, the measured crossover of the two
+                 programs' launch prices (env still wins for both).
 
 Gating: TM_TPU_ENGINE = auto (default, engine on) | on | off. `off`
 restores the direct per-caller dispatch paths; acceptance is
@@ -78,8 +82,10 @@ def maybe_autotune() -> None:
     routed. When the device plane is in use on a real accelerator and
     the env didn't pin TM_TPU_BATCH_CUTOVER / TM_TPU_MSM_CUTOVER,
     measure (a) the host per-signature verify time and (b) the warm
-    end-to-end latency of a tiny device launch, and set the cutovers to
-    the batch size where the device launch actually pays for itself.
+    end-to-end latency of a tiny device launch, and set the device
+    cutover to the batch size where the device launch actually pays for
+    itself; the MSM cutover is read from MSM_CUTOVER_ROWS for the device
+    kind in use, with no launch of its own.
     Both prices, and that of the host route as the engine runs it (one
     coalesced batch through the C loop; it decides nothing yet), are
     published as gauges (engine_autotune_*_seconds).
@@ -117,6 +123,27 @@ def maybe_autotune() -> None:
             _AUTOTUNE["done"] = True
 
 
+# The padded batch size from which one launch of the MSM program, from
+# submission to verdicts, is cheaper than one launch of the
+# per-signature program behind the pubkey cache, by device kind
+# (jax.devices()[0].device_kind): measured, not derived. An entry is one
+# run of scripts/route_prices.py on that kind; PERF.md section 6 prints
+# the prices it rests on. On a TPU v5e the MSM's launch was the dearer
+# one at every size from 64 rows to MAX_COALESCE_ROWS (14.3 against 5.1
+# ms at 128 rows, 59.3 against 57.7 at 8192), so its entry is the first
+# padded size past what was measured. A kind without an entry keeps
+# crypto/ed25519.py's default. sr25519 shares the number, as it always
+# has: it has never run on a chip and no benchmark cell routes it, so it
+# has no prices of its own.
+MSM_CUTOVER_ROWS = {"TPU v5 lite": 16384}
+
+
+def _device_kind() -> str:
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
 def _autotune_probe(dev_pinned: bool, msm_pinned: bool) -> None:
     from ..crypto import ed25519 as ed
 
@@ -152,10 +179,11 @@ def _autotune_probe(dev_pinned: bool, msm_pinned: bool) -> None:
     if not dev_pinned:
         ed.DEVICE_BATCH_CUTOVER = cutover
     if not msm_pinned:
-        # the MSM's Horner/reduce tail is a roughly constant extra
-        # launch cost; it amortizes ~4x past the point a plain
-        # launch does
-        ed.MSM_BATCH_CUTOVER = max(64, min(4 * cutover, 8192))
+        # none of the prices above decides this: the table's entry for
+        # the device kind, as the smallest batch that pads to it
+        entry = MSM_CUTOVER_ROWS.get(_device_kind())
+        if entry is not None:
+            ed.MSM_BATCH_CUTOVER = entry // 2 + 1
     m = _engine_metrics()
     m.autotuned.set(1)
     m.device_batch_cutover.set(ed.DEVICE_BATCH_CUTOVER)

@@ -255,6 +255,29 @@ def _unpinned_probe(monkeypatch):
     monkeypatch.setattr(ed, "MSM_BATCH_CUTOVER", 256)
 
 
+def _probe_prices(monkeypatch, launch: float) -> None:
+    """The probe's clock and the three things it times, faked: a host
+    verification takes 1 unit, the tiny launch `launch` units, the host
+    route's 64-row batch 3.2."""
+    import types
+
+    from tendermint_tpu.ops import verify as V
+
+    clock = [0.0]
+
+    def tick(dt, result=True):
+        def fake(*a, **k):
+            clock[0] += dt
+            return result
+        return fake
+
+    monkeypatch.setattr(ed, "_single_verify", tick(1.0))
+    monkeypatch.setattr(V, "verify_batch", tick(launch))
+    monkeypatch.setitem(E._HOST_VERIFY, "ed25519", tick(3.2, [True] * 64))
+    monkeypatch.setattr(E, "_time", types.SimpleNamespace(
+        perf_counter=lambda: clock[0], monotonic=time.monotonic))
+
+
 def test_autotune_probe_failure_is_counted_not_swallowed(monkeypatch):
     """A probe launch that fails on the device leaves the defaults in
     force, once, and says so: logged and counted."""
@@ -280,25 +303,11 @@ def test_autotune_finishes_before_the_first_batch_is_routed(monkeypatch):
     """The probe runs on the dispatch worker ahead of the first group:
     submit() does not wait for it, and no batch is routed under
     cutovers that are about to change."""
-    import types
-
-    from tendermint_tpu.ops import verify as V
-
     _unpinned_probe(monkeypatch)
-    clock = [0.0]
-
-    def tick(dt):
-        def fake(*a, **k):
-            clock[0] += dt
-            return True
-        return fake
-
     # 1 time unit per host verify, 20 per tiny launch: the launch pays
-    # for itself at 32 rows (8, 16 < 20 <= 32), MSM at 4x that
-    monkeypatch.setattr(ed, "_single_verify", tick(1.0))
-    monkeypatch.setattr(V, "verify_batch", tick(20.0))
-    monkeypatch.setattr(E, "_time", types.SimpleNamespace(
-        perf_counter=lambda: clock[0], monotonic=time.monotonic))
+    # for itself at 32 rows (8, 16 < 20 <= 32); the MSM cutover is the
+    # table's, and this device kind has no entry: the default stays
+    _probe_prices(monkeypatch, launch=20.0)
     seen = []
     real = E.VerifyEngine._dispatch_group
 
@@ -310,7 +319,60 @@ def test_autotune_finishes_before_the_first_batch_is_routed(monkeypatch):
     monkeypatch.setitem(E._HOST_VERIFY, "ed25519", lambda pks, msgs, sigs: [True] * len(sigs))
     handle = E.get_engine().submit("ed25519", [b"k" * 32] * 2, [b"m"] * 2, [b"s" * 64] * 2)
     assert handle.result(timeout=60) == [True, True]
-    assert seen == [(32, 128, True)]
+    assert seen == [(32, 256, True)]
+
+
+# Device kind -> its entry in the routing cases: the chip the table's
+# entry was measured on, a kind given a 512-row entry for the test, and
+# one the table has never heard of (crypto/ed25519.py's default stays).
+_ENTRIES = {"TPU v5 lite": E.MSM_CUTOVER_ROWS["TPU v5 lite"], "crossing at 512": 512,
+            "unheard of": None}
+
+
+@pytest.mark.parametrize("kind,rows", [
+    (kind, rows) for kind in _ENTRIES for rows in (51, 101, 334, 667, 1000)
+] + [("crossing at 512", 256), ("crossing at 512", 257), ("crossing at 512", 512),
+     ("crossing at 512", 2048), ("TPU v5 lite", 8192), ("unheard of", 256)])
+def test_a_device_batch_takes_the_program_the_table_says(monkeypatch, kind, rows):
+    """The choice between the two device programs is the measured
+    crossover of the device kind in use, by the batch's padded size:
+    the probe draws the device cutover as it did and leaves the MSM
+    cutover to MSM_CUTOVER_ROWS; a kind without an entry keeps the
+    module default. The kernels are stubbed: the route is under test."""
+    from tendermint_tpu.metrics import engine_metrics
+    from tendermint_tpu.ops import msm as M
+    from tendermint_tpu.ops import verify as V
+
+    _unpinned_probe(monkeypatch)
+    monkeypatch.setattr(E, "_device_kind", lambda: kind)
+    entry = _ENTRIES[kind]
+    if entry is None:
+        want_msm = rows >= 256
+    else:
+        monkeypatch.setitem(E.MSM_CUTOVER_ROWS, kind, entry)
+        want_msm = V._pad_pow2(rows) >= entry
+    want = "two_phase_msm" if want_msm else "bitmap"
+    _probe_prices(monkeypatch, launch=8.0)  # what 8 host verifications cost: a device cutover of 8
+    monkeypatch.setattr(V, "verify_batch_cached_async", lambda pks, msgs, sigs: len(sigs))
+    monkeypatch.setattr(V, "collect", lambda n: [True] * n)
+    monkeypatch.setattr(M, "verify_batch_rlc_async", lambda pks, msgs, sigs: len(sigs))
+    monkeypatch.setattr(M, "collect_rlc", lambda n: True)
+
+    def path_rows():
+        return {labels["path"]: v for _, labels, v in engine_metrics().path_rows.samples()
+                if labels["plane"] == "ed25519" and labels["status"] == "accept"}
+
+    before = path_rows()
+    handle = E.get_engine().submit("ed25519", [b"k" * 32] * rows, [b"m"] * rows,
+                                   [b"s" * 64] * rows)
+    assert handle.result(timeout=60) == [True] * rows
+    grown = {p: v - before.get(p, 0.0) for p, v in path_rows().items() if v != before.get(p, 0.0)}
+    assert grown == {want: rows}
+    assert ed.DEVICE_BATCH_CUTOVER == 8
+    assert isinstance(ed.MSM_BATCH_CUTOVER, int)
+    assert (ed.MSM_BATCH_CUTOVER == 256) == (entry is None)
+    # the direct-dispatch copies compare the same number the same way
+    assert (rows >= ed.MSM_BATCH_CUTOVER) == want_msm
 
 
 # ------------------------------------------- ADVICE r5 regression pins

@@ -9,8 +9,6 @@ probe publishes.
 import os
 import random
 import sys
-import time
-import types
 
 import pytest
 
@@ -29,7 +27,7 @@ from tendermint_tpu.proto import messages as pb
 from tendermint_tpu.types.light_block import LightBlock
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from test_engine import _unpinned_probe  # noqa: E402
+from test_engine import _probe_prices, _unpinned_probe  # noqa: E402
 
 # 9 equal validators: more than 1/3 is a 4-signature batch, more than
 # 2/3 a 7-signature one, both padded to the 8-row programs that
@@ -168,25 +166,16 @@ def test_the_prices_stay_unset_without_an_accelerator(unpriced, monkeypatch):
     assert (ed.DEVICE_BATCH_CUTOVER, ed.MSM_BATCH_CUTOVER) == before
 
 
-def test_a_probe_publishes_the_prices_it_drew_the_cutovers_from(unpriced, monkeypatch):
+def test_a_probe_publishes_the_prices_it_drew_the_device_cutover_from(unpriced, monkeypatch):
     _unpinned_probe(monkeypatch)
-    clock = [0.0]
-
-    def tick(dt, result=True):
-        def fake(*a, **k):
-            clock[0] += dt
-            return result
-        return fake
-
-    # a host verification of 1 unit, a launch of 20: the cutovers are
-    # drawn as before (32, 128); the host route's 64-row batch takes 3.2
-    monkeypatch.setattr(ed, "_single_verify", tick(1.0))
-    monkeypatch.setattr(V, "verify_batch", tick(20.0))
-    monkeypatch.setitem(E._HOST_VERIFY, "ed25519", tick(3.2, [True] * 64))
-    monkeypatch.setattr(E, "_time", types.SimpleNamespace(
-        perf_counter=lambda: clock[0], monotonic=time.monotonic))
+    # a host verification of 1 unit, a launch of 20: the device cutover
+    # drawn from them is 32; the MSM cutover is left to the table of
+    # measured crossovers (no entry for this device kind: the default
+    # stays); the host route's 64-row batch takes 3.2
+    _probe_prices(monkeypatch, launch=20.0)
     E.maybe_autotune()
-    assert (ed.DEVICE_BATCH_CUTOVER, ed.MSM_BATCH_CUTOVER) == (32, 128)
+    assert E._device_kind() not in E.MSM_CUTOVER_ROWS
+    assert (ed.DEVICE_BATCH_CUTOVER, ed.MSM_BATCH_CUTOVER) == (32, 256)
     prices = _prices()
     assert prices["autotune_host_sig_seconds"] == [pytest.approx(1.0)]
     assert prices["autotune_launch_seconds"] == [pytest.approx(20.0)]
