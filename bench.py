@@ -1039,40 +1039,29 @@ def bench_mempool(floods=(1000, 10000, 50000)):
     EngineTxPreVerifier()([signed[0]])
     from tendermint_tpu.perf import Samples
 
-    rates = {}
-    s_signed = None
-    for mode, env_val in (("engine_on", "auto"), ("engine_off", "off")):
-        prior = os.environ.get("TM_TPU_ENGINE")
-        os.environ["TM_TPU_ENGINE"] = env_val
-        try:
-            reps = []
-            for _ in range(BENCH_REPEATS):
-                pool = mk_pool(
-                    LocalClient(KVStoreApplication()), n_signed,
-                    pre_verify=EngineTxPreVerifier(),
-                )
-                t0 = time.perf_counter()
-                out = pool.check_tx_batch(signed)
-                reps.append(n_signed / (time.perf_counter() - t0))
-                assert all(not isinstance(o, Exception) and o.is_ok for o in out)
-            s = Samples(reps)
-            if mode == "engine_on":
-                s_signed = s
-            rates[f"batched_{mode}"] = s.median
-            pool = mk_pool(
-                LocalClient(KVStoreApplication()), n_signed,
-                pre_verify=EngineTxPreVerifier(),
-            )
-            sample = signed[:256]
-            t0 = time.perf_counter()
-            for tx in sample:
-                pool.check_tx(tx)
-            rates[f"per_tx_{mode}"] = len(sample) / (time.perf_counter() - t0)
-        finally:
-            if prior is None:
-                os.environ.pop("TM_TPU_ENGINE", None)
-            else:
-                os.environ["TM_TPU_ENGINE"] = prior
+    reps = []
+    for _ in range(BENCH_REPEATS):
+        pool = mk_pool(
+            LocalClient(KVStoreApplication()), n_signed,
+            pre_verify=EngineTxPreVerifier(),
+        )
+        t0 = time.perf_counter()
+        out = pool.check_tx_batch(signed)
+        reps.append(n_signed / (time.perf_counter() - t0))
+        assert all(not isinstance(o, Exception) and o.is_ok for o in out)
+    s_signed = Samples(reps)
+    pool = mk_pool(
+        LocalClient(KVStoreApplication()), n_signed,
+        pre_verify=EngineTxPreVerifier(),
+    )
+    sample = signed[:256]
+    t0 = time.perf_counter()
+    for tx in sample:
+        pool.check_tx(tx)
+    rates = {
+        "batched_engine_on": s_signed.median,
+        "per_tx_engine_on": len(sample) / (time.perf_counter() - t0),
+    }
     _log(
         "mempool signed flood (1024 sig-txs): "
         + ", ".join(f"{k} {v:,.0f} tx/s" for k, v in sorted(rates.items()))
@@ -1088,13 +1077,13 @@ def bench_mempool(floods=(1000, 10000, 50000)):
                 "value": round(rates["batched_engine_on"], 1),
                 "unit": "tx/sec admitted (signed flood, engine-coalesced pre-verify)",
                 "vs_baseline": round(
-                    rates["batched_engine_on"] / rates["per_tx_engine_off"], 3
+                    rates["batched_engine_on"] / rates["per_tx_engine_on"], 3
                 ),
                 "mad": round(s_signed.mad, 1),
                 "n_samples": len(s_signed),
                 "flood": n_signed,
                 "mode": "batched_engine_on",
-                "per_tx_baseline": round(rates["per_tx_engine_off"], 1),
+                "per_tx_baseline": round(rates["per_tx_engine_on"], 1),
             }
         ),
         flush=True,
@@ -1465,28 +1454,18 @@ def main():
         from tendermint_tpu.ops import msm as M
 
         pks, msgs, sigs = (x[:best_batch] for x in jobs)
-        # cached vs uncached phase-1 follows the production gate
-        from tendermint_tpu.crypto.ed25519 import (
-            _msm_cache_enabled,
-            _pk_cache_enabled,
-        )
-
-        if _pk_cache_enabled() and _msm_cache_enabled():
-            dispatch_msm = M.verify_batch_rlc_cached_async
-        else:
-            dispatch_msm = M.verify_batch_rlc_async
         try:
             from tendermint_tpu.perf import Samples
 
             _flight_mark("msm")
             msm_rates = []
             with stage_deadline(min(_remaining() - 15, 300)):
-                h = dispatch_msm(pks, msgs, sigs)
+                h = M.verify_batch_rlc_async(pks, msgs, sigs)
                 assert M.collect_rlc(h), "MSM rejected valid batch (warm-up)"
                 for _ in range(BENCH_REPEATS):
                     t0 = time.perf_counter()
                     inflight = [
-                        dispatch_msm(pks, msgs, sigs) for _ in range(PIPELINE_ITERS)
+                        M.verify_batch_rlc_async(pks, msgs, sigs) for _ in range(PIPELINE_ITERS)
                     ]
                     oks = [M.collect_rlc(x) for x in inflight]
                     dt = (time.perf_counter() - t0) / PIPELINE_ITERS
@@ -1496,10 +1475,7 @@ def main():
             _log(f"batch {best_batch} msm: {s.format(0)} sigs/s pipelined")
             _perf_record(
                 "msm", "ed25519_msm_throughput", "sigs/sec/chip", s,
-                params={
-                    "batch": best_batch,
-                    "cached": dispatch_msm is M.verify_batch_rlc_cached_async,
-                },
+                params={"batch": best_batch, "cached": False},
             )
             _save_stage_trace("msm")
             if s.median > best:
@@ -1549,9 +1525,7 @@ def main():
     # Stage 7: coalesced multi-caller throughput through the unified
     # async verification engine — the first engine-plane metric:
     # coalesced device launches. Non-final line.
-    from tendermint_tpu.ops import engine as _engine
-
-    if _engine.engine_enabled() and _remaining() > 45:
+    if _remaining() > 45:
         try:
             from tendermint_tpu.perf import Samples
 

@@ -148,29 +148,13 @@ DEVICE_BATCH_CUTOVER = int(os.environ.get("TM_TPU_BATCH_CUTOVER", "64"))
 # kernel (ops/msm.py — ONE combined equation, doublings amortized away)
 # runs first and the per-signature bitmap kernel only on failure — the
 # reference's two-phase shape (types/validation.go:245-255). Below it
-# the MSM's Horner/reduce tail isn't amortized. TM_TPU_MSM=off disables
-# the fast path entirely. The env value pins it; otherwise it is a
+# the MSM's Horner/reduce tail isn't amortized. The env value pins it
+# (a value past any batch turns the route off); otherwise it is a
 # DEFAULT that ops/engine.maybe_autotune replaces, on a device kind it
 # has an entry for, with the measured crossover of the two device
 # programs (ops/engine.MSM_CUTOVER_ROWS): no probe, a table look-up.
 MSM_BATCH_CUTOVER = int(os.environ.get("TM_TPU_MSM_CUTOVER", "256"))
 
-
-def _msm_enabled() -> bool:
-    return os.environ.get("TM_TPU_MSM", "on").strip().lower() not in (
-        "off", "0", "false", "no",
-    )
-
-
-def _msm_cache_enabled() -> bool:
-    """TM_TPU_MSM_CACHE routes MSM phase 1 through the HBM cache.
-    Default OFF until an on-chip A/B of msm vs msm_cache decides (not
-    measured) — XLA-CPU relative numbers favor uncached and don't
-    transfer. Default-off flags parse the on-list; default-on flags
-    (_msm_enabled above) parse the off-list."""
-    return os.environ.get("TM_TPU_MSM_CACHE", "off").strip().lower() in (
-        "on", "1", "true", "yes",
-    )
 
 try:  # native (OpenSSL) fast path for single verification
     from cryptography.exceptions import InvalidSignature as _InvalidSignature
@@ -242,96 +226,18 @@ class Ed25519BatchVerifier(BatchVerifier):
         return self.verify_async()()
 
     def verify_async(self):
-        """Engine path (TM_TPU_ENGINE=auto/on, the default): submit to
-        the process-wide coalescing pipeline (ops/engine.py) — jobs from
-        concurrent callers merge into one launch with per-caller demux,
-        prep for batch i+1 overlaps batch i's kernel, and sub-cutover
-        batches ride the threaded C host plane. Returns a completion
-        callable either way.
-
-        Direct path (TM_TPU_ENGINE=off): launch prep + H2D + kernel
-        now, return a completion callable — callers overlap the kernel
-        with host work (e.g. blocksync applies block h while h+1's
-        commit verifies). Host path completes eagerly (nothing to
-        overlap). Acceptance is byte-identical between the two."""
-        n = len(self._sigs)
-        if n == 0:
+        """Submit to the process-wide coalescing pipeline
+        (ops/engine.py), the one place a batch's route is chosen: jobs
+        from concurrent callers merge into one launch with per-caller
+        demux, prep for batch i+1 overlaps batch i's kernel, and
+        sub-cutover batches ride the threaded C host plane. Returns a
+        completion callable, so callers overlap the verification with
+        host work (blocksync applies block h while h+1's commit
+        verifies)."""
+        if not self._sigs:
             return lambda: (False, [])
         from ..ops import engine as _engine
 
-        if _engine.engine_enabled():
-            return _engine.verify_async_via_engine(
-                KEY_TYPE, self._pks, self._msgs, self._sigs,
-                journey=self.journey,
-            )
-        # direct dispatch: the cutovers below still deserve the one-shot
-        # launch-latency calibration (no-op after the first call)
-        _engine.maybe_autotune()
-        if _use_device() and n >= DEVICE_BATCH_CUTOVER:
-            from ..ops import verify as dev
-
-            def bitmap_async():
-                # HBM pubkey cache (the reference's expanded-key LRU,
-                # ed25519.go:57, lifted to device memory): production
-                # commits reuse the same validator keys height after
-                # height. TM_TPU_PK_CACHE=off forces the uncached kernel.
-                if _pk_cache_enabled():
-                    return dev.verify_batch_cached_async(self._pks, self._msgs, self._sigs)
-                return dev.verify_batch_async(self._pks, self._msgs, self._sigs)
-
-            if _msm_enabled() and n >= MSM_BATCH_CUTOVER:
-                # Phase 1: the RLC/MSM all-valid fast path; phase 2 (on
-                # failure or precheck refusal) localizes with the bitmap
-                # kernel. All-valid batches accept deterministically, so
-                # the final (ok, bitmap) is identical to the per-sig
-                # plane; failure costs one extra launch, like the
-                # reference's serial re-verify (types/validation.go:245).
-                from ..ops import msm as dev_msm
-
-                if _pk_cache_enabled() and _msm_cache_enabled():
-                    handle = dev_msm.verify_batch_rlc_cached_async(
-                        self._pks, self._msgs, self._sigs
-                    )
-                else:
-                    handle = dev_msm.verify_batch_rlc_async(self._pks, self._msgs, self._sigs)
-                # A precheck refusal (None handle) means phase 2 is
-                # certain: dispatch the bitmap NOW so the caller keeps
-                # the launch-now/collect-later overlap instead of
-                # paying the whole launch at collect time.
-                dispatched = bitmap_async() if handle is None else None
-
-                def complete_msm():
-                    if handle is not None and dev_msm.collect_rlc(handle):
-                        _observe_direct(KEY_TYPE, "two_phase_msm", n, n)
-                        return True, [True] * n
-                    pending = dispatched if dispatched is not None else bitmap_async()
-                    bools = [bool(b) for b in dev.collect(pending)]
-                    _observe_direct(KEY_TYPE, "two_phase_msm", n, sum(bools))
-                    return all(bools), bools
-
-                return complete_msm
-
-            dispatched = bitmap_async()
-
-            def complete():
-                bools = [bool(b) for b in dev.collect(dispatched)]
-                _observe_direct(KEY_TYPE, "bitmap", n, sum(bools))
-                return all(bools), bools
-
-            return complete
-        from .. import trace as _trace
-
-        with _trace.span("verify.direct_host", "crypto", plane=KEY_TYPE, rows=n):
-            bools = [_single_verify(p, m, s) for p, m, s in zip(self._pks, self._msgs, self._sigs)]
-        _observe_direct(KEY_TYPE, "host", n, sum(bools))
-        result = (all(bools), bools)
-        return lambda: result
-
-
-def _observe_direct(plane: str, path: str, n: int, accepted: int) -> None:
-    """Fold a direct-dispatch (TM_TPU_ENGINE=off) launch into the
-    engine path counters; the direct_* labeling rule lives in
-    EngineMetrics.observe_direct."""
-    from ..metrics import engine_metrics
-
-    engine_metrics().observe_direct(plane, path, n, accepted)
+        return _engine.verify_async_via_engine(
+            KEY_TYPE, self._pks, self._msgs, self._sigs, journey=self.journey,
+        )

@@ -343,8 +343,8 @@ class Sr25519BatchVerifier(BatchVerifier):
     batched on the SAME TPU curve kernels as ed25519 (ops/verify_sr.py,
     ristretto codec in ops/ristretto.py) — both of the reference's
     batch-capable key types ride one device plane. Host path: Straus
-    ladders per signature. Gating mirrors ed25519 (TM_TPU_CRYPTO +
-    launch-latency cutover)."""
+    ladders per signature. The engine routes both key types by the same
+    rule (TM_TPU_CRYPTO + the cutovers)."""
 
     def __init__(self):
         self._jobs: list[tuple[bytes, bytes, bytes]] = []
@@ -360,83 +360,13 @@ class Sr25519BatchVerifier(BatchVerifier):
         return self.verify_async()()
 
     def verify_async(self):
-        """Device path: launch prep + H2D + kernel now, return a
-        completion callable so callers overlap the kernel with host
-        work (same contract as Ed25519BatchVerifier.verify_async)."""
-        from .ed25519 import (
-            DEVICE_BATCH_CUTOVER,
-            MSM_BATCH_CUTOVER,
-            _msm_enabled,
-            _pk_cache_enabled,
-            _use_device,
-        )
-
-        n = len(self._jobs)
-        if n == 0:
+        """Submit to the engine (ops/engine.py), which chooses the
+        route; same contract as Ed25519BatchVerifier.verify_async."""
+        if not self._jobs:
             return lambda: (False, [])
         from ..ops import engine as _engine
 
-        if _engine.engine_enabled():
-            return _engine.verify_async_via_engine(
-                KEY_TYPE,
-                [j[0] for j in self._jobs],
-                [j[1] for j in self._jobs],
-                [j[2] for j in self._jobs],
-                journey=self.journey,
-            )
-        # direct dispatch: the cutovers below still deserve the one-shot
-        # launch-latency calibration (no-op after the first call)
-        _engine.maybe_autotune()
-        if _use_device() and n >= DEVICE_BATCH_CUTOVER:
-            from ..ops import verify_sr as dev
-
-            pks = [j[0] for j in self._jobs]
-            msgs = [j[1] for j in self._jobs]
-            sigs = [j[2] for j in self._jobs]
-
-            def bitmap_async():
-                if _pk_cache_enabled():
-                    return dev.verify_batch_cached_async(pks, msgs, sigs)
-                return dev.verify_batch_async(pks, msgs, sigs)
-
-            if _msm_enabled() and n >= MSM_BATCH_CUTOVER:
-                # two-phase like the ed25519 plane: the RLC/MSM combined
-                # equation first, per-signature bitmap only on failure.
-                # A precheck refusal dispatches the bitmap immediately,
-                # preserving the launch-now/collect-later overlap.
-                from ..ops import msm as dev_msm
-
-                handle = dev_msm.verify_batch_rlc_sr_async(pks, msgs, sigs)
-                dispatched = bitmap_async() if handle is None else None
-
-                def complete_msm():
-                    from ..metrics import engine_metrics
-
-                    if handle is not None and dev_msm.collect_rlc(handle):
-                        engine_metrics().observe_direct(KEY_TYPE, "two_phase_msm", n, n)
-                        return True, [True] * n
-                    pending = dispatched if dispatched is not None else bitmap_async()
-                    bools = [bool(b) for b in dev.collect(pending)]
-                    engine_metrics().observe_direct(KEY_TYPE, "two_phase_msm", n, sum(bools))
-                    return all(bools), bools
-
-                return complete_msm
-
-            dispatched = bitmap_async()
-
-            def complete():
-                from ..metrics import engine_metrics
-
-                bools = [bool(b) for b in dev.collect(dispatched)]
-                engine_metrics().observe_direct(KEY_TYPE, "bitmap", n, sum(bools))
-                return all(bools), bools
-
-            return complete
-        from .. import trace as _trace
-        from ..metrics import engine_metrics
-
-        with _trace.span("verify.direct_host", "crypto", plane=KEY_TYPE, rows=n):
-            oks = [verify(pk, msg, sig) for pk, msg, sig in self._jobs]
-        engine_metrics().observe_direct(KEY_TYPE, "host", n, sum(oks))
-        result = (all(oks), oks)
-        return lambda: result
+        pks, msgs, sigs = zip(*self._jobs)
+        return _engine.verify_async_via_engine(
+            KEY_TYPE, pks, msgs, sigs, journey=self.journey,
+        )

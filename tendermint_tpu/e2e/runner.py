@@ -1763,12 +1763,11 @@ def run_soak(manifest_path: str, base_dir: str, duration: float = 30.0,
     small_box = eff_cores < FULL_MIX_CORES
     if small_box:
         # the core gate's device-plane half: on a small box every node
-        # runs the native host crypto path outright — the jax import
+        # runs the engine's host plane outright — the jax import
         # (~15s of CPU per process) and accelerator probes otherwise
         # steal exactly the core consensus needs, mid-run, every time a
         # node (re)starts or a late joiner boots (docs/e2e.md)
-        for k, v in (("TM_TPU_ENGINE", "off"), ("TM_TPU_CRYPTO", "off"),
-                     ("TM_TPU_AUTOTUNE", "off")):
+        for k, v in (("TM_TPU_CRYPTO", "off"), ("TM_TPU_AUTOTUNE", "off")):
             runner.extra_node_env.setdefault(k, os.environ.get(k, v))
         logger(f"core-gate: {eff_cores} core(s) < {FULL_MIX_CORES}: nodes "
                "pinned to the host crypto plane (no jax import)")

@@ -24,7 +24,8 @@ boxes with no accelerator stack.
                       size lands a fresh compile on the SAME cell, and
                       count > 1 is direct evidence the engine's
                       shape-bucketing broke (the silent-throughput-
-                      killer class; TM_TPU_SHAPE_CHURN injects it).
+                      killer class; a test injects it by patching
+                      ops/verify.pad_pow2_rows to leave rows unpadded).
   device_mem_growth   the trailing live-buffer residency samples are
                       monotone nondecreasing with total growth over a
                       floor — the buffer-leak signature, judged from

@@ -59,8 +59,7 @@ class EngineTxPreVerifier:
     """The TxMempool pre_verify hook: batch-verifies every signed-tx
     envelope in the admission batch through the engine (one coalesced
     submit per batch; the engine merges concurrent admitters into
-    single device/host-C launches). With TM_TPU_ENGINE=off it degrades
-    to the per-signature direct path, byte-identical in verdicts.
+    single device/host-C launches).
 
     Verdicts: True (valid), False (invalid — the mempool rejects before
     the app sees the tx), None (no envelope: pass through)."""
@@ -82,15 +81,7 @@ class EngineTxPreVerifier:
             return out
         from ..ops import engine as E
 
-        if E.engine_enabled():
-            complete = E.verify_async_via_engine("ed25519", pks, msgs, sigs)
-            _, bools = complete()
-        else:
-            from ..crypto.ed25519 import _single_verify
-
-            bools = [
-                _single_verify(p, m, s) for p, m, s in zip(pks, msgs, sigs)
-            ]
+        _, bools = E.verify_async_via_engine("ed25519", pks, msgs, sigs)()
         for i, ok in zip(idx, bools):
             out[i] = bool(ok)
         return out

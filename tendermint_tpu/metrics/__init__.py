@@ -826,20 +826,12 @@ class EngineMetrics:
 
     def observe_path(self, plane: str, path: str, bools) -> None:
         """Fold one launch's per-row outcomes into the path counters."""
-        self.observe_path_counts(plane, path, len(bools), sum(1 for b in bools if b))
-
-    def observe_path_counts(self, plane: str, path: str, n: int, accepted: int) -> None:
+        n, accepted = len(bools), sum(1 for b in bools if b)
         self.launches.add(1, plane, path)
         if accepted:
             self.path_rows.add(accepted, plane, path, "accept")
         if n - accepted:
             self.path_rows.add(n - accepted, plane, path, "reject")
-
-    def observe_direct(self, plane: str, path: str, n: int, accepted: int) -> None:
-        """A direct-dispatch (TM_TPU_ENGINE=off) launch, labeled
-        direct_* so the scheduler's coalesced launches stay
-        distinguishable from per-caller ones."""
-        self.observe_path_counts(plane, f"direct_{path}", n, accepted)
 
 
 class HashMetrics:

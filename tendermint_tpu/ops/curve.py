@@ -247,8 +247,7 @@ def fixed_base_table() -> np.ndarray:
     return _FIXED_TABLE
 
 
-def double_scalar_mul_base(s_bytes, k_bytes, a_pt=None, final_t: bool = True,
-                           a_table=None):
+def double_scalar_mul_base(s_bytes, k_bytes, a_pt, final_t: bool = True):
     """[s]B + [k]A' in one interleaved Straus ladder (A' = a_pt, usually
     the negated pubkey). s_bytes/k_bytes: (32, B); a_pt: (4, 32, B) with
     T. With final_t the output carries a valid T (the last addition
@@ -257,19 +256,11 @@ def double_scalar_mul_base(s_bytes, k_bytes, a_pt=None, final_t: bool = True,
     unrolled final window bloats the graph — callers that only double
     and compare the result (the ed25519 identity check) take this path.
 
-    a_table, if given, is a prebuilt (16, 4, 32, B) multiples table for
-    A' (the HBM-resident pubkey cache hands these in, skipping both the
-    decompression and the per-call table build — the device analog of
-    the reference's expanded-pubkey LRU, crypto/ed25519/ed25519.go:57).
-
     Per 4-bit window: 4 shared doublings (3 without T) + one addition per
     scalar (only the first produces T) + two 16-way one-hot selects."""
     nibs_s = scalar_to_nibbles(s_bytes)  # (64, B)
     nibs_k = scalar_to_nibbles(k_bytes)
-    if a_table is None:
-        a_table = _build_var_table(a_pt)  # (16, 4, 32, B)
-    elif a_pt is None:
-        a_pt = a_table[1]  # multiple 1x = A' itself (for the vma tie)
+    a_table = _build_var_table(a_pt)  # (16, 4, 32, B)
     b_table = jnp.asarray(base_table())[..., None]  # (16, 4, 32, 1)
 
     def window(acc, w, last: bool):

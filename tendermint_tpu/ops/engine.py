@@ -33,10 +33,11 @@ arxiv 2112.02229). This module is that pipeline:
                  MSM_CUTOVER_ROWS, the measured crossover of the two
                  programs' launch prices (env still wins for both).
 
-Gating: TM_TPU_ENGINE = auto (default, engine on) | on | off. `off`
-restores the direct per-caller dispatch paths; acceptance is
-byte-identical either way (the engine runs the same kernels and the
-same host acceptance chain, only scheduled differently).
+This is the only router: both BatchVerifiers and the mempool's
+pre-verifier submit here, and VerifyEngine._dispatch_group alone chooses
+host / per-signature / two-phase MSM for a batch. The module imports no
+jax; a node pinned to the host (TM_TPU_CRYPTO=off) runs the host plane
+and never loads it.
 """
 
 from __future__ import annotations
@@ -52,16 +53,7 @@ from ..metrics import engine_metrics as _engine_metrics
 # Rows per coalesced launch. Jobs beyond this form the next batch (the
 # double buffer absorbs them); bounds both padding waste and the jit
 # shape zoo.
-MAX_COALESCE_ROWS = int(os.environ.get("TM_TPU_ENGINE_MAX_ROWS", "8192"))
-
-
-def engine_enabled() -> bool:
-    """TM_TPU_ENGINE gate. auto == on (the engine is the default path);
-    off restores the direct dispatch paths in crypto/ed25519.py and
-    crypto/sr25519.py byte-identically."""
-    return os.environ.get("TM_TPU_ENGINE", "auto").strip().lower() not in (
-        "off", "0", "false", "no",
-    )
+MAX_COALESCE_ROWS = 8192
 
 
 # ------------------------------------------------------------------ autotune
@@ -89,9 +81,9 @@ def maybe_autotune() -> None:
     Both prices, and that of the host route as the engine runs it (one
     coalesced batch through the C loop; it decides nothing yet), are
     published as gauges (engine_autotune_*_seconds).
-    The probe runs under the lock on the first router to arrive — the
-    engine's dispatch worker, or a direct-dispatch caller — and every
-    other router waits for it, so no batch is routed while the
+    The probe runs under the lock on the first caller to arrive — the
+    engine's dispatch worker, or a set-up step that asks for it early —
+    and the other waits for it, so no batch is routed while the
     cutovers change; callers of VerifyEngine.submit never wait. The
     tiny launch may compile on a fresh cache; the first routed batch
     pays that once, beside its own program's compile. A failed probe is
@@ -541,7 +533,7 @@ class VerifyEngine:
                 return dev.verify_batch_cached_async(pks, msgs, sigs)
             return dev.verify_batch_async(pks, msgs, sigs)
 
-        if ed._msm_enabled() and total >= ed.MSM_BATCH_CUTOVER:
+        if total >= ed.MSM_BATCH_CUTOVER:
             # two-phase: the RLC/MSM all-valid fast path first, the
             # bitmap kernel only on failure — the reference's shape
             # (types/validation.go:245-255). A precheck refusal (None
@@ -551,8 +543,6 @@ class VerifyEngine:
 
             if plane == "sr25519":
                 rlc = dev_msm.verify_batch_rlc_sr_async(pks, msgs, sigs)
-            elif ed._pk_cache_enabled() and ed._msm_cache_enabled():
-                rlc = dev_msm.verify_batch_rlc_cached_async(pks, msgs, sigs)
             else:
                 rlc = dev_msm.verify_batch_rlc_async(pks, msgs, sigs)
             dispatched = bitmap_async() if rlc is None else None

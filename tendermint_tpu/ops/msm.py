@@ -58,11 +58,11 @@ from .verify import L, _pad_pow2, pad_pow2_rows, prepare_batch
 
 # Parallel point-streams. 128 fills the VPU lane axis for the table
 # builds; the accumulate add then runs at width 64*G. Batches smaller
-# than G fall back to G=B (the pad floor is 8). Rounded DOWN to a power
-# of two: padded batches are powers of two (pad_pow2_rows, floor 8), so
-# a power-of-two G always divides the batch exactly — a non-divisor
-# would silently truncate rounds and drop signatures from the sum.
-G_STREAMS = 1 << max(0, int(os.environ.get("TM_TPU_MSM_STREAMS", "128")).bit_length() - 1)
+# than G fall back to G=B (the pad floor is 8). A power of two: padded
+# batches are powers of two (pad_pow2_rows, floor 8), so G always
+# divides the batch exactly — a non-divisor would silently truncate
+# rounds and drop signatures from the sum (_accumulate_windows raises).
+G_STREAMS = 128
 
 
 def _select_windows(table, nibs):
@@ -160,85 +160,6 @@ def msm_verify_kernel_impl(a_enc, r_enc, zk_bytes, z_bytes, zs_bytes):
 
 
 msm_verify_kernel = jax.jit(msm_verify_kernel_impl)
-
-
-def msm_verify_kernel_cached_impl(tables, oks, slots, r_enc, zk_bytes, z_bytes, zs_bytes):
-    """Cache-hit MSM: A arrives as slot indices into the HBM-resident
-    split power-table cache (ops/verify.PubkeyCache with PK_SPLITS
-    rows: row c holds the 16-multiples table of -[2^(256/S * c)]A), so
-    the A side needs NO decompression and NO per-round table build, and
-    its window count drops from 64 to 64/S — chunk c of zk rides row c,
-    landing in the same low windows. R still decompresses + builds
-    (every signature's R is fresh). W covers max(32, 64/S) windows."""
-    r = r_enc.T.astype(jnp.int32)
-    n = r.shape[1]
-    r_pt, r_oks = C.decompress(r, zip215=True)
-    neg_r = C.point_neg(r_pt)
-    a_ok = jnp.all(oks[slots])
-    all_ok = a_ok & jnp.all(r_oks)
-
-    s_chunks = tables.shape[1]  # PK_SPLITS rows per cache entry
-    per = 64 // s_chunks  # zk nibbles per chunk
-    nibs_zk = C.scalar_to_nibbles(zk_bytes.T.astype(jnp.int32))  # (64, B)
-    nibs_z = C.scalar_to_nibbles(z_bytes.T.astype(jnp.int32))  # (32, B)
-
-    g = min(G_STREAMS, n)
-    if n % g:
-        # same trace-time tail-row guard as _accumulate_windows: the
-        # cached kernel's rounds loop would silently drop n % g rows
-        raise ValueError(
-            f"cached MSM batch size {n} is not a multiple of the stream count {g}; "
-            f"pad the batch (pad_pow2_rows) so no rows drop from the RLC sum"
-        )
-    rounds = n // g
-    wn = max(32, per)
-    w0 = C.identity_point((wn, g)) + 0 * neg_r[:, :, :1, None]
-    # ONE gather of every row this batch touches, transposed to the
-    # limb layout up front — a per-round gather inside the loop costs
-    # far more than slicing a pre-gathered array
-    tabs_a = jnp.transpose(tables[slots].astype(jnp.int32), (1, 2, 3, 4, 0))
-    # (S, 16, 4, 32, B)
-
-    def round_body(t, w_acc):
-        col_r = lax.dynamic_slice_in_dim(neg_r, t * g, g, axis=2)
-        tab_r = C._build_var_table(col_r)  # (16, 4, 32, g)
-        d_r = lax.dynamic_slice_in_dim(nibs_z, t * g, g, axis=1)  # (32, g)
-        pad_r = wn - 32
-        entry_r = _select_windows(tab_r, d_r)  # (4, 32, 32, g)
-        if pad_r:
-            ident = C.identity_point((pad_r, g)) + 0 * entry_r[:, :, :1, :1]
-            entry_r = jnp.concatenate([entry_r, ident], axis=2)
-        w_acc = C.point_add(w_acc, entry_r, out_t=True)
-        # A chunks: chunk c's 16-nibble sub-scalar lands in windows
-        # [0, per), riding cache row c (pre-multiplied by 2^(256c/S))
-        d_zk = lax.dynamic_slice_in_dim(nibs_zk, t * g, g, axis=1)  # (64, g)
-        lo = w_acc[:, :, :per]
-        for c in range(s_chunks):
-            tab_c = lax.dynamic_slice_in_dim(tabs_a[c], t * g, g, axis=3)
-            d_c = lax.dynamic_slice_in_dim(d_zk, c * per, per, axis=0)
-            entry_c = _select_windows(tab_c, d_c)  # (4, 32, per, g)
-            lo = C.point_add(lo, entry_c, out_t=True)
-        return jnp.concatenate([lo, w_acc[:, :, per:]], axis=2)
-
-    w_acc = lax.fori_loop(0, rounds, round_body, w0)
-
-    def horner_step(i, acc):
-        acc = C.point_double(acc, out_t=False)
-        acc = C.point_double(acc, out_t=False)
-        acc = C.point_double(acc, out_t=False)
-        acc = C.point_double(acc, out_t=True)
-        wth = lax.dynamic_index_in_dim(w_acc, wn - 2 - i, axis=2, keepdims=False)
-        return C.point_add(acc, wth, out_t=True)
-
-    acc = lax.fori_loop(0, wn - 1, horner_step, w_acc[:, :, wn - 1])
-    total = _tree_reduce_points(acc)
-    sb = C.fixed_base_mul(zs_bytes.T.astype(jnp.int32))
-    total = C.point_add(total, sb, out_t=False)
-    total = lax.fori_loop(0, 3, lambda _, v: C.point_double(v, out_t=False), total)
-    return all_ok & C.point_is_identity(total)[0]
-
-
-msm_verify_kernel_cached = jax.jit(msm_verify_kernel_cached_impl)
 
 
 def msm_verify_sr_kernel_impl(a_enc, r_enc, zk_bytes, z_bytes, zs_bytes):
@@ -365,25 +286,18 @@ def _scalars_rlc(s_rows, k_rows, n, z_raw):
         return _rlc_scalars(s_rows, k_rows, n, z_raw)
 
 
-def _launch_rlc(fn, kernel, head, slots, rows, zs_row, n, fid):
+def _launch_rlc(kernel, rows, zs_row, n, fid):
     """`ops.launch`: pad the per-row arrays to the program's row count,
     stage them (`device.h2d`, its child) and make the asynchronous
-    kernel call (and a compile, when one happens). `head` are the
-    kernel's leading device-resident arguments and `slots` its cache
-    slots (the cached plane), or () and None."""
+    kernel call (and a compile, when one happens)."""
     padded = _pad_pow2(n)
     with _trace.span("ops.launch", "ops", rows=n, padded=padded):
-        rows = pad_pow2_rows(rows, n, churnable=False)
-        if slots is not None:
-            # padded rows carry zero scalars (identity contributions), but their
-            # slot must point at a VALID cached key: slot 0 may hold a key whose
-            # encoding fails decode, which would sink all_ok for a valid batch
-            rows = [np.pad(slots, (0, len(rows[0]) - n), mode="edge"), *rows]
+        rows = pad_pow2_rows(rows, n)
         nbytes = sum(a.nbytes for a in rows) + zs_row.nbytes
         with _devobs.transfer_span("h2d", nbytes, flow=fid):
             dev_args = [jnp.asarray(a) for a in (*rows, zs_row)]
-        with _devobs.attribution(fn=fn, rows=padded, flow=fid):
-            return kernel(*head, *dev_args)
+        with _devobs.attribution(fn="rlc", rows=padded, flow=fid):
+            return kernel(*dev_args)
 
 
 def _dispatch_rlc(prepare, kernel, pubkeys, msgs, sigs, z_raw):
@@ -401,7 +315,7 @@ def _dispatch_rlc(prepare, kernel, pubkeys, msgs, sigs, z_raw):
             return None
         a_enc, r_enc, s_rows, k_rows = prepped
         zk, z_out, zs_row = _scalars_rlc(s_rows, k_rows, n, z_raw)
-        handle = _launch_rlc("rlc", kernel, (), None, [a_enc, r_enc, zk, z_out], zs_row, n, fid)
+        handle = _launch_rlc(kernel, [a_enc, r_enc, zk, z_out], zs_row, n, fid)
     _engine_metrics().kernel_launches.add(1, "rlc")
     return handle
 
@@ -410,51 +324,6 @@ def verify_batch_rlc_async(pubkeys, msgs, sigs, z_raw: bytes | None = None):
     """Dispatch the ed25519 RLC check without blocking. Returns an
     opaque handle for collect_rlc, or None on precheck refusal."""
     return _dispatch_rlc(prepare_batch, msm_verify_kernel, pubkeys, msgs, sigs, z_raw)
-
-
-def verify_batch_rlc_cached_async(pubkeys, msgs, sigs, z_raw: bytes | None = None):
-    """The RLC check through the HBM pubkey cache: cache hits skip A
-    decompression AND the per-round A table build, and ride the split
-    power tables (Horner depth 32 instead of 64). Falls back to the
-    uncached MSM when the cache overflows or holds legacy-shape
-    entries. Same contract as verify_batch_rlc_async."""
-    from .verify import pubkey_cache
-
-    n = len(sigs)
-    if n == 0:
-        return None
-    cache = pubkey_cache()
-    if cache.tables.ndim != 5:
-        return verify_batch_rlc_async(pubkeys, msgs, sigs, z_raw)
-    fid = _devobs.next_flow() if _devobs.enabled() else 0
-    with _trace.span("ops.msm_dispatch", "ops", kernel="rlc_cached", rows=n, flow=fid) as sp:
-        # prep/precheck BEFORE touching the cache: this path REFUSES any
-        # batch with a malformed row, so inserting its keys first would
-        # build zero-byte entries into the HBM cache (possibly evicting
-        # live validator keys) for a batch that never verifies. The bitmap
-        # cached path legitimately inserts first — it verifies malformed
-        # rows masked, not refused.
-        prepped = _prep_rlc(prepare_batch, pubkeys, msgs, sigs, n)
-        if prepped is None:
-            return None
-        a_enc, r_enc, s_rows, k_rows = prepped
-        keys = [pk if len(pk) == 32 else b"\x00" * 32 for pk in pubkeys]
-        slots, tables, oks = cache.ensure_snapshot(keys)
-        zk, z_out, zs_row = _scalars_rlc(s_rows, k_rows, n, z_raw)
-        if slots is None:
-            # more distinct keys than the cache holds: take the uncached
-            # kernel, reusing the prep + scalar math already done instead
-            # of re-dispatching through verify_batch_rlc_async
-            sp.annotate(cache="overflow")
-            fn = "rlc"
-            handle = _launch_rlc(fn, msm_verify_kernel, (), None,
-                                 [a_enc, r_enc, zk, z_out], zs_row, n, fid)
-        else:
-            fn = "rlc_cached"
-            handle = _launch_rlc(fn, msm_verify_kernel_cached, (tables, oks), slots,
-                                 [r_enc, zk, z_out], zs_row, n, fid)
-    _engine_metrics().kernel_launches.add(1, fn)
-    return handle
 
 
 def collect_rlc(dispatched) -> bool:

@@ -25,7 +25,6 @@ Split of labor:
 from __future__ import annotations
 
 import hashlib
-import os
 
 import numpy as np
 
@@ -81,50 +80,13 @@ def verify_kernel_impl(a_enc, r_enc, s_bytes, k_bytes):
 verify_kernel = jax.jit(verify_kernel_impl)
 
 
-def build_pk_tables_impl(a_enc):
-    """Cache-fill kernel: (B, 32) uint8 pubkey encodings -> the Straus
-    multiples tables of the NEGATED points, (B, 16, 4, 32) int16, plus
-    the (B,) ZIP-215 decode-ok bits. int16 is exact: table limbs are
-    fe_mul outputs (|limb| < 2^9, ops/field.py bounds contract)."""
-    a = a_enc.T.astype(jnp.int32)  # (32, B)
-    a_pt, ok = C.decompress(a, zip215=True)
-    table = C._build_var_table(C.point_neg(a_pt))  # (16, 4, 32, B)
-    return jnp.transpose(table, (3, 0, 1, 2)).astype(jnp.int16), ok
-
-
-build_pk_tables = jax.jit(build_pk_tables_impl)
-
-
-def verify_kernel_cached_impl(tables, oks, slots, r_enc, s_bytes, k_bytes):
-    """Cache-hit kernel: like verify_kernel_impl but A arrives as slot
-    indices into the device-resident tables cache — no A decompression,
-    no per-call table build, no A bytes over the host link."""
-    r = r_enc.T.astype(jnp.int32)
-    s = s_bytes.T.astype(jnp.int32)
-    k = k_bytes.T.astype(jnp.int32)
-    n = r.shape[1]
-    a_table = jnp.transpose(tables[slots].astype(jnp.int32), (1, 2, 3, 0))
-    a_ok = oks[slots]
-    r_pt, r_ok = C.decompress(r, zip215=True)
-    q = C.double_scalar_mul_base(s, k, final_t=False, a_table=a_table)
-    return _cofactored_accept(q, r_pt, a_ok, r_ok, n)
-
-
-verify_kernel_cached = jax.jit(verify_kernel_cached_impl)
-
-
 # Split-ladder cached plane: the HBM cache stores power-of-2^(256/S)
 # multiples tables of each negated pubkey, so the cache-hit ladder needs
 # only 256/S/4*4 - 4 shared doublings instead of 252 (doublings are
 # ~45% of the kernel; at S=4 this removes ~40% of the per-sig field
 # work). [s]B rides rows of the host-precomputed fixed-base comb, which
-# never needed doublings at all. TM_TPU_PK_SPLIT picks S (1 = legacy
-# single-table ladder).
-PK_SPLITS = int(os.environ.get("TM_TPU_PK_SPLIT", "4"))
-if PK_SPLITS not in (1, 2, 4, 8):
-    # not assert: stripped under -O, and a mismatched split silently
-    # rejects every valid signature on the cache-hit path
-    raise ValueError(f"TM_TPU_PK_SPLIT must be 1, 2, 4 or 8, got {PK_SPLITS}")
+# never needed doublings at all.
+PK_SPLITS = 4
 
 
 def build_pk_tables_split_impl(a_enc):
@@ -160,22 +122,22 @@ verify_kernel_cached_split = jax.jit(verify_kernel_cached_split_impl)
 class PubkeyCache:
     """HBM-resident decompressed-pubkey cache (the device analog of the
     reference's 4096-entry expanded-pubkey LRU, crypto/ed25519/
-    ed25519.go:57). Stores each pubkey's negated Straus table so cache
-    hits skip decompression AND the per-call table build (~10% of the
-    verify kernel) and never re-send A bytes through the host link.
+    ed25519.go:57). Stores each pubkey's PK_SPLITS power tables of the
+    negated point (build_pk_tables_split) so cache hits skip
+    decompression AND the table build, take the short split ladder, and
+    never re-send A bytes through the host link.
 
     Functional-update safety: eviction overwrites slots via .at[].set,
     which creates a NEW device array — in-flight async batches keep
     referencing the buffers they were dispatched with."""
 
-    def __init__(self, capacity: int = 4096, build_fn=None, entry_shape=(16, 4, 32),
-                 plane: str = "pk"):
+    def __init__(self, capacity: int = 4096, build_fn=None, plane: str = "pk"):
         import collections
         import threading
 
         self.capacity = capacity
         self.plane = plane  # devobs compile-attribution + residency label
-        self._build = build_fn or build_pk_tables  # sr25519 plugs in its decoder
+        self._build = build_fn or build_pk_tables_split  # sr25519 plugs in its decoder
         self._lock = threading.Lock()  # reactors verify concurrently
         self._lru: "collections.OrderedDict[bytes, int]" = collections.OrderedDict()
         # Two-phase fill bookkeeping. The table build is a device
@@ -195,7 +157,7 @@ class PubkeyCache:
         #   re-serialize hit-only verifiers behind the build).
         self._pending: "dict[bytes, threading.Event]" = {}
         self._pinned: "dict[bytes, int]" = {}
-        self.tables = jnp.zeros((capacity,) + tuple(entry_shape), jnp.int16)
+        self.tables = jnp.zeros((capacity, PK_SPLITS, 16, 4, 32), jnp.int16)
         self.oks = jnp.zeros((capacity,), bool)
 
     def ensure(self, pubkeys):
@@ -336,14 +298,7 @@ _PK_CACHE: PubkeyCache | None = None
 def pubkey_cache() -> PubkeyCache:
     global _PK_CACHE
     if _PK_CACHE is None:
-        if PK_SPLITS > 1:
-            _PK_CACHE = PubkeyCache(
-                build_fn=build_pk_tables_split,
-                entry_shape=(PK_SPLITS, 16, 4, 32),
-                plane="ed25519_pk",
-            )
-        else:
-            _PK_CACHE = PubkeyCache(plane="ed25519_pk")
+        _PK_CACHE = PubkeyCache(plane="ed25519_pk")
     return _PK_CACHE
 
 
@@ -354,26 +309,12 @@ def _pad_pow2(n: int, floor: int = 8) -> int:
     return size
 
 
-def _shape_churn() -> bool:
-    """TM_TPU_SHAPE_CHURN=1 disables pow2 padding on the bitmap-plane
-    dispatch paths — a fault-injection knob that turns every distinct
-    batch size into a fresh XLA program, the regression the
-    recompile_storm verdict (lens/gates.py, tmdev) exists to catch.
-    Never applied to the MSM plane: its kernels require the row count
-    to divide the stream count and would raise on raw sizes."""
-    return os.environ.get("TM_TPU_SHAPE_CHURN", "").strip().lower() in (
-        "1", "on", "true", "yes",
-    )
-
-
-def pad_pow2_rows(arrays, n: int, churnable: bool = True):
+def pad_pow2_rows(arrays, n: int):
     """Pad (n, 32) uint8 arrays up to the next power-of-two row count so
     jit caches a small set of program shapes (shared by the ed25519 and
-    sr25519 planes). `churnable=False` call sites (the MSM plane, whose
-    kernels require padded row counts) are exempt from the
-    TM_TPU_SHAPE_CHURN fault injection."""
+    sr25519 planes and the MSM, whose kernels require it)."""
     size = _pad_pow2(n)
-    if size == n or (churnable and _shape_churn()):
+    if size == n:
         return arrays
     pad = size - n
     return [np.pad(a, ((0, pad), (0, 0))) for a in arrays]
@@ -553,13 +494,8 @@ def verify_batch_cached_async(pubkeys, msgs, sigs):
     """verify_batch_async through the HBM pubkey cache: repeated
     validator sets (every production VerifyCommit after the first at a
     given height range) skip A decompression + table build on device."""
-    cache = pubkey_cache()
-    # Pick the kernel from the cache's ACTUAL entry shape, not PK_SPLITS:
-    # a caller that installed a bare PubkeyCache() (legacy single-table
-    # entries) must not be routed to the split kernel.
-    kern = verify_kernel_cached_split if cache.tables.ndim == 5 else verify_kernel_cached
     return dispatch_cached(
-        cache, prepare_batch, kern,
+        pubkey_cache(), prepare_batch, verify_kernel_cached_split,
         verify_batch_async, pubkeys, msgs, sigs,
         fn_label="ed25519_bitmap_cached",
     )

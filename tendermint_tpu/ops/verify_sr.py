@@ -46,35 +46,6 @@ def verify_sr_kernel_impl(a_enc, r_enc, s_bytes, k_bytes):
 verify_sr_kernel = jax.jit(verify_sr_kernel_impl)
 
 
-def build_sr_tables_impl(a_enc):
-    """Cache-fill kernel for the sr25519 plane: ristretto decode +
-    negate + Straus multiples table, (B, 16, 4, 32) int16 + ok bits
-    (same contract as ops/verify.py build_pk_tables_impl)."""
-    a = a_enc.T.astype(jnp.int32)
-    a_pt, ok = R.decode(a)
-    table = C._build_var_table(C.point_neg(a_pt))
-    return jnp.transpose(table, (3, 0, 1, 2)).astype(jnp.int16), ok
-
-
-build_sr_tables = jax.jit(build_sr_tables_impl)
-
-
-def verify_sr_kernel_cached_impl(tables, oks, slots, r_enc, s_bytes, k_bytes):
-    """Cache-hit kernel: A arrives as slots into the device-resident
-    ristretto table cache; only the result re-encoding remains."""
-    r = r_enc.T.astype(jnp.int32)
-    s = s_bytes.T.astype(jnp.int32)
-    k = k_bytes.T.astype(jnp.int32)
-    a_table = jnp.transpose(tables[slots].astype(jnp.int32), (1, 2, 3, 0))
-    a_ok = oks[slots]
-    q = C.double_scalar_mul_base(s, k, a_table=a_table)  # final_t for encode
-    enc = R.encode(q)
-    return a_ok & jnp.all(enc == r, axis=0)
-
-
-verify_sr_kernel_cached = jax.jit(verify_sr_kernel_cached_impl)
-
-
 def build_sr_tables_split_impl(a_enc):
     """Split-plane cache fill (see ops/verify.py build_pk_tables_split):
     ristretto decode + negate + power tables, (B, S, 16, 4, 32) int16."""
@@ -115,18 +86,11 @@ _SR_CACHE = None
 
 
 def sr_pubkey_cache():
-    from .verify import PK_SPLITS, PubkeyCache
+    from .verify import PubkeyCache
 
     global _SR_CACHE
     if _SR_CACHE is None:
-        if PK_SPLITS > 1:
-            _SR_CACHE = PubkeyCache(
-                build_fn=build_sr_tables_split,
-                entry_shape=(PK_SPLITS, 16, 4, 32),
-                plane="sr25519_pk",
-            )
-        else:
-            _SR_CACHE = PubkeyCache(build_fn=build_sr_tables, plane="sr25519_pk")
+        _SR_CACHE = PubkeyCache(build_fn=build_sr_tables_split, plane="sr25519_pk")
     return _SR_CACHE
 
 
@@ -191,14 +155,8 @@ def verify_batch_cached_async(pubkeys, msgs, sigs):
     contract as the ed25519 plane's verify_batch_cached_async)."""
     from .verify import dispatch_cached
 
-    cache = sr_pubkey_cache()
-    kern = (
-        verify_sr_kernel_cached_split
-        if cache.tables.ndim == 5
-        else verify_sr_kernel_cached
-    )
     return dispatch_cached(
-        cache, prepare_batch, kern,
+        sr_pubkey_cache(), prepare_batch, verify_sr_kernel_cached_split,
         verify_batch_async, pubkeys, msgs, sigs,
         fn_label="sr25519_bitmap_cached",
     )

@@ -144,18 +144,10 @@ def verify_batch_sharded_cached(mesh: Mesh, pubkeys, msgs, sigs, key_type: str =
         return np.zeros((0,), bool), False
     if key_type == "ed25519":
         plane, cache = V, V.pubkey_cache()
-        kern = (
-            V.verify_kernel_cached_split_impl
-            if cache.tables.ndim == 5
-            else V.verify_kernel_cached_impl
-        )
+        kern = V.verify_kernel_cached_split_impl
     elif key_type == "sr25519":
         plane, cache = VS, VS.sr_pubkey_cache()
-        kern = (
-            VS.verify_sr_kernel_cached_split_impl
-            if cache.tables.ndim == 5
-            else VS.verify_sr_kernel_cached_impl
-        )
+        kern = VS.verify_sr_kernel_cached_split_impl
     else:
         raise ValueError(f"unsupported key_type {key_type!r} for sharded verification")
     keys = [pk if len(pk) == 32 else b"\x00" * 32 for pk in pubkeys]

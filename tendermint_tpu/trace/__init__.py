@@ -41,8 +41,8 @@ state.finalize_block / state.abci_commit,
 light.update / light.fetch / light.verify_step / light.header_checks /
 light.detect_divergence / light.store (one light-client update, from
 the caller to the store), verify.commit_walk (address lookup,
-sign-bytes, tally) / verify.commit_dispatch / verify.commit_collect /
-verify.direct_host, blocksync.try_sync (one block on the reactor's
+sign-bytes, tally) / verify.commit_dispatch / verify.commit_collect,
+blocksync.try_sync (one block on the reactor's
 thread) / blocksync.parts / blocksync.verify_commit /
 blocksync.verify_ahead / blocksync.save_block / blocksync.apply /
 blocksync.starved / blocksync.settle (both retrospective),

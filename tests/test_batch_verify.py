@@ -165,28 +165,22 @@ def test_sharded_verify_sr25519_8_devices():
     assert not bitmap[37] and bitmap.sum() == n - 1  # fault localized
 
 
-def test_split_and_legacy_cached_planes_agree():
-    """The split-ladder cached kernel (TM_TPU_PK_SPLIT=4 default) and the
-    legacy single-table cached kernel accept identical sets: run the same
-    batch (valid + tampered + small-order edge) through BOTH cache
-    planes explicitly."""
+def test_split_cached_plane_agrees_with_the_uncached_kernel_and_the_oracle():
+    """The split-ladder cached kernel accepts exactly what the uncached
+    verify_kernel and the pure-Python oracle accept: the same batch
+    (valid + tampered + small-order edge) through a bare PubkeyCache,
+    whose default entries are the split form."""
     pks, msgs, sigs = make_jobs(4, tamper_idx=(1,))
     so = ref.small_order_points()[1]
     pks.append(so); msgs.append(b"edge"); sigs.append(ref.compress(ref.IDENTITY) + b"\x00" * 32)
 
-    legacy = V.PubkeyCache(capacity=8, build_fn=V.build_pk_tables)
-    split = V.PubkeyCache(
-        capacity=8, build_fn=V.build_pk_tables_split,
-        entry_shape=(V.PK_SPLITS, 16, 4, 32),
-    )
-    got_legacy = V.collect(V.dispatch_cached(
-        legacy, V.prepare_batch, V.verify_kernel_cached, V.verify_batch_async,
-        pks, msgs, sigs))
     got_split = V.collect(V.dispatch_cached(
-        split, V.prepare_batch, V.verify_kernel_cached_split, V.verify_batch_async,
-        pks, msgs, sigs))
-    assert [bool(b) for b in got_legacy] == [bool(b) for b in got_split]
-    assert not got_split[1] and bool(got_split[4])
+        V.PubkeyCache(capacity=8), V.prepare_batch, V.verify_kernel_cached_split,
+        V.verify_batch_async, pks, msgs, sigs))
+    got_uncached = V.verify_batch(pks, msgs, sigs)
+    want = [ref.verify(p, m, s, zip215=True) for p, m, s in zip(pks, msgs, sigs)]
+    assert [bool(b) for b in got_split] == [bool(b) for b in got_uncached] == want
+    assert want == [True, False, True, True, True]
 
 
 def test_sharded_cached_matches_sharded_uncached():
@@ -263,7 +257,7 @@ def test_pubkey_cache_fill_does_not_block_hits():
             building.set()
             assert gate.wait(timeout=10)
         tables = jnp.tile(
-            jnp.arange(n, dtype=jnp.int16).reshape(n, 1, 1, 1), (1, 16, 4, 32)
+            jnp.arange(n, dtype=jnp.int16).reshape(n, 1, 1, 1, 1), (1, V.PK_SPLITS, 16, 4, 32)
         )
         return tables, jnp.ones((n,), bool)
 
@@ -309,4 +303,4 @@ def test_pubkey_cache_fill_does_not_block_hits():
     import numpy as _np
 
     got = _np.asarray(result["tables"])[result["slots"]]
-    assert {int(x) for x in got[:, 0, 0, 0]} == {0, 1, 2}
+    assert {int(x) for x in got[:, 0, 0, 0, 0]} == {0, 1, 2}

@@ -81,7 +81,7 @@ def test_e2e_perturbed_testnet(tmp_path):
     finally:
         runner.cleanup()
     # cleanup scraped each node's final /metrics exposition into its
-    # home dir; with the engine default-on (TM_TPU_ENGINE=auto) the
+    # home dir; every commit is verified through the engine, so the
     # commit-verify traffic must have surfaced the engine telemetry
     # plane (ops/engine.py -> metrics.EngineMetrics via the process-
     # global registry) on at least one node's scrape.
@@ -93,12 +93,9 @@ def test_e2e_perturbed_testnet(tmp_path):
                 scraped.append(f.read())
     assert scraped, "no node produced a metrics.txt artifact"
     assert any("tendermint_consensus_height" in t for t in scraped)
-    from tendermint_tpu.ops.engine import engine_enabled
-
-    if engine_enabled():
-        assert any("tendermint_engine_submitted_jobs_total" in t for t in scraped), (
-            "engine telemetry series missing from every node's final scrape"
-        )
+    assert any("tendermint_engine_submitted_jobs_total" in t for t in scraped), (
+        "engine telemetry series missing from every node's final scrape"
+    )
     # the structural-hash plane (crypto/merkle + the memoized
     # ValidatorSet/Header hashes) rides the same process-global
     # registry; any committed block must have produced builds and memo

@@ -3,16 +3,19 @@
 Pins the tentpole contracts: coalescing with per-caller demux
 (mixed-validity batches stay isolated per caller), worker exception
 propagation (a dispatch-stage failure reaches the submitting caller and
-the engine keeps serving), byte-identical acceptance with the engine
-off (direct dispatch) and on, autotune leaving the CPU defaults
-untouched, and the msm tail-row alignment assertion (ADVICE r5 medium).
+the engine keeps serving), the oracle's verdicts on every route and
+key type, a host-only node that never imports jax, autotune leaving
+the CPU defaults untouched, and the msm tail-row alignment assertion
+(ADVICE r5 medium).
 Includes the tier-1 bench smoke that pushes one tiny coalesced batch
 through the engine under JAX_PLATFORMS=cpu so the path cannot rot
 between TPU windows.
 """
 
 import os
+import subprocess
 import sys
+import textwrap
 import threading
 import time
 
@@ -97,37 +100,143 @@ def test_engine_concurrent_caller_isolation():
         assert results[c] == want, c
 
 
-def test_engine_device_path_matches_direct(monkeypatch):
-    """Engine-on and engine-off (direct dispatch) must return
-    byte-identical (ok, bools) on the same mixed-validity corpus, on
-    both the host plane and the device plane (cutover forced down)."""
-    corpus = [
-        make_jobs(6),
-        make_jobs(8, tamper_idx={0, 7}),
-        make_jobs(5, tamper_idx={2}),
-    ]
+def _ed25519_corpus():
+    corpus = [make_jobs(6), make_jobs(8, tamper_idx={0, 7}), make_jobs(5, tamper_idx={2})]
+    oracle = lambda p, m, s: ref.verify(p, m, s, zip215=True)
+    return corpus, Ed25519BatchVerifier, Ed25519PubKey, oracle
 
-    def run(pks, msgs, sigs):
-        bv = Ed25519BatchVerifier()
+
+def _sr25519_corpus():
+    from tendermint_tpu.crypto import sr25519 as sr
+
+    corpus = []
+    for n, tamper in ((6, ()), (8, (0, 7)), (5, (2,))):
+        privs = [sr.Sr25519PrivKey.generate(b"route-%d-%d" % (n, i)) for i in range(n)]
+        msgs = [b"sr-vote-%d" % i for i in range(n)]
+        sigs = [p.sign(m) for p, m in zip(privs, msgs)]
+        for i in tamper:
+            sigs[i] = sigs[i][:10] + bytes([sigs[i][10] ^ 0xFF]) + sigs[i][11:]
+        corpus.append(([p.pub_key().bytes() for p in privs], msgs, sigs))
+    return corpus, sr.Sr25519BatchVerifier, sr.Sr25519PubKey, sr.verify
+
+
+# route -> (DEVICE_BATCH_CUTOVER, MSM_BATCH_CUTOVER) that force it
+_ROUTE_CUTOVERS = {"host": (1 << 30, 1 << 30), "bitmap": (4, 1 << 30), "two_phase_msm": (4, 4)}
+
+
+@pytest.mark.parametrize("key_type", ["ed25519", "sr25519"])
+@pytest.mark.parametrize("route", list(_ROUTE_CUTOVERS))
+def test_engine_matches_the_oracle_on_every_route(monkeypatch, route, key_type):
+    """Both batch verifiers submit to the engine and nothing else, so on
+    each route the engine can choose (forced by the two cutovers) the
+    (ok, bools) of a mixed-validity corpus are the pure-Python oracle's,
+    and the launches are counted under that route's name."""
+    from tendermint_tpu.metrics import engine_metrics
+
+    corpus, verifier, pubkey, oracle = {"ed25519": _ed25519_corpus,
+                                        "sr25519": _sr25519_corpus}[key_type]()
+    device, msm = _ROUTE_CUTOVERS[route]
+    monkeypatch.setenv("TM_TPU_CRYPTO", "on")
+    monkeypatch.setattr(ed, "DEVICE_BATCH_CUTOVER", device)
+    monkeypatch.setattr(ed, "MSM_BATCH_CUTOVER", msm)
+
+    def launches():
+        return {labels["path"]: v for _, labels, v in engine_metrics().launches.samples()
+                if labels["plane"] == key_type}
+
+    before = launches()
+    for pks, msgs, sigs in corpus:
+        bv = verifier()
         for p, m, s in zip(pks, msgs, sigs):
-            bv.add(Ed25519PubKey(p), m, s)
+            bv.add(pubkey(p), m, s)
+        ok, bools = bv.verify()
+        want = [oracle(p, m, s) for p, m, s in zip(pks, msgs, sigs)]
+        assert bools == want
+        assert ok == all(want)
+    def grown():
+        return {p: v - before.get(p, 0.0) for p, v in launches().items() if v != before.get(p, 0.0)}
+
+    # the collect worker counts a launch after it has woken the caller
+    deadline = time.monotonic() + 10
+    while grown() != {route: len(corpus)} and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert grown() == {route: len(corpus)}
+
+
+_HOST_ONLY_NODE = """
+    import sys
+
+    from tendermint_tpu.crypto import ed25519_ref as ref
+    from tendermint_tpu.crypto import sr25519 as sr
+    from tendermint_tpu.crypto.ed25519 import Ed25519BatchVerifier, Ed25519PubKey
+    from tendermint_tpu.mempool.preverify import EngineTxPreVerifier, make_sig_tx
+
+    def tampered(sig):
+        return sig[:10] + bytes([sig[10] ^ 0xFF]) + sig[11:]
+
+    def verdicts(verifier, jobs):
+        bv = verifier()
+        for job in jobs:
+            bv.add(*job)
         return bv.verify()
 
-    for force_device in (False, True):
-        if force_device:
-            monkeypatch.setattr(ed, "DEVICE_BATCH_CUTOVER", 4)
-            monkeypatch.setattr(ed, "MSM_BATCH_CUTOVER", 4)
-        got_on = []
-        monkeypatch.setenv("TM_TPU_ENGINE", "auto")
-        for pks, msgs, sigs in corpus:
-            got_on.append(run(pks, msgs, sigs))
-        monkeypatch.setenv("TM_TPU_ENGINE", "off")
-        got_off = [run(pks, msgs, sigs) for pks, msgs, sigs in corpus]
-        assert got_on == got_off
-        for (ok, bools), (pks, msgs, sigs) in zip(got_on, corpus):
-            want = [ref.verify(p, m, s, zip215=True) for p, m, s in zip(pks, msgs, sigs)]
-            assert bools == want
-            assert ok == all(want)
+    sks = [ref.gen_privkey(bytes([i + 1]) * 32) for i in range(3)]
+    ed_jobs = [(Ed25519PubKey(sk[32:]), b"vote", ref.sign(sk, b"vote")) for sk in sks]
+    assert verdicts(Ed25519BatchVerifier, ed_jobs) == (True, [True] * 3)
+    ed_jobs[1] = (ed_jobs[1][0], b"vote", tampered(ed_jobs[1][2]))
+    assert verdicts(Ed25519BatchVerifier, ed_jobs) == (False, [True, False, True])
+
+    privs = [sr.Sr25519PrivKey.generate(b"host-only-%d" % i) for i in range(3)]
+    sr_jobs = [(p.pub_key(), b"vote", p.sign(b"vote")) for p in privs]
+    assert verdicts(sr.Sr25519BatchVerifier, sr_jobs) == (True, [True] * 3)
+    sr_jobs[2] = (sr_jobs[2][0], b"vote", tampered(sr_jobs[2][2]))
+    assert verdicts(sr.Sr25519BatchVerifier, sr_jobs) == (False, [True, True, False])
+
+    good = make_sig_tx(b"\\x11" * 32, b"pay=1")
+    bad = good[:-1] + bytes([good[-1] ^ 1])
+    assert EngineTxPreVerifier()([good, b"plain=1"]) == [True, None]
+    assert EngineTxPreVerifier()([good, bad, b"plain=1"]) == [True, False, None]
+
+    from tendermint_tpu.metrics import engine_metrics
+    paths = {labels["path"] for _, labels, _ in engine_metrics().launches.samples()}
+    assert paths == {"host"}, paths
+    assert "jax" not in sys.modules, "a host-only node imported jax"
+    print("HOST-ONLY-OK")
+"""
+
+
+def test_host_only_node_verifies_through_the_engine_without_importing_jax():
+    """A node pinned to the host (TM_TPU_CRYPTO=off, the e2e core gate's
+    pin) verifies commits of both key types and signed transactions
+    through the engine's host plane, refuses a tampered signature in
+    each, and never imports jax. A process of its own: this one has."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if not k.startswith("TM_TPU_")}
+    env.update(TM_TPU_CRYPTO="off", TM_TPU_AUTOTUNE="off", PYTHONPATH=root)
+    proc = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(_HOST_ONLY_NODE)],
+        env=env, cwd=root, capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "HOST-ONLY-OK" in proc.stdout
+
+
+def test_engine_host_plane_without_the_native_library(monkeypatch):
+    """TM_TPU_NATIVE=off: the C loop is absent, _host_verify_ed25519
+    falls back to one _single_verify a row, and the engine's verdicts
+    are still the oracle's, ZIP-215 edge included."""
+    from tendermint_tpu import native
+
+    monkeypatch.setenv("TM_TPU_NATIVE", "off")
+    monkeypatch.setattr(ed, "DEVICE_BATCH_CUTOVER", 1 << 30)
+    pks, msgs, sigs = make_jobs(5, tamper_idx={3})
+    pks.append(ref.small_order_points()[1])
+    msgs.append(b"anything")
+    sigs.append(ref.compress(ref.IDENTITY) + b"\x00" * 32)
+    assert native.host_verify_batch(pks, msgs, sigs) is None
+    want = [ref.verify(p, m, s, zip215=True) for p, m, s in zip(pks, msgs, sigs)]
+    assert want == [True, True, True, False, True, True]
+    assert submit_and_wait(pks, msgs, sigs) == want
 
 
 def test_engine_zip215_edge_acceptance():
@@ -371,8 +480,6 @@ def test_a_device_batch_takes_the_program_the_table_says(monkeypatch, kind, rows
     assert ed.DEVICE_BATCH_CUTOVER == 8
     assert isinstance(ed.MSM_BATCH_CUTOVER, int)
     assert (ed.MSM_BATCH_CUTOVER == 256) == (entry is None)
-    # the direct-dispatch copies compare the same number the same way
-    assert (rows >= ed.MSM_BATCH_CUTOVER) == want_msm
 
 
 # ------------------------------------------- ADVICE r5 regression pins
@@ -397,60 +504,10 @@ def test_msm_misaligned_batch_raises_not_truncates(monkeypatch):
         M.msm_verify_kernel_impl(a, r, zk, z, zs)
 
 
-def test_msm_cached_precheck_refusal_never_touches_cache():
-    """ADVICE r5 (low): a batch refused at precheck (malformed row)
-    must not insert anything into the HBM pubkey cache — malformed
-    pubkeys must not evict live validator keys."""
-    import secrets
-
-    from tendermint_tpu.ops import msm as M
-    from tendermint_tpu.ops.verify import pubkey_cache
-
-    pks, msgs, sigs = make_jobs(3)
-    fresh = ref.gen_privkey(secrets.token_bytes(32))[32:]
-    pks.append(fresh)
-    msgs.append(b"m")
-    sigs.append(b"\x00" * 10)  # malformed: fails precheck
-    cache = pubkey_cache()
-    before = dict(cache._lru)
-    assert M.verify_batch_rlc_cached_async(pks, msgs, sigs) is None
-    assert dict(cache._lru) == before  # no insertions, no reordering
-    assert fresh not in cache._lru
-
-
-def test_rlc_cached_overflow_fallback_reuses_prep(monkeypatch):
-    """When the batch holds more distinct keys than the HBM cache, the
-    cached RLC dispatch must fall back to the uncached kernel WITHOUT
-    re-running prepare_batch, and still verify both polarities."""
-    from tendermint_tpu.ops import msm as M
-    from tendermint_tpu.ops import verify as V
-
-    cache = V.PubkeyCache(
-        capacity=2, build_fn=V.build_pk_tables_split,
-        entry_shape=(V.PK_SPLITS, 16, 4, 32),
-    )
-    monkeypatch.setattr(V, "_PK_CACHE", cache)
-    calls = []
-    real_prepare = M.prepare_batch
-
-    def counting_prepare(*a):
-        calls.append(1)
-        return real_prepare(*a)
-
-    monkeypatch.setattr(M, "prepare_batch", counting_prepare)
-    pks, msgs, sigs = make_jobs(4)  # 4 distinct keys > capacity 2
-    z = bytes(range(1, 17)) * 4
-    assert M.collect_rlc(M.verify_batch_rlc_cached_async(pks, msgs, sigs, z_raw=z)) is True
-    assert len(calls) == 1, "fallback re-ran prepare_batch"
-    pks2, msgs2, sigs2 = make_jobs(4, tamper_idx={1})
-    assert M.collect_rlc(M.verify_batch_rlc_cached_async(pks2, msgs2, sigs2, z_raw=z)) is False
-
-
 def test_rlc_precheck_refusal_dispatches_bitmap_immediately(monkeypatch):
-    """ADVICE r5 (low): when the RLC dispatch refuses at precheck, the
-    bitmap kernel must be dispatched at verify_async time (launch-now/
-    collect-later preserved), not deferred to completion."""
-    monkeypatch.setenv("TM_TPU_ENGINE", "off")
+    """ADVICE r5 (low): when the RLC dispatch refuses at precheck,
+    _dispatch_group must launch the bitmap kernel itself (launch-now/
+    collect-later preserved), not leave it to the collect thunk."""
     monkeypatch.setattr(ed, "DEVICE_BATCH_CUTOVER", 4)
     monkeypatch.setattr(ed, "MSM_BATCH_CUTOVER", 4)
     from tendermint_tpu.ops import verify as V
@@ -464,18 +521,15 @@ def test_rlc_precheck_refusal_dispatches_bitmap_immediately(monkeypatch):
 
     monkeypatch.setattr(V, "verify_batch_cached_async", spy)
     pks, msgs, sigs = make_jobs(5)
-    # s >= L: well-formed 64 bytes (passes add()) but fails the RLC
-    # precheck, so _dispatch_rlc returns None
+    # s >= L: well-formed 64 bytes but fails the RLC precheck, so
+    # _dispatch_rlc returns None
     s = int.from_bytes(sigs[2][32:], "little")
     sigs[2] = sigs[2][:32] + int.to_bytes(s + ref.L, 32, "little")
-    bv = Ed25519BatchVerifier()
-    for p, m, s in zip(pks, msgs, sigs):
-        bv.add(Ed25519PubKey(p), m, s)
-    pending = bv.verify_async()
-    assert dispatched_at == ["dispatch"], "bitmap not dispatched at verify_async time"
-    ok, bools = pending()
-    assert ok is False
-    assert bools == [True, True, False, True, True]
+    thunk, path = E.VerifyEngine()._dispatch_group([E._Job("ed25519", pks, msgs, sigs)])
+    assert path == "two_phase_msm"
+    assert dispatched_at == ["dispatch"], "bitmap not dispatched before the collect thunk"
+    assert thunk() == [True, True, False, True, True]
+    assert dispatched_at == ["dispatch"], "the collect thunk dispatched a second bitmap"
 
 
 # ------------------------------------------------------- bench smoke
@@ -516,8 +570,6 @@ def test_engine_trace_and_telemetry_integration(monkeypatch):
     from tendermint_tpu import trace as T
     from tendermint_tpu.metrics import engine_metrics, global_registry
 
-    if not E.engine_enabled():
-        pytest.skip("TM_TPU_ENGINE=off")
     m = engine_metrics()
     overlap_before = _counter_value(m.overlap_seconds)
     launches_before = _counter_value(m.launches)

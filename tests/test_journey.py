@@ -284,10 +284,8 @@ def test_engine_journey_passthrough():
     launch's collect span (the attribution the lens verify split
     reads)."""
     from tendermint_tpu.crypto import ed25519_ref as ref
-    from tendermint_tpu.ops.engine import engine_enabled, get_engine
+    from tendermint_tpu.ops.engine import get_engine
 
-    if not engine_enabled():
-        pytest.skip("TM_TPU_ENGINE=off")
     sk = ref.gen_privkey(b"\x11" * 32)
     pk, msg = sk[32:], b"tmpath-journey-probe"
     sig = ref.sign(sk, msg)
@@ -313,7 +311,6 @@ def test_verify_commit_tags_the_engine_with_its_height():
     host-vs-engine verify split reads."""
     from helpers import make_block_id, make_keys, make_validator_set, sign_commit
     from tendermint_tpu.crypto import BatchVerifier
-    from tendermint_tpu.ops.engine import engine_enabled
     from tendermint_tpu.types.validation import verify_commit
 
     assert BatchVerifier.journey is None  # default: untagged
@@ -334,10 +331,9 @@ def test_verify_commit_tags_the_engine_with_its_height():
     tag = T.journey_key(5, 0, "verify", "")
     dispatch = [e for e in events if e["name"] == "verify.commit_dispatch"]
     assert dispatch and dispatch[0]["args"]["height"] == 5
-    if engine_enabled():
-        tagged = [e for e in events if e["name"] in ("engine.dispatch", "engine.collect")
-                  and tag in ((e.get("args") or {}).get("journeys") or [])]
-        assert tagged, "commit journey tag never reached an engine span"
+    tagged = [e for e in events if e["name"] in ("engine.dispatch", "engine.collect")
+              and tag in ((e.get("args") or {}).get("journeys") or [])]
+    assert tagged, "commit journey tag never reached an engine span"
 
 
 # ------------------------------------------------------ cross-node flows

@@ -53,8 +53,10 @@ def traffic():
     return t
 
 
-def _samples(family, label):
-    return {labels[label]: value for _, labels, value in family.samples()}
+def _samples(family, label, **where):
+    """label value -> sample, over the samples whose other labels are `where`."""
+    return {labels[label]: value for _, labels, value in family.samples()
+            if all(labels[k] == v for k, v in where.items())}
 
 
 def _grown(before, after):
@@ -67,9 +69,8 @@ def test_a_walk_under_each_route_agrees_with_the_reference(traffic, monkeypatch,
     monkeypatch.setattr(ed, "DEVICE_BATCH_CUTOVER", device)
     monkeypatch.setattr(ed, "MSM_BATCH_CUTOVER", msm)
     monkeypatch.setenv("TM_TPU_PK_CACHE", pk_cache)
-    monkeypatch.setenv("TM_TPU_ENGINE", "auto")
     chain, m = traffic.chain, engine_metrics()
-    paths = _samples(m.launches, "path")
+    paths = _samples(m.launches, "path", plane="ed25519")
     launched = _samples(m.kernel_launches, "kernel")
 
     client = traffic.new_client()
@@ -80,7 +81,7 @@ def test_a_walk_under_each_route_agrees_with_the_reference(traffic, monkeypatch,
         # benchmark/reference.commit_verdict on the 1/3 and the 2/3 check
         assert traffic._verdicts(stored.signed_header.commit) == (True, True)
     # every batch of the walk took the route under test, through its own kernel
-    assert set(_grown(paths, _samples(m.launches, "path"))) == {path}
+    assert set(_grown(paths, _samples(m.launches, "path", plane="ed25519"))) == {path}
     assert set(_grown(launched, _samples(m.kernel_launches, "kernel"))) - {"pk_table_build"} \
         == kernels
 
@@ -109,7 +110,8 @@ def test_a_walk_under_each_route_agrees_with_the_reference(traffic, monkeypatch,
 
 def _stub_tables(enc):
     """A table build that launches nothing: the counters count rows."""
-    return (jnp.zeros((enc.shape[0], 16, 4, 32), jnp.int16), jnp.ones((enc.shape[0],), bool))
+    return (jnp.zeros((enc.shape[0], V.PK_SPLITS, 16, 4, 32), jnp.int16),
+            jnp.ones((enc.shape[0],), bool))
 
 
 KEYS = [bytes([i]) * 32 for i in range(8)]

@@ -418,29 +418,27 @@ def test_preverify_envelope_roundtrip_and_verdicts():
     assert verdicts == [True, False, None]
 
 
-def test_preverify_batch_admission_outcomes(monkeypatch):
+def test_preverify_batch_admission_outcomes():
     """Signed-flood admission: invalid signatures are rejected before
-    the app, valid and unsigned txs admit; engine off (direct
-    per-signature path) produces identical verdicts."""
+    the app, valid and unsigned txs admit; one tx at a time gives the
+    same verdicts."""
     from tendermint_tpu.mempool.preverify import EngineTxPreVerifier, make_sig_tx
 
     good = make_sig_tx(b"\x11" * 32, b"a=1")
     bad = good[:-1] + bytes([good[-1] ^ 1])
     plain = b"k=1"
-    for engine_env in ("auto", "off"):
-        monkeypatch.setenv("TM_TPU_ENGINE", engine_env)
-        mp = TxMempool(_DirectClient(PriorityApp()), pre_verify=EngineTxPreVerifier())
-        out = mp.check_tx_batch([good, bad, plain])
-        assert out[0].is_ok and out[2].is_ok
-        assert out[1].code == 1 and "signature" in out[1].log
-        assert mp.size() == 2
-        # rejected sig left the cache: resubmission re-evaluates
-        out2 = mp.check_tx_batch([bad])
-        assert out2[0].code == 1
-        # sequential parity
-        mp2 = TxMempool(_DirectClient(PriorityApp()), pre_verify=EngineTxPreVerifier())
-        assert mp2.check_tx(good).is_ok
-        assert mp2.check_tx(bad).code == 1
+    mp = TxMempool(_DirectClient(PriorityApp()), pre_verify=EngineTxPreVerifier())
+    out = mp.check_tx_batch([good, bad, plain])
+    assert out[0].is_ok and out[2].is_ok
+    assert out[1].code == 1 and "signature" in out[1].log
+    assert mp.size() == 2
+    # rejected sig left the cache: resubmission re-evaluates
+    out2 = mp.check_tx_batch([bad])
+    assert out2[0].code == 1
+    # sequential parity
+    mp2 = TxMempool(_DirectClient(PriorityApp()), pre_verify=EngineTxPreVerifier())
+    assert mp2.check_tx(good).is_ok
+    assert mp2.check_tx(bad).code == 1
 
 
 def test_batch_duplicates_reach_app_exactly_as_sequential():

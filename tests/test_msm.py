@@ -95,36 +95,6 @@ def test_msm_empty_batch():
     assert msm.verify_batch_rlc([], [], []) is False
 
 
-def test_msm_cached_matches_uncached():
-    """Cache-hit MSM (split power tables, no A decompress/build) must
-    agree with the uncached MSM on both verdict polarities, including
-    the ZIP-215 oddballs that live in the cache."""
-    from tendermint_tpu.ops.msm import (
-        collect_rlc,
-        verify_batch_rlc,
-        verify_batch_rlc_cached_async,
-    )
-
-    pks, msgs, sigs = make_jobs(8)
-    so = ref.small_order_points()[1]
-    pks.append(so)
-    msgs.append(b"anything")
-    sigs.append(ref.compress(ref.IDENTITY) + b"\x00" * 32)
-    for i in range(7):  # pad to 16 with more valid jobs
-        p2, m2, s2 = make_jobs(1)
-        pks.append(p2[0]); msgs.append(m2[0]); sigs.append(s2[0])
-    z = Z16 * len(sigs)
-    assert collect_rlc(verify_batch_rlc_cached_async(pks, msgs, sigs, z_raw=z)) is True
-    assert verify_batch_rlc(pks, msgs, sigs, z_raw=z) is True
-    # tamper one: both planes reject
-    bad = bytearray(sigs[4]); bad[1] ^= 1
-    sigs2 = list(sigs); sigs2[4] = bytes(bad)
-    assert collect_rlc(verify_batch_rlc_cached_async(pks, msgs, sigs2, z_raw=z)) is False
-    assert verify_batch_rlc(pks, msgs, sigs2, z_raw=z) is False
-    # second cached call is a pure cache hit (keys already resident)
-    assert collect_rlc(verify_batch_rlc_cached_async(pks, msgs, sigs, z_raw=z)) is True
-
-
 def test_msm_sharded_8_devices():
     """Sharded RLC over the virtual 8-device mesh: per-shard equations
     with per-shard zs partials, one psum AND-reduce verdict."""

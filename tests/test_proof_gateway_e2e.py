@@ -130,8 +130,7 @@ def test_proof_gateway_under_concurrent_bisecting_clients(tmp_path):
     runner = Runner(m, str(tmp_path / "net"), logger=lambda *a: None)
     # the small-box host-crypto pin (run_soak discipline): node
     # processes must not burn the cores on jax imports mid-scenario
-    for k, v in (("TM_TPU_ENGINE", "off"), ("TM_TPU_CRYPTO", "off"),
-                 ("TM_TPU_AUTOTUNE", "off")):
+    for k, v in (("TM_TPU_CRYPTO", "off"), ("TM_TPU_AUTOTUNE", "off")):
         runner.extra_node_env.setdefault(k, os.environ.get(k, v))
     post_gates, watch_gates = gate_overrides_for()
     # tmproof rolling gates, opted in for the whole client window: the

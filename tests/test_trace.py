@@ -311,8 +311,6 @@ def test_engine_workers_name_the_submitters_span(monkeypatch):
     stacks."""
     from tendermint_tpu.ops import engine as E
 
-    if not E.engine_enabled():
-        pytest.skip("TM_TPU_ENGINE=off")
     eng = E.get_engine()
     assert all(eng.submit("ed25519", *_signed_jobs(2, b"warm")).result(timeout=300))
     # Hold the dispatch worker inside the first group's dispatch while
