@@ -211,7 +211,7 @@ class Handshaker:
                 height=block.header.height,
                 time_ns=block.header.time.unix_ns(),
                 txs=list(block.txs),
-                decided_last_commit=executor.build_last_commit_info(block, state.initial_height),
+                decided_last_commit=executor.build_last_commit_info(block, state),
                 misbehavior=evidence_to_abci(block.evidence),
                 proposer_address=block.header.proposer_address,
                 next_validators_hash=block.header.next_validators_hash,

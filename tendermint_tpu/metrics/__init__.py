@@ -600,6 +600,13 @@ class StateMetrics:
         self.block_verify_time = reg.histogram(
             f"{ns}_block_verify_time", "Time of LastCommit verification", buckets=(0.001, 0.01, 0.05, 0.1, 0.5, 1)
         )
+        # "state": the set the caller's State holds; "store": re-derived by
+        # StateStore.load_validators (the handshake's replay of an older block)
+        self.commit_info = reg.counter(
+            f"{ns}_commit_info_total",
+            "Last-commit infos built for the app, by where the validator set came from",
+            labels=("source",),
+        )
         # statetree commit modes: "full" (cold rebuild), "path" (pure
         # updates, dirty root-paths only), "structural" (insert/delete
         # reshapes the tree; unchanged subtrees are memo-copied)

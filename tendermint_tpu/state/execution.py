@@ -183,7 +183,7 @@ class BlockExecutor:
                 height=block.header.height,
                 time_ns=block.header.time.unix_ns(),
                 txs=list(block.txs),
-                proposed_last_commit=self.build_last_commit_info(block, state.initial_height),
+                proposed_last_commit=self.build_last_commit_info(block, state),
                 misbehavior=evidence_to_abci(block.evidence),
                 proposer_address=block.header.proposer_address,
                 next_validators_hash=block.header.next_validators_hash,
@@ -228,7 +228,7 @@ class BlockExecutor:
                     height=block.header.height,
                     time_ns=block.header.time.unix_ns(),
                     txs=list(block.txs),
-                    decided_last_commit=self.build_last_commit_info(block, state.initial_height),
+                    decided_last_commit=self.build_last_commit_info(block, state),
                     misbehavior=evidence_to_abci(block.evidence),
                     proposer_address=block.header.proposer_address,
                     next_validators_hash=block.header.next_validators_hash,
@@ -308,26 +308,39 @@ class BlockExecutor:
 
     # ----------------------------------------------------------- helpers
 
-    def build_last_commit_info(self, block: Block, initial_height: int) -> abci.CommitInfo:
-        """ref: buildLastCommitInfo (execution.go:388)."""
-        if block.header.height == initial_height:
+    def build_last_commit_info(self, block: Block, state: State) -> abci.CommitInfo:
+        """ref: buildLastCommitInfo (execution.go:388). When `block` is
+        the state's next one, the set that signed its last commit is the
+        `state.last_validators` validate_block verified that commit
+        against. Only an older block (the handshake replaying to the
+        app while the state is ahead) reads the store, whose load
+        re-derives proposer priorities nothing here reads, one pass over
+        the set per height since it last changed."""
+        height = block.header.height
+        if height == state.initial_height:
             return abci.CommitInfo()
-        last_val_set = self.store.load_validators(block.header.height - 1)
-        if last_val_set is None:
-            raise RuntimeError(f"failed to load validator set at height {block.header.height - 1}")
-        commit = block.last_commit
-        if commit.size() != last_val_set.size():
-            raise RuntimeError(
-                f"commit size ({commit.size()}) doesn't match validator set length ({last_val_set.size()}) "
-                f"at height {block.header.height}"
-            )
-        votes = [
-            abci.VoteInfo(
-                validator=abci.Validator(address=val.address, power=val.voting_power),
-                signed_last_block=not commit.signatures[i].absent(),
-            )
-            for i, val in enumerate(last_val_set.validators)
-        ]
+        held = height == state.last_block_height + 1
+        source = "state" if held else "store"
+        with _trace.span("state.commit_info", "state", height=height, source=source):
+            last_val_set = state.last_validators if held else self.store.load_validators(height - 1)
+            if last_val_set is None:
+                raise RuntimeError(f"failed to load validator set at height {height - 1}")
+            commit = block.last_commit
+            if commit.size() != last_val_set.size():
+                raise RuntimeError(
+                    f"commit size ({commit.size()}) doesn't match validator set length ({last_val_set.size()}) "
+                    f"at height {height}"
+                )
+            votes = [
+                abci.VoteInfo(
+                    validator=abci.Validator(address=val.address, power=val.voting_power),
+                    signed_last_block=not commit.signatures[i].absent(),
+                )
+                for i, val in enumerate(last_val_set.validators)
+            ]
+        counter = getattr(self.metrics, "commit_info", None)
+        if counter is not None:
+            counter.add(1, source)
         return abci.CommitInfo(round=commit.round, votes=votes)
 
 

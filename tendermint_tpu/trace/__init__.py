@@ -37,7 +37,8 @@ Design constraints:
 
 Span catalog (docs/observability.md): consensus.step (instant) /
 consensus.finalize_commit, state.apply_block / state.validate_block /
-state.finalize_block / state.abci_commit,
+state.finalize_block (state.commit_info under it: the last commit's
+CommitInfo, `source` state or store) / state.abci_commit,
 light.update / light.fetch / light.verify_step / light.header_checks /
 light.detect_divergence / light.store (one light-client update, from
 the caller to the store), verify.commit_walk (address lookup,

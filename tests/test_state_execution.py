@@ -126,6 +126,171 @@ def test_process_proposal_roundtrip():
     assert executor.process_proposal(block, state)
 
 
+# ---- last-commit info from the held state (ISSUE 30) ------------------
+#
+# A chain on which every answer of build_last_commit_info differs from
+# its neighbours': a validator joins (tx at 2, in force from 4), one
+# changes power (tx at 4, in force from 6), and the commits for heights
+# 2 and 6 each lack one signature.
+
+CI_HEIGHTS = 8
+CI_JOIN_AT, CI_POWER_AT = 2, 4
+CI_ABSENT_IN_COMMIT_FOR = (2, 6)
+
+
+@pytest.fixture(scope="module")
+def commit_info_chain():
+    from types import SimpleNamespace
+
+    from tendermint_tpu.abci.kvstore import make_validator_tx
+    from tendermint_tpu.crypto.ed25519 import Ed25519PrivKey
+
+    keys, state, executor, state_store, block_store, _ = make_chain_fixtures()
+    joiner = Ed25519PrivKey.generate(b"\x77" * 32)
+    keys = keys + [joiner]
+    txs_at = {
+        CI_JOIN_AT: [make_validator_tx(joiner.pub_key().bytes(), 5)],
+        CI_POWER_AT: [make_validator_tx(keys[0].pub_key().bytes(), 25)],
+    }
+    base_t = 1_700_000_001 * 10**9
+    before, blocks = {}, {}
+    commit, bid = Commit(height=0), None
+    for h in range(1, CI_HEIGHTS + 1):
+        if h > 1:
+            signers = keys[:3] + keys[4:] if h - 1 in CI_ABSENT_IN_COMMIT_FOR else keys
+            commit = sign_commit(CHAIN_ID, state.last_validators, signers, h - 1, 0, bid)
+        before[h] = state
+        proposer = state.validators.get_proposer()
+        blocks[h] = state.make_block(h, txs_at.get(h, []), commit, [], proposer.address,
+                                     Time.from_unix_ns(base_t + h * 10**9))
+        state, bid = propose_and_apply(keys, state, executor, block_store, txs_at.get(h, []), commit, h,
+                                       base_t + h * 10**9)
+    # the set that signed the commit in block h: the join in force from 4, the power change from 6
+    assert [before[h].last_validators.size() for h in (4, 5)] == [4, 5]
+    assert [before[h].last_validators.total_voting_power() for h in (6, 7)] == [45, 60]
+    return SimpleNamespace(executor=executor, store=state_store, before=before, blocks=blocks, tip=state)
+
+
+def commit_info_from_store(store, block):
+    """What build_last_commit_info answered before ISSUE 30: the set
+    re-derived from the store, whatever the caller held."""
+    from tendermint_tpu.abci import types as abci
+
+    vals = store.load_validators(block.header.height - 1)
+    commit = block.last_commit
+    assert commit.size() == vals.size()
+    return abci.CommitInfo(
+        round=commit.round,
+        votes=[
+            abci.VoteInfo(validator=abci.Validator(address=v.address, power=v.voting_power),
+                          signed_last_block=not commit.signatures[i].absent())
+            for i, v in enumerate(vals.validators)
+        ],
+    )
+
+
+@pytest.fixture
+def store_reads(commit_info_chain, monkeypatch):
+    """The heights StateStore.load_validators was asked for."""
+    reads = []
+    load = commit_info_chain.store.load_validators
+    monkeypatch.setattr(commit_info_chain.store, "load_validators", lambda h: reads.append(h) or load(h))
+    return reads
+
+
+@pytest.mark.parametrize("source", ["state", "store"])
+@pytest.mark.parametrize("height", range(1, CI_HEIGHTS + 1))
+def test_commit_info_equals_the_stores_answer(commit_info_chain, store_reads, height, source):
+    """Held state (the block is the state's next) and the fallback (an
+    older block while the state is ahead, the handshake's
+    _exec_block_on_app) both give, field for field, the CommitInfo built
+    from store.load_validators(height - 1); only the fallback reads it."""
+    c = commit_info_chain
+    block = c.blocks[height]
+    state = c.before[height] if source == "state" else c.tip
+    info = c.executor.build_last_commit_info(block, state)
+    if height == 1:
+        assert info == type(info)() and store_reads == []
+        return
+    assert store_reads == ([] if source == "state" else [height - 1])
+    assert info == commit_info_from_store(c.store, block)
+    assert [v.signed_last_block for v in info.votes].count(False) == (height - 1 in CI_ABSENT_IN_COMMIT_FOR)
+
+
+@pytest.mark.parametrize("entry", ["apply_block", "process_proposal"])
+def test_next_block_never_reads_validators_from_the_store(entry):
+    """The O(height) re-derivation cannot come back unnoticed: with the
+    block at last_block_height + 1, neither entry point may reach
+    StateStore.load_validators."""
+    keys, state, executor, state_store, block_store, _ = make_chain_fixtures()
+    base_t = 1_700_000_001 * 10**9
+    s, bid = propose_and_apply(keys, state, executor, block_store, [], Commit(height=0), 1, base_t)
+    reads = []
+    state_store.load_validators = lambda h: reads.append(h)  # returns None: a read would also raise
+    for h in (2, 3):
+        commit = sign_commit(CHAIN_ID, s.last_validators, keys, h - 1, 0, bid)
+        if entry == "process_proposal":
+            proposer = s.validators.get_proposer()
+            block = s.make_block(h, [], commit, [], proposer.address, Time.from_unix_ns(base_t + h * 10**9))
+            assert executor.process_proposal(block, s)
+        s, bid = propose_and_apply(keys, s, executor, block_store, [], commit, h, base_t + h * 10**9)
+    assert reads == []
+
+
+@pytest.mark.parametrize("source", ["state", "store", "store-missing"])
+def test_commit_info_refusals_are_unchanged(commit_info_chain, source):
+    """A commit of another size than the set, from either source, and a
+    height the store does not hold raise the RuntimeErrors they raised."""
+    import copy
+
+    c = commit_info_chain
+    block = copy.deepcopy(c.blocks[5])
+    state = c.before[5] if source == "state" else c.tip
+    executor = c.executor
+    if source == "store-missing":
+        executor = BlockExecutor(StateStore(MemDB()), None)
+        match = "failed to load validator set at height 4"
+    else:
+        block.last_commit.signatures.pop()
+        match = r"commit size \(4\) doesn't match validator set length \(5\) at height 5"
+    with pytest.raises(RuntimeError, match=match):
+        executor.build_last_commit_info(block, state)
+
+
+def test_commit_info_span_and_counter_name_the_source():
+    """apply_block opens state.commit_info under its state.finalize_block
+    span, an older block replayed against a state that is ahead reads
+    source="store", the initial height builds nothing; and
+    commit_info_total{source} counts one per span."""
+    from tendermint_tpu import trace as T
+    from tendermint_tpu.metrics import Registry, StateMetrics
+
+    keys, state, executor, _, block_store, _ = make_chain_fixtures()
+    executor.metrics = StateMetrics(Registry())
+    base_t = 1_700_000_001 * 10**9
+    was = T.enabled()
+    T.set_enabled(True)
+    T.clear()
+    try:
+        s1, bid1 = propose_and_apply(keys, state, executor, block_store, [], Commit(height=0), 1, base_t)
+        commit1 = sign_commit(CHAIN_ID, s1.last_validators, keys, 1, 0, bid1)
+        s2, _ = propose_and_apply(keys, s1, executor, block_store, [], commit1, 2, base_t + 10**9)
+        executor.build_last_commit_info(block_store.load_block(2), s2)
+        events = {}
+        for e in T.export()["traceEvents"]:
+            if e["ph"] == "X":
+                events.setdefault(e["name"], []).append(e["args"])
+    finally:
+        T.clear()
+        T.set_enabled(was)
+    held, replayed = events["state.commit_info"]
+    assert (held["height"], held["source"]) == (2, "state")
+    assert (replayed["height"], replayed["source"]) == (2, "store")
+    assert held["parent"] == events["state.finalize_block"][1]["span"] != replayed["parent"]
+    counted = {lbl["source"]: n for _, lbl, n in executor.metrics.commit_info.samples()}
+    assert counted == {"state": 1, "store": 1}
+
+
 def test_state_store_validator_lookup():
     keys, state, executor, state_store, block_store, app = make_chain_fixtures()
     base_t = 1_700_000_001 * 10**9
