@@ -42,6 +42,7 @@ GROUPS = (
     "EngineMetrics",
     "HashMetrics",
     "ProofMetrics",
+    "LightMetrics",
     "FlightMetrics",
     "DeviceMetrics",
 )

@@ -7,9 +7,11 @@ and persists trusted light blocks.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 
 from .. import trace as _trace
+from ..metrics import light_metrics as _light_metrics
 from ..types.evidence import LightClientAttackEvidence
 from ..types.light_block import LightBlock
 from ..types.validation import Fraction
@@ -92,7 +94,7 @@ class LightClient:
         height = self.trust_options.height
         # a client's trust root is an update too: fetched, checked, stored
         with _trace.span("light.update", "light", height=height, mode="root"):
-            with _trace.span("light.fetch", "light", provider="primary", height=height):
+            with self._fetch_span("target", height):
                 lb = self.primary.light_block(height)
                 lb.validate_basic(self.chain_id)
             if lb.signed_header.hash() != self.trust_options.hash:
@@ -127,7 +129,7 @@ class LightClient:
         """Verify the primary's latest header (ref: client.go:380 Update)."""
         now = now or self.now()
         with _trace.span("light.update", "light", height=0, mode=self.mode) as sp:
-            with _trace.span("light.fetch", "light", provider="primary", height=0):
+            with self._fetch_span("target", 0):
                 latest = self.primary.light_block(0)
             sp.annotate(height=latest.height)
             trusted = self.store.latest_light_block()
@@ -145,7 +147,8 @@ class LightClient:
                     )
                 return trusted
             # verify the block already in hand — no refetch round-trip
-            with _trace.span("light.fetch", "light", provider="primary", height=latest.height):
+            with _trace.span("light.fetch", "light", provider="primary", height=latest.height,
+                             purpose="target"):
                 latest.validate_basic(self.chain_id)
             self._verify_light_block(latest, now)
             return latest
@@ -164,7 +167,7 @@ class LightClient:
                 raise LightClientError("light client not initialized")
             if height < latest.height:
                 return self._verify_backwards(height, latest, now)
-            with _trace.span("light.fetch", "light", provider="primary", height=height):
+            with self._fetch_span("target", height):
                 lb = self.primary.light_block(height)
                 lb.validate_basic(self.chain_id)
             self._verify_light_block(lb, now)
@@ -225,13 +228,13 @@ class LightClient:
                         self.trust_options.trust_level,
                     )
             except Exception as e:
-                sp.annotate(
-                    outcome="bisect" if isinstance(e, vf.ErrNewValSetCantBeTrusted)
-                    else "invalid" if isinstance(e, vf.ErrInvalidHeader) else "error",
-                    error=type(e.__context__ or e).__name__,
-                )
+                outcome = ("bisect" if isinstance(e, vf.ErrNewValSetCantBeTrusted)
+                           else "invalid" if isinstance(e, vf.ErrInvalidHeader) else "error")
+                sp.annotate(outcome=outcome, error=type(e.__context__ or e).__name__)
+                _light_metrics().verify_steps.add(1, outcome)
                 raise
             sp.annotate(outcome="ok")
+            _light_metrics().verify_steps.add(1, "ok")
 
     def _verify_sequential(self, trusted: LightBlock, new_lb: LightBlock, now: Time) -> list[LightBlock]:
         """Verify every height in (trusted, new]; returns the verified
@@ -239,7 +242,7 @@ class LightClient:
         current = trusted
         verified: list[LightBlock] = []
         for h in range(trusted.height + 1, new_lb.height + 1):
-            lb = new_lb if h == new_lb.height else self._fetch(self.primary, h)
+            lb = new_lb if h == new_lb.height else self._fetch(self.primary, h, "sequential")
             self._verify_step(current, lb, now)
             if h != new_lb.height:
                 verified.append(lb)
@@ -273,7 +276,7 @@ class LightClient:
                     raise LightClientError(
                         f"cannot bisect between adjacent heights {current.height}/{candidate.height}"
                     )
-                mid_lb = self._fetch(self.primary, mid)
+                mid_lb = self._fetch(self.primary, mid, "pivot")
                 pending.append(mid_lb)
         return [lb for lb in verified[1:] if lb.height != target.height]
 
@@ -282,7 +285,7 @@ class LightClient:
         backwards)."""
         current = from_lb
         for h in range(from_lb.height - 1, height - 1, -1):
-            lb = self._fetch(self.primary, h)
+            lb = self._fetch(self.primary, h, "sequential")
             lb.validate_basic(self.chain_id)
             if lb.signed_header.hash() != current.signed_header.header.last_block_id.hash:
                 raise LightClientError(
@@ -292,12 +295,23 @@ class LightClient:
         self.store.save_light_block(current)
         return current
 
-    def _fetch(self, provider: Provider, height: int) -> LightBlock:
+    @contextlib.contextmanager
+    def _fetch_span(self, purpose: str, height: int, provider: str = "primary"):
+        """The span around one fetch from a provider, counted by what
+        the block is for: the height asked for (`target`, a trust root
+        among them), a bisection's midpoint (`pivot`), a witness's copy
+        (`witness`), one height of a hash-chain walk (`sequential`)."""
+        _light_metrics().fetches.add(1, purpose)
+        with _trace.span("light.fetch", "light", provider=provider, height=height,
+                         purpose=purpose):
+            yield
+
+    def _fetch(self, provider: Provider, height: int, purpose: str) -> LightBlock:
         last_err = None
         for _ in range(MAX_RETRY_ATTEMPTS):
             try:
-                with _trace.span("light.fetch", "light", height=height,
-                                 provider="primary" if provider is self.primary else "witness"):
+                with self._fetch_span(purpose, height,
+                                      "primary" if provider is self.primary else "witness"):
                     lb = provider.light_block(height)
                     lb.validate_basic(self.chain_id)
                 return lb
@@ -342,8 +356,7 @@ class LightClient:
             lagging = []
             for witness in remaining:
                 try:
-                    with _trace.span("light.fetch", "light", provider="witness",
-                                     height=new_lb.height):
+                    with self._fetch_span("witness", new_lb.height, "witness"):
                         w_lb = witness.light_block(new_lb.height)
                 except ErrLightBlockNotFound:
                     lagging.append(witness)

@@ -931,6 +931,30 @@ class ProofMetrics:
         )
 
 
+class LightMetrics:
+    """The light client's own counters (light/client.py): what its
+    fetches were for and how its trust steps ended. A client that has
+    fallen behind a rotating validator set bisects: `outcome="bisect"`
+    steps and `purpose="pivot"` fetches are what that costs beyond the
+    steps that succeed (ref: light/client.go verifySkipping; the
+    reference counts neither). Registered on the process-global
+    registry: a process may run many clients (the proxy, statesync,
+    the benchmark's walks) and the counters are theirs together."""
+
+    def __init__(self, reg: Registry):
+        ns = f"{NAMESPACE}_light"
+        self.verify_steps = reg.counter(
+            f"{ns}_verify_steps_total",
+            "Trust steps by outcome (ok, bisect, invalid, error)",
+            labels=("outcome",),
+        )
+        self.fetches = reg.counter(
+            f"{ns}_fetches_total",
+            "Light blocks asked of a provider, by purpose (target, pivot, witness, sequential)",
+            labels=("purpose",),
+        )
+
+
 class FlightMetrics:
     """Self-telemetry for the in-run flight recorder
     (metrics/flight.py): how many timeseries.jsonl records this node
@@ -1043,6 +1067,7 @@ _ENGINE_METRICS: EngineMetrics | None = None
 _HASH_METRICS: HashMetrics | None = None
 _PROOF_METRICS: ProofMetrics | None = None
 _DEVICE_METRICS: DeviceMetrics | None = None
+_LIGHT_METRICS: LightMetrics | None = None
 _ENGINE_LOCK = threading.Lock()
 
 
@@ -1093,6 +1118,17 @@ def device_metrics() -> DeviceMetrics:
             if _DEVICE_METRICS is None:
                 _DEVICE_METRICS = DeviceMetrics(_GLOBAL_REGISTRY)
     return _DEVICE_METRICS
+
+
+def light_metrics() -> LightMetrics:
+    """Lazy process-wide LightMetrics singleton (a light client's first
+    fetch or trust step registers the families)."""
+    global _LIGHT_METRICS
+    if _LIGHT_METRICS is None:
+        with _ENGINE_LOCK:
+            if _LIGHT_METRICS is None:
+                _LIGHT_METRICS = LightMetrics(_GLOBAL_REGISTRY)
+    return _LIGHT_METRICS
 
 
 class PrometheusServer:

@@ -119,6 +119,15 @@ def verify_kernel_cached_split_impl(tables, oks, slots, r_enc, s_bytes, k_bytes)
 verify_kernel_cached_split = jax.jit(verify_kernel_cached_split_impl)
 
 
+@jax.jit
+def _publish_pk_tables(tables, oks, idx, new_tables, new_oks):
+    """A fill's rows into the cache's arrays, as NEW arrays (in-flight
+    batches keep the ones they were dispatched with). One program per
+    padded row count: rows whose index is out of range are dropped."""
+    return (tables.at[idx].set(new_tables, mode="drop"),
+            oks.at[idx].set(new_oks, mode="drop"))
+
+
 class PubkeyCache:
     """HBM-resident decompressed-pubkey cache (the device analog of the
     reference's 4096-entry expanded-pubkey LRU, crypto/ed25519/
@@ -237,16 +246,24 @@ class PubkeyCache:
                     ev.wait()
                 continue  # retry: the fills we waited on moved the LRU
             # ---- build OUTSIDE the lock (the expensive device call)
+            # Both programs of a fill run at the launch bucket of the
+            # batch that missed, whatever the number of misses: the
+            # rows past the misses decode a zero key and scatter to
+            # slot `capacity`, out of range, which the publish drops.
+            # A rotating validator set misses 1, 3, 13 keys of a batch;
+            # shapes cut to the miss count compiled inside each update.
+            m, rows = len(missing), _pad_pow2(len(pubkeys))
             try:
-                enc = np.frombuffer(b"".join(missing), np.uint8).reshape(-1, 32)
-                (enc_p,) = pad_pow2_rows([enc], len(missing))
+                enc_p = np.zeros((rows, 32), np.uint8)
+                enc_p[:m] = np.frombuffer(b"".join(missing), np.uint8).reshape(-1, 32)
+                idx_p = np.full((rows,), self.capacity, np.int32)
+                idx_p[:m] = idx
                 fid = _devobs.next_flow() if _devobs.enabled() else 0
-                with _trace.span("ops.pk_cache_fill", "ops", misses=len(missing), flow=fid):
-                    with _devobs.transfer_span("h2d", enc_p.nbytes, flow=fid):
-                        enc_dev = jnp.asarray(enc_p)
+                with _trace.span("ops.pk_cache_fill", "ops", misses=m, rows=rows, flow=fid):
+                    with _devobs.transfer_span("h2d", enc_p.nbytes + idx_p.nbytes, flow=fid):
+                        enc_dev, idx_dev = jnp.asarray(enc_p), jnp.asarray(idx_p)
                     with _devobs.attribution(
-                        fn=f"{self.plane}_table_build",
-                        rows=_pad_pow2(len(missing)), flow=fid,
+                        fn=f"{self.plane}_table_build", rows=rows, flow=fid,
                     ):
                         new_tables, new_oks = self._build(enc_dev)
                 _engine_metrics().kernel_launches.add(1, "pk_table_build")
@@ -259,10 +276,10 @@ class PubkeyCache:
                     self._unpin(distinct)
                 event.set()  # waiters retry against the rolled-back state
                 raise
-            m = len(missing)
             with self._lock:
-                self.tables = self.tables.at[idx].set(new_tables[:m])
-                self.oks = self.oks.at[idx].set(new_oks[:m])
+                with _devobs.attribution(fn=f"{self.plane}_table_publish", rows=rows, flow=fid):
+                    self.tables, self.oks = _publish_pk_tables(
+                        self.tables, self.oks, idx_dev, new_tables, new_oks)
                 for pk in missing:
                     if self._pending.get(pk) is event:
                         del self._pending[pk]
