@@ -364,8 +364,19 @@ class LightClient:
                 except (ProviderError, OSError):
                     # hard-down witness (network error): no retry value
                     continue
+                # The cross-check reads the header alone, so a witness's
+                # copy never has its commit or its validator set decoded
+                # (types/light_block.py) unless it diverges: the evidence
+                # carries the whole block. One whose parts are then no
+                # messages gave no usable block, like a hard-down witness.
+                diverged = w_lb.signed_header.hash() != primary_hash
+                if diverged:
+                    try:
+                        w_lb.read_parts()
+                    except ValueError:
+                        continue
                 cross_referenced += 1
-                if w_lb.signed_header.hash() == primary_hash:
+                if not diverged:
                     continue
                 # Diverging witness: build attack evidence against
                 # whichever chain is lying, with the ABCI component

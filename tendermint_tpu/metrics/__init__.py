@@ -953,6 +953,12 @@ class LightMetrics:
             "Light blocks asked of a provider, by purpose (target, pivot, witness, sequential)",
             labels=("purpose",),
         )
+        self.block_parts = reg.counter(
+            f"{ns}_block_parts_total",
+            "A light block's large parts (validator_set, commit): deferred when a block is built from "
+            "its proto with the part left as it arrived, read when such a part is decoded and built",
+            labels=("part", "event"),
+        )
 
 
 class FlightMetrics:

@@ -13,6 +13,10 @@ A class's `fields` list is compiled once, on the first use of that class,
 into the source of its decode, encode and `__init__` functions (as
 `dataclasses` generates `__init__`); `_Codec` holds them on the class.
 Nothing interprets `fields` per message.
+
+A `lazy` sub-message is decoded when it is first read: `decode` checks
+its framing and keeps its slice of the buffer (`_Unread`), so a caller
+that reads a header out of a light block pays for no validator set.
 """
 
 from __future__ import annotations
@@ -42,9 +46,9 @@ _SCALARS = {
 
 
 class Field:
-    __slots__ = ("number", "ftype", "name", "repeated", "always_emit", "msg_cls")
+    __slots__ = ("number", "ftype", "name", "repeated", "always_emit", "msg_cls", "lazy")
 
-    def __init__(self, number, ftype, name, repeated=False, always_emit=False, msg_cls=None):
+    def __init__(self, number, ftype, name, repeated=False, always_emit=False, msg_cls=None, lazy=False):
         self.number = number
         self.ftype = ftype
         self.name = name
@@ -53,6 +57,8 @@ class Field:
         # embedded messages: the field is marshaled unconditionally.
         self.always_emit = always_emit
         self.msg_cls = msg_cls  # class or callable returning class (for cycles)
+        # a nullable sub-message whose inside `decode` leaves as bytes until the field is read
+        self.lazy = lazy
 
     def message_class(self):
         cls = self.msg_cls
@@ -68,6 +74,51 @@ def _scalar(ftype: str):
         raise TypeError(f"unknown scalar type {ftype}") from None
 
 
+class Deferred:
+    """What a `DeferredAttr` holds until it is first read."""
+
+    __slots__ = ()
+
+    def read(self):
+        raise NotImplementedError
+
+
+class DeferredAttr:
+    """An attribute that may be set to a `Deferred`, which the first
+    read replaces by what it reads: from then on the attribute is an
+    ordinary object, and what the `Deferred` held is let go. Two threads
+    that read at once both read it, and both results are equal. A read
+    that raises leaves the attribute as it was."""
+
+    def __init__(self, name: str):
+        self.slot = "_" + name
+
+    def __get__(self, obj, owner=None):
+        if obj is None:
+            raise AttributeError(self.slot)  # as a dataclass field: no default
+        v = obj.__dict__[self.slot]
+        if isinstance(v, Deferred):
+            v = obj.__dict__[self.slot] = v.read()
+        return v
+
+    def __set__(self, obj, value):
+        obj.__dict__[self.slot] = value
+
+
+class _Unread(Deferred):
+    """A lazy field's sub-message as it lies in the buffer its parent
+    was decoded from: framing checked, inside not yet looked at. Bytes
+    that are no message raise on `read` what `decode` raises on them."""
+
+    __slots__ = ("cls", "buf", "start", "end")
+
+    def __init__(self, cls, buf: bytes, start: int, end: int):
+        self.cls, self.buf, self.start, self.end = cls, buf, start, end
+
+    def read(self):
+        return self.cls.decode(self.buf[self.start : self.end])
+
+
 class Message:
     """Base class; subclasses set `fields = [Field(...), ...]`."""
 
@@ -77,6 +128,11 @@ class Message:
     def __init_subclass__(cls, **kwargs):
         super().__init_subclass__(**kwargs)
         cls._codec = None  # a subclass never runs its parent's plan
+        for f in cls.__dict__.get("fields", ()):
+            if f.lazy:
+                if f.ftype != "message" or f.repeated or f.always_emit:
+                    raise TypeError(f"{cls.__name__}.{f.name}: only a nullable sub-message can be lazy")
+                setattr(cls, f.name, DeferredAttr(f.name))
 
     def __init__(self, **kwargs):
         cls = type(self)
@@ -191,6 +247,7 @@ class _Codec:
             "uzigzag": wire.encode_zigzag,
             "B1": wire.ONE_BYTE,
             "join": b"".join,
+            "Unread": _Unread,
         }
         for i, f in enumerate(cls.fields):
             if f.ftype == "message":
@@ -280,9 +337,13 @@ class _Codec:
 
     def _decode_field_lines(self, i: int, f: Field, ind: str) -> list[str]:
         if f.ftype == "message":
-            value = f"dec{i}(buf, pos, e)" if f"dec{i}" in self._ns else f"M{i}.decode(buf[pos:e])"
-            store = f"f{i}.append({value})" if f.repeated else f"f{i} = {value}"
-            return _read_length(ind, "end") + [ind + store, f"{ind}pos = e"]
+            if f.lazy:
+                # only the last occurrence is kept unread: one it replaces is decoded here, for its verdict
+                store = [f"{ind}if f{i} is not None:", f"{ind}    f{i}.read()", f"{ind}f{i} = Unread(M{i}, buf, pos, e)"]
+            else:
+                value = f"dec{i}(buf, pos, e)" if f"dec{i}" in self._ns else f"M{i}.decode(buf[pos:e])"
+                store = [ind + (f"f{i}.append({value})" if f.repeated else f"f{i} = {value}")]
+            return _read_length(ind, "end") + store + [f"{ind}pos = e"]
         kind = _scalar(f.ftype)[2]
         if not f.repeated:
             return _read_scalar(kind, ind, "end", "start", f"f{i}")
@@ -303,7 +364,8 @@ class _Codec:
             return 'def encode(msg):\n    return b""'
         out = ["def encode(msg):", "    parts = []", "    add = parts.append"]
         for i, f in fields:
-            out.append(f"    v = msg.{f.name}")
+            # a lazy field's slot, not its attribute: encoding reads nothing
+            out.append(f"    v = msg.__dict__[{'_' + f.name!r}]" if f.lazy else f"    v = msg.{f.name}")
             out += self._encode_field_lines(i, f)
         out.append("    return join(parts)")
         return "\n".join(out)
@@ -336,9 +398,12 @@ class _Codec:
             # a present message is emitted even when empty (gogo writes
             # tag+len for non-nil pointers); `always_emit` only decides
             # what an absent one defaults to.
+            convert = "{x}.encode()"
             if f"enc{i}" in self._ns:
-                return each(f"enc{i}({{x}}) if {{x}}.__class__ is M{i} else {{x}}.encode()", "v is not None")
-            return each("{x}.encode()", "v is not None")
+                convert = f"enc{i}({{x}}) if {{x}}.__class__ is M{i} else {{x}}.encode()"
+            if f.lazy:  # never read: the bytes it arrived as
+                convert = f"{{x}}.buf[{{x}}.start:{{x}}.end] if {{x}}.__class__ is Unread else ({convert})"
+            return each(convert, "v is not None")
         wt, zero, kind = _scalar(f.ftype)
         if kind == "string":
             return each("{x}.encode('utf-8')", f"v != {zero!r}")

@@ -249,14 +249,14 @@ class SimpleValidator(Message):
 class SignedHeader(Message):
     fields = [
         Field(1, "message", "header", msg_cls=Header),
-        Field(2, "message", "commit", msg_cls=Commit),
+        Field(2, "message", "commit", msg_cls=Commit, lazy=True),
     ]
 
 
 class LightBlock(Message):
     fields = [
         Field(1, "message", "signed_header", msg_cls=SignedHeader),
-        Field(2, "message", "validator_set", msg_cls=ValidatorSet),
+        Field(2, "message", "validator_set", msg_cls=ValidatorSet, lazy=True),
     ]
 
 

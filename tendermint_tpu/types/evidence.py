@@ -217,8 +217,14 @@ class LightClientAttackEvidence:
         from .light_block import LightBlock
 
         t = p.timestamp or pb.Timestamp()
+        conflicting = LightBlock.from_proto(p.conflicting_block)
+        # Evidence is read in full wherever it is verified, and a block
+        # can carry it past the pool's check (pending, matched by the
+        # header's hash): a part that is no message is refused here, at
+        # decoding, not where it is first read.
+        conflicting.read_parts()
         return cls(
-            conflicting_block=LightBlock.from_proto(p.conflicting_block),
+            conflicting_block=conflicting,
             common_height=p.common_height or 0,
             byzantine_validators=[Validator.from_proto(v) for v in (p.byzantine_validators or [])],
             total_voting_power=p.total_voting_power or 0,

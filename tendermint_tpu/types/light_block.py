@@ -1,18 +1,52 @@
-"""SignedHeader and LightBlock (ref: types/light.go)."""
+"""SignedHeader and LightBlock (ref: types/light.go).
+
+A light block's two large parts, the commit and the validator set, are
+built from their proto the first time something reads them (`_Deferred`):
+`from_proto` builds the header alone. A block that is only compared by
+its header's hash (a witness's copy, light/client.py `_cross_reference`)
+never pays for the parts; a block that is validated, verified or stored
+reads both in `validate_basic` and is an ordinary object from then on.
+"""
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 
+from .. import trace as _trace
+from ..metrics import light_metrics as _light_metrics
 from ..proto import messages as pb
+from ..proto.message import Deferred, DeferredAttr
 from .block import Commit, Header
 from .validator_set import ValidatorSet
+
+
+class _Deferred(Deferred):
+    """A part not yet built: the proto message that carries it (whose
+    own field may still be bytes, proto/message.py `lazy`) and the
+    `from_proto` that builds it."""
+
+    __slots__ = ("source", "part", "build")
+
+    def __init__(self, source, part: str, build):
+        self.source, self.part, self.build = source, part, build
+        _light_metrics().block_parts.add(1, part, "deferred")
+
+    def read(self):
+        """Decode and build the part; malformed bytes raise the
+        ValueError that decoding the whole block used to raise. A part
+        the message does not carry reads as None, which `validate_basic`
+        refuses."""
+        with _trace.span("light.decode_part", "light", part=self.part):
+            p = getattr(self.source, self.part)
+            value = None if p is None else self.build(p)
+        _light_metrics().block_parts.add(1, self.part, "read")
+        return value
 
 
 @dataclass
 class SignedHeader:
     header: Header
-    commit: Commit
+    commit: Commit = DeferredAttr("commit")
 
     def validate_basic(self, chain_id: str) -> None:
         """ref: SignedHeader.ValidateBasic (types/light.go:161)."""
@@ -43,7 +77,7 @@ class SignedHeader:
 
     @classmethod
     def from_proto(cls, p: pb.SignedHeader) -> "SignedHeader":
-        return cls(header=Header.from_proto(p.header), commit=Commit.from_proto(p.commit))
+        return cls(header=Header.from_proto(p.header), commit=_Deferred(p, "commit", Commit.from_proto))
 
 
 @dataclass
@@ -51,7 +85,7 @@ class LightBlock:
     """SignedHeader + the validator set that signed it (ref: types/light.go:14)."""
 
     signed_header: SignedHeader
-    validator_set: ValidatorSet
+    validator_set: ValidatorSet = DeferredAttr("validator_set")
 
     @property
     def height(self) -> int:
@@ -71,6 +105,12 @@ class LightBlock:
                 f"({self.signed_header.header.validators_hash.hex()} != {self.validator_set.hash().hex()})"
             )
 
+    def read_parts(self) -> None:
+        """Decode and build both parts now, for a caller that must know
+        here, and not where it first reads one, that they are messages:
+        raises the ValueError the read of a malformed part raises."""
+        self.signed_header.commit, self.validator_set  # noqa: B018
+
     def to_proto(self) -> pb.LightBlock:
         return pb.LightBlock(signed_header=self.signed_header.to_proto(), validator_set=self.validator_set.to_proto())
 
@@ -78,5 +118,5 @@ class LightBlock:
     def from_proto(cls, p: pb.LightBlock) -> "LightBlock":
         return cls(
             signed_header=SignedHeader.from_proto(p.signed_header),
-            validator_set=ValidatorSet.from_proto(p.validator_set),
+            validator_set=_Deferred(p, "validator_set", ValidatorSet.from_proto),
         )

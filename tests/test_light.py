@@ -365,3 +365,146 @@ def test_lagging_witness_retried_not_fatal():
     lb = client.verify_light_block_at_height(target)
     assert lb.height == target
     assert lagging.calls >= 3, "witness was not retried"
+
+
+# -- a light block decodes what is read (types/light_block.py) -----------------
+#
+# Providers that hold every block as its wire encoding and decode it on
+# each fetch, as a client over RPC does: the benchmark's own
+# (benchmark/drivers/light.py), so what the light cells run is what is held.
+
+
+def _encoded_providers(provider, heights, witness_blocks=None):
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from benchmark.drivers.light import make_provider_class
+
+    cls = make_provider_class()
+    blocks = {h: provider.light_block(h).to_proto().encode() for h in heights}
+    return cls(CHAIN, blocks, "primary"), cls(CHAIN, {**blocks, **(witness_blocks or {})}, "witness0"), blocks
+
+
+def _with_garbage_parts(raw: bytes) -> bytes:
+    """The same header, framing intact, and bytes that are no message
+    where the commit and the validator set were."""
+    from test_light_block_lazy import GARBAGE, decoded, with_parts
+
+    return with_parts(decoded(raw), commit=GARBAGE, validator_set=GARBAGE)
+
+
+def _forked(provider, height: int, app_hash: bytes) -> bytes:
+    lb = provider.light_block(height)
+    lb.signed_header.header.app_hash = app_hash
+    return lb.to_proto().encode()
+
+
+def _part_samples():
+    from tendermint_tpu.metrics import light_metrics
+
+    return {(labels["part"], labels["event"]): value
+            for _, labels, value in light_metrics().block_parts.samples()}
+
+
+def test_an_update_reads_the_primarys_parts_and_leaves_the_witnesss_bytes():
+    from tendermint_tpu import trace
+
+    node, provider = build_chain()
+    target = node.block_store.height()
+    primary, witness, _ = _encoded_providers(provider, [1, target])
+    client = LightClient(CHAIN, _trust_options(provider), primary, witnesses=[witness],
+                         clock=lambda: now_after(provider))
+    before = _part_samples()
+    was = trace.enabled()
+    trace.set_enabled(True)
+    trace.clear()
+    try:
+        lb = client.verify_light_block_at_height(target)
+        events = [ev for ev in trace.export()["traceEvents"] if ev.get("ph") == "X"]
+    finally:
+        trace.set_enabled(was)
+        trace.clear()
+    assert lb.height == target and client.store.light_block(target) is lb
+    grown = {k: v - before.get(k, 0.0) for k, v in _part_samples().items() if v != before.get(k, 0.0)}
+    assert grown == {("commit", "deferred"): 2, ("validator_set", "deferred"): 2,
+                     ("commit", "read"): 1, ("validator_set", "read"): 1}
+    fetches = {ev["args"]["span"]: ev["args"] for ev in events if ev["name"] == "light.fetch"}
+    assert sorted(a["purpose"] for a in fetches.values()) == ["target", "witness"]
+    parts = [ev["args"] for ev in events if ev["name"] == "light.decode_part"]
+    assert sorted(a["part"] for a in parts) == ["commit", "validator_set"]
+    assert all(fetches[a["parent"]]["purpose"] == "target" for a in parts)
+    # the witness's copy was compared and let go with both parts still bytes
+    divergence = next(ev["args"] for ev in events if ev["name"] == "light.detect_divergence")
+    assert divergence["cross_referenced"] == 1
+
+
+def test_a_witness_with_another_header_still_yields_evidence_that_validates():
+    node, provider = build_chain()
+    target = node.block_store.height()
+    primary, witness, _ = _encoded_providers(
+        provider, [1, target], {target: _forked(provider, target, b"\x66" * 32)})
+    client = LightClient(CHAIN, _trust_options(provider), primary, witnesses=[witness],
+                         clock=lambda: now_after(provider))
+    with pytest.raises(ErrLightClientAttack):
+        client.verify_light_block_at_height(target)
+    ev = client.latest_attack_evidence
+    assert ev is not None and ev.conflicting_block.signed_header.header.app_hash == b"\x66" * 32
+    assert len(ev.conflicting_block.signed_header.commit.signatures) == ev.conflicting_block.validator_set.size()
+    # its parts were read from the witness's bytes; a forged header no longer is what the commit signs
+    with pytest.raises(ValueError, match="commit signs block"):
+        ev.validate_basic()
+    ev.conflicting_block.validator_set.validate_basic()
+    ev.conflicting_block.signed_header.commit.validate_basic()
+    assert client.store.light_block(target) is None
+
+
+def test_a_witness_with_a_conflicting_sound_block_yields_evidence_that_validates():
+    """The witness answers with a sound light block of another header
+    (the chain's own, one height down)."""
+    node, provider = build_chain()
+    target = node.block_store.height()
+    lb = provider.light_block(target)
+    other = provider.light_block(target - 1)  # sound in itself, another header
+    other_raw = other.to_proto().encode()
+    primary, witness, _ = _encoded_providers(provider, [1, target], {target: other_raw})
+    client = LightClient(CHAIN, _trust_options(provider), primary, witnesses=[witness],
+                         clock=lambda: now_after(provider))
+    with pytest.raises(ErrLightClientAttack):
+        client.verify_light_block_at_height(target)
+    ev = client.latest_attack_evidence
+    assert ev.conflicting_block.signed_header.hash() == other.signed_header.hash() != lb.signed_header.hash()
+    ev.conflicting_block.validate_basic(CHAIN)  # read from the witness's bytes, whole and sound
+    assert ev.total_voting_power > 0 and provider.evidence is not None
+
+
+def test_a_witness_whose_header_matches_is_cross_referenced_whatever_its_parts_hold():
+    node, provider = build_chain()
+    target = node.block_store.height()
+    raw = provider.light_block(target).to_proto().encode()
+    primary, witness, _ = _encoded_providers(provider, [1, target], {target: _with_garbage_parts(raw)})
+    with pytest.raises(ValueError):
+        witness.light_block(target).validator_set
+    client = LightClient(CHAIN, _trust_options(provider), primary, witnesses=[witness],
+                         clock=lambda: now_after(provider))
+    assert client.verify_light_block_at_height(target).height == target
+    assert client.latest_attack_evidence is None
+
+
+@pytest.mark.parametrize("honest_witness", [False, True])
+def test_a_diverging_witness_whose_parts_are_garbage_gave_no_usable_block(honest_witness):
+    node, provider = build_chain()
+    target = node.block_store.height()
+    forged = _with_garbage_parts(_forked(provider, target, b"\x66" * 32))
+    primary, witness, _ = _encoded_providers(provider, [1, target], {target: forged})
+    witnesses = [witness] + ([provider] if honest_witness else [])
+    client = LightClient(CHAIN, _trust_options(provider), primary, witnesses=witnesses,
+                         clock=lambda: now_after(provider))
+    if honest_witness:
+        assert client.verify_light_block_at_height(target).height == target
+    else:
+        # as when every witness is down: no cross-check, nothing trusted, and no ValueError out of the update
+        with pytest.raises(LightClientError, match="cross-reference"):
+            client.verify_light_block_at_height(target)
+        assert client.store.light_block(target) is None
+    assert client.latest_attack_evidence is None
