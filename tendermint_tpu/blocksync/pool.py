@@ -45,9 +45,10 @@ class _BpPeer:
 class BlockPool:
     """ref: pool.go BlockPool."""
 
-    def __init__(self, start_height: int, send_request, send_error=None):
+    def __init__(self, start_height: int, send_request, send_error=None, metrics=None):
         """send_request(height, peer_id) asks the reactor to fire a
-        BlockRequest; send_error(err, peer_id) reports bad peers."""
+        BlockRequest; send_error(err, peer_id) reports bad peers;
+        metrics is the node's BlockSyncMetrics, if it has one."""
         self.height = start_height  # next height to verify
         self.start_height = start_height
         self.send_request = send_request
@@ -55,6 +56,9 @@ class BlockPool:
         self.peers: dict[str, _BpPeer] = {}
         self.requesters: dict[int, str] = {}  # height → assigned peer
         self.blocks: dict[int, tuple] = {}  # height → (block, peer_id)
+        self.metrics = metrics
+        self.blocks_dropped = 0  # received, unverified, thrown away with their sender
+        self._refused_at: dict[str, float] = {}  # peer removed for a refusal → trace clock, us
         self._ext_commits: dict[int, object] = {}  # height → pb.ExtendedCommit
         self.max_peer_height = 0
         self._lock = threading.RLock()
@@ -110,6 +114,15 @@ class BlockPool:
                 self.peers[peer_id] = _BpPeer(peer_id=peer_id, base=base, height=height)
             if height > self.max_peer_height:
                 self.max_peer_height = height
+            refused_us = self._refused_at.pop(peer_id, None)
+        if refused_us is not None:
+            # how long the refusal kept this peer out, in hindsight:
+            # eviction, disconnect, redial, handshake and status
+            out_us = _trace.now_us() - refused_us
+            _trace.complete("blocksync.peer_out", "blocksync", refused_us, out_us, peer=peer_id)
+            if self.metrics is not None:
+                self.metrics.peer_returns.add(1)
+                self.metrics.peer_out_seconds.add(out_us / 1e6)
 
     def remove_peer(self, peer_id: str) -> None:
         """ref: pool.go:343 RemovePeer — reassign its heights."""
@@ -119,10 +132,17 @@ class BlockPool:
                 del self.requesters[h]
             # drop unverified blocks it delivered — a banned peer's
             # second block must not be used to verify the first
-            for h in [h for h, (_, p) in self.blocks.items() if p == peer_id and h >= self.height]:
+            dropped = [h for h, (_, p) in self.blocks.items() if p == peer_id and h >= self.height]
+            for h in dropped:
                 del self.blocks[h]
                 self._ext_commits.pop(h, None)
+            self._count_dropped(len(dropped))
             self.max_peer_height = max((p.height for p in self.peers.values()), default=0)
+
+    def _count_dropped(self, n: int) -> None:
+        self.blocks_dropped += n
+        if n and self.metrics is not None:
+            self.metrics.blocks_dropped.add(n)
 
     # ----------------------------------------------------------- blocks
 
@@ -146,6 +166,8 @@ class BlockPool:
             if height in self.blocks:
                 return False
             self.blocks[height] = (block, peer_id)
+            if self.metrics is not None:
+                self.metrics.blocks_received.add(1)
             peer = self.peers.get(peer_id)
             if peer is not None:
                 peer.pending = max(0, peer.pending - 1)
@@ -209,7 +231,9 @@ class BlockPool:
             self.requesters.pop(height, None)
             peer_id = entry[1] if entry else None
             if peer_id is not None:
+                self._count_dropped(1)
                 self.remove_peer(peer_id)
+                self._refused_at.setdefault(peer_id, _trace.now_us())
             return peer_id
 
     def is_caught_up(self) -> bool:

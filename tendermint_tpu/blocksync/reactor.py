@@ -3,7 +3,8 @@
 Serves BlockRequests from the local store and runs the verify loop:
 PeekTwoBlocks → VerifyCommitLight(first, using second.LastCommit) —
 routed through the batched TPU verification plane (reactor.go:582) —
-→ ApplyBlock → PopRequest. Channel 0x40, priority 5.
+→ ValidateBlock → PopRequest → SaveBlock → ApplyBlock. Channel 0x40,
+priority 5.
 
 Blocksync is the reference's per-height serial path; batching many
 heights' commits into one TPU launch happens naturally here because
@@ -23,10 +24,11 @@ from ..types.validation import verify_commit_light, verify_commit_light_async
 from ..types.validator_set import NotEnoughVotingPowerError
 from .pool import BlockPool
 
-# What a commit that does not verify raises (types/validation.py). Only
-# these blame the peers that sent the blocks; anything else that escapes
-# verification — a JAX runtime error above all — is this node's own
-# fault and halts it through on_fatal.
+# What a commit that does not verify (types/validation.py) or a block
+# that does not validate (state/validation.py, types/block.py) raises.
+# Only these blame the peers that sent the blocks; anything else that
+# escapes verification — a JAX runtime error above all — is this node's
+# own fault and halts it through on_fatal.
 _VERDICT_ERRORS = (ValueError, NotEnoughVotingPowerError)
 
 
@@ -140,10 +142,11 @@ class BlockSyncReactor:
         """on_caught_up(state, blocks_synced) fires when the pool reaches
         the network head — the node switches to consensus
         (ref: reactor.go:370 SwitchToBlockSync / poolRoutine).
-        on_fatal(exc) fires when a VERIFIED block fails to apply, or
-        when verification itself fails with anything but a verdict (a
-        device compile or runtime error) — faults of this node that it
-        must halt on, as the reference's poolRoutine panic does."""
+        on_fatal(exc) fires when a VALIDATED block fails to persist or
+        apply, or when verification or validation itself fails with
+        anything but a verdict (a device compile or runtime error) —
+        faults of this node that it must halt on, as the reference's
+        poolRoutine panic does."""
         self.state = state
         self.block_exec = block_executor
         self.block_store = block_store
@@ -152,14 +155,15 @@ class BlockSyncReactor:
         self.on_caught_up = on_caught_up or (lambda state, n: None)
         self.on_fatal = on_fatal or (lambda exc: None)
         self.block_sync = block_sync
+        self.metrics = metrics  # BlockSyncMetrics (ref: blocksync/metrics.go)
         self.pool = BlockPool(
             max(self.state.last_block_height + 1, self.state.initial_height),
             self._send_block_request,
             self._send_peer_error,
+            metrics=metrics,
         )
         self.blocks_synced = 0
         self.sync_error = False
-        self.metrics = metrics  # BlockSyncMetrics (ref: blocksync/metrics.go)
         # verify-ahead pipeline state: (height, block obj, commit-source
         # block obj, valset hash, completion callable). Object identity
         # guards against the pool refetching either block; the valset
@@ -283,11 +287,11 @@ class BlockSyncReactor:
             try:
                 advanced = self._try_sync_one()
             except Exception as exc:
-                # A verified block failing to apply is a store/app
-                # invariant violation — the reference panics here
-                # (reactor.go poolRoutine) — and a verification that
-                # raised something other than a verdict is a fault of
-                # this node's device plane. Halt the node via on_fatal
+                # A validated block failing to persist or apply is a
+                # store/app invariant violation — the reference panics
+                # here (reactor.go poolRoutine) — and a verification
+                # that raised something other than a verdict is a fault
+                # of this node's device plane. Halt the node via on_fatal
                 # rather than dying silently, stalling half-alive, or
                 # banning honest peers in a refetch loop.
                 import traceback
@@ -326,17 +330,40 @@ class BlockSyncReactor:
     def _try_sync_one(self) -> bool:
         """One block, everything on the reactor's thread, under one
         span: the root of what runs below it. A poll that found nothing
-        is a span of microseconds with applied false."""
+        is a span of microseconds with applied and refused false."""
         with _trace.span("blocksync.try_sync", "blocksync") as sp:
-            applied = self._sync_one()
-            sp.annotate(applied=applied)
+            t0 = time.perf_counter()
+            applied, refused = self._sync_one()
+            sp.annotate(applied=applied, refused=refused)
+            if refused and self.metrics is not None:
+                self.metrics.refusal_seconds.add(time.perf_counter() - t0)
         return applied
 
-    def _sync_one(self) -> bool:
-        """ref: reactor.go:536-616 (the trySync block)."""
+    def _refuse(self, height: int, stage: str, err: Exception) -> None:
+        """The pair (height, height + 1) holds a lie, and either sender
+        could be the liar (a forged second.LastCommit fails an honest
+        first block): ban BOTH and refetch both heights (ref:
+        reactor.go:592-604 errors both senders). The pool throws away
+        every unverified block the two delivered."""
+        with _trace.span("blocksync.refuse", "blocksync", height=height, stage=stage) as sp:
+            dropped = self.pool.blocks_dropped
+            second_peer = self.pool.block_sender(height + 1)
+            first_peer = self.pool.redo_request(height)
+            banned = [first_peer] if first_peer is not None else []
+            if second_peer is not None and second_peer != first_peer:
+                self.pool.redo_request(height + 1)
+                banned.insert(0, second_peer)
+            sp.annotate(banned=len(banned), dropped=self.pool.blocks_dropped - dropped)
+            if self.metrics is not None:
+                self.metrics.refusals.add(1, stage)
+            for peer in banned:
+                self.channel.send_error(PeerError(node_id=peer, err=err))
+
+    def _sync_one(self) -> tuple[bool, bool]:
+        """(applied, refused). ref: reactor.go:536-616 (the trySync block)."""
         first, second = self.pool.peek_two_blocks()
         if first is None or second is None:
-            return False
+            return False, False
         _trace.annotate(height=first.header.height)
         first_parts = None
         try:
@@ -345,13 +372,16 @@ class BlockSyncReactor:
             # the verify-ahead pipeline when the previous iteration
             # already dispatched this height to the device.
             ahead, self._verify_ahead = self._verify_ahead, None
-            if (
+            fresh = (
                 ahead is not None
                 and ahead[0] == first.header.height
                 and ahead[1] is first
                 and ahead[2] is second
                 and ahead[3] == self.state.validators.hash()
-            ):
+            )
+            if ahead is not None and self.metrics is not None:
+                self.metrics.verify_ahead.add(1, "used" if fresh else "stale")
+            if fresh:
                 first_parts, first_id = ahead[4], ahead[5]  # reuse dispatch-time work
                 ahead[6]()  # completes the dispatched kernel; raises as sync would
             else:
@@ -369,18 +399,8 @@ class BlockSyncReactor:
                     )
             self._dispatch_verify_ahead(second)
         except _VERDICT_ERRORS as e:
-            # Either sender could be lying (a forged second.LastCommit
-            # fails an honest first block): ban BOTH and refetch both
-            # heights (ref: reactor.go:592-604 errors both senders).
-            h = first.header.height
-            second_peer = self.pool.block_sender(h + 1)
-            first_peer = self.pool.redo_request(h)
-            if second_peer is not None and second_peer != first_peer:
-                self.pool.redo_request(h + 1)
-                self.channel.send_error(PeerError(node_id=second_peer, err=e))
-            if first_peer is not None:
-                self.channel.send_error(PeerError(node_id=first_peer, err=e))
-            return False
+            self._refuse(first.header.height, "commit", e)
+            return False, True
 
         height = first.header.height
         ec = self.pool.take_ext_commit(height)
@@ -397,9 +417,28 @@ class BlockSyncReactor:
                 peer = self.pool.redo_request(height)
                 if peer is not None:
                     self.channel.send_error(PeerError(node_id=peer, err=err))
-                return False
+                return False, False
         else:
             ec = None  # extensions disabled at this height: nothing to persist
+
+        # Validate the block before it is persisted (ref: reactor.go
+        # poolRoutine, ValidateBlock after VerifyCommitLight): its
+        # hashes against what it carries, its header against our state,
+        # its own LastCommit in full. The commit above proved the
+        # header's hash and the part set of the bytes served; a block
+        # that passes it and fails here is signed by the set we trust
+        # and is not our chain's (a peer on a fork), and takes the path
+        # a failed commit takes. Nothing may refuse between here and
+        # save_block: the executor's memo is keyed by the header's hash,
+        # which another copy of this height can share, so the object
+        # validated is the object persisted and applied (apply_block's
+        # own call is the memo's hit).
+        try:
+            with _trace.span("blocksync.validate", "blocksync", height=height):
+                self.block_exec.validate_block(self.state, first)
+        except _VERDICT_ERRORS as e:
+            self._refuse(height, "block", e)
+            return False, True
 
         self.pool.pop_request()
         # Block and extended commit ride one DB batch: a crash between
@@ -415,7 +454,7 @@ class BlockSyncReactor:
         self.blocks_synced += 1
         if self.metrics is not None:
             self.metrics.num_blocks.add(1)
-        return True
+        return True, False
 
     def _validate_ext_commit(self, ec, height: int, first_id, vals=None,
                              chain_id: str = "") -> Exception | None:

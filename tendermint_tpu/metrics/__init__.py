@@ -526,6 +526,35 @@ class BlockSyncMetrics:
         self.num_blocks = reg.counter(f"{ns}_num_blocks", "Blocks synced and applied")
         self.latest_height = reg.gauge(f"{ns}_latest_block_height", "Pool verify height")
         self.sync_rate = reg.gauge(f"{ns}_sync_rate", "Recent blocks/sec estimate")
+        # What a lying peer costs (no reference analog). A refusal is
+        # one pair of heights whose senders were both blamed: at stage
+        # "commit" the light check of second.LastCommit failed, at
+        # stage "block" the block itself failed validation against the
+        # state, before it was persisted.
+        self.refusals = reg.counter(
+            f"{ns}_refusals_total", "Pairs of blocks refused, by stage", labels=("stage",)
+        )
+        self.refusal_seconds = reg.counter(
+            f"{ns}_refusal_seconds_total", "Time inside sync iterations that refused a pair"
+        )
+        self.blocks_received = reg.counter(
+            f"{ns}_blocks_received_total", "Requested blocks the pool took from peers"
+        )
+        self.blocks_dropped = reg.counter(
+            f"{ns}_blocks_dropped_total",
+            "Received, unverified blocks the pool threw away with the peer that sent them",
+        )
+        self.peer_returns = reg.counter(
+            f"{ns}_peer_returns_total", "Peers removed for a refusal that reported a status again"
+        )
+        self.peer_out_seconds = reg.counter(
+            f"{ns}_peer_out_seconds_total", "Time from such a removal to that status"
+        )
+        self.verify_ahead = reg.counter(
+            f"{ns}_verify_ahead_total",
+            "Commit verifications dispatched one height ahead, by outcome (used, stale)",
+            labels=("outcome",),
+        )
 
 
 class StateSyncMetrics:

@@ -812,3 +812,273 @@ def test_missing_extended_commit_refetches_at_ve_height():
     reactor.pool.add_block(peer2, b2)
     assert reactor._try_sync_one() is True
     assert fresh.block_store.height() == 1
+
+
+# ---------------------------------------------------- validate before persist
+
+
+def _fixture_reactor(chain, metrics=None):
+    """A joiner of a fixture chain with stub p2p: (reactor, peer errors,
+    fatals, the joiner's block store)."""
+    from tendermint_tpu.blocksync import fixture
+
+    state, executor, _, block_store = fixture._executor(chain.gen_doc)
+    errors, fatal = [], []
+
+    class _Chan:
+        def send_to(self, *a, **k):
+            return True
+
+        def send_error(self, e):
+            errors.append(e)
+
+    class _PM:
+        def subscribe(self, cb):
+            pass
+
+        def unsubscribe(self, cb):
+            pass
+
+    reactor = BlockSyncReactor(state, executor, block_store, _Chan(), _PM(),
+                               on_fatal=fatal.append, metrics=metrics)
+    return reactor, errors, fatal, block_store
+
+
+def _samples(counter) -> dict:
+    return {tuple(labels.values()): v for _, labels, v in counter.samples()}
+
+
+def _serve(reactor, blocks_by_peer: dict) -> None:
+    """Each peer reports the range of the blocks it holds, is asked for
+    them, and delivers."""
+    for peer, blocks in blocks_by_peer.items():
+        heights = [b.header.height for b in blocks]
+        reactor.pool.set_peer_range(peer, min(heights), max(heights))
+    for peer, blocks in blocks_by_peer.items():
+        for b in blocks:
+            reactor.pool.requesters[b.header.height] = peer
+            assert reactor.pool.add_block(peer, b)
+
+
+def _forked_pair(chain, height: int, lie: str):
+    """Blocks `height` and `height + 1` as a peer on a fork would serve
+    them: block `height` carries a lie in what ValidateBlock checks, and
+    EVERY validator has signed it all the same, so that the commit in
+    block `height + 1` proves it. `lie`: a LastCommit signature beyond
+    the light prefix under the honest header ("tail"), the same with the
+    header's LastCommitHash made to match ("tail_rehashed"), or a
+    LastCommitHash that matches nothing ("last_commit_hash")."""
+    from tendermint_tpu.blocksync import fixture
+    from tendermint_tpu.types.block import BlockID
+
+    first = chain.block_store.load_block(height)
+    second = chain.block_store.load_block(height + 1)
+    if lie == "last_commit_hash":
+        first.header.last_commit_hash = b"\x77" * 32
+    else:
+        cs = first.last_commit.signatures[-1]
+        cs.signature = cs.signature[:32] + bytes([cs.signature[32] ^ 1]) + cs.signature[33:]
+        if lie == "tail_rehashed":
+            first.header.last_commit_hash = first.last_commit.hash()
+    first = type(first).decode(first.encode())  # as it comes off the wire: no memo
+    forged_id = BlockID(hash=first.hash(), part_set_header=first.make_part_set().header)
+    keys_by_addr = {k.pub_key().address(): k for k in chain.keys}
+    second.last_commit = fixture.sign_commit(
+        chain.chain_id, chain.state.validators, keys_by_addr, height, forged_id,
+        second.last_commit.signatures[0].timestamp)
+    return first, second
+
+
+@pytest.mark.parametrize("lie,verdict", [
+    ("tail", "wrong Header.LastCommitHash"),
+    ("tail_rehashed", "wrong signature (#3)"),
+    ("last_commit_hash", "wrong Header.LastCommitHash"),
+])
+def test_block_that_fails_validation_is_refused_before_it_is_persisted(lie, verdict):
+    """A pair the commit check passes (a fork the same validators
+    signed) whose first block does not validate against our state: a
+    fault of the peers, not of this node. It used to be saved, fail
+    inside apply_block and halt the node with its block store one height
+    above its state. Now: stage "block", both senders blamed, both
+    heights fetched again, nothing persisted, the loop goes on and the
+    honest copies sync."""
+    from tendermint_tpu.blocksync import fixture
+    from tendermint_tpu.metrics import BlockSyncMetrics, Registry
+
+    chain = fixture.build_chain(7, 4, 5)
+    metrics = BlockSyncMetrics(Registry())
+    reactor, errors, fatal, block_store = _fixture_reactor(chain, metrics)
+    first, second = _forked_pair(chain, 2, lie)
+    honest, forked, neighbour = "aa" * 20, "bb" * 20, "cc" * 20
+    _serve(reactor, {honest: [chain.block_store.load_block(1)], forked: [first],
+                     neighbour: [second]})
+    assert reactor._try_sync_one() is True  # height 1
+    assert reactor._try_sync_one() is False  # the pair (2, 3)
+    assert fatal == [] and reactor.sync_error is False
+    assert {e.node_id for e in errors} == {forked, neighbour}
+    assert all(isinstance(e.err, ValueError) and verdict in str(e.err) for e in errors), errors
+    assert block_store.height() == 1 and reactor.state.last_block_height == 1
+    assert reactor.pool.height == 2
+    assert forked not in reactor.pool.peers and neighbour not in reactor.pool.peers
+    assert 2 not in reactor.pool.requesters and 3 not in reactor.pool.requesters
+    assert _samples(metrics.refusals) == {("block",): 1.0}
+    assert _samples(metrics.refusal_seconds)[()] > 0
+    assert reactor.pool.blocks_dropped == 2 and _samples(metrics.blocks_dropped)[()] == 2.0
+    # the honest copies of the same heights go through
+    errors.clear()
+    _serve(reactor, {honest: [chain.block_store.load_block(h) for h in (2, 3, 4)]})
+    assert reactor._try_sync_one() is True and reactor._try_sync_one() is True
+    assert errors == [] and block_store.height() == 3 == reactor.state.last_block_height
+    assert block_store.load_block(2).hash() == chain.block_hashes[1]
+
+
+def test_last_commit_lie_beyond_the_light_prefix_is_a_peer_fault():
+    """One signature of block 3's LastCommit corrupted in the row the
+    light rule never reads (3 of 4 equal validators are enough). The
+    pair (2, 3) passes, and the pair (3, 4) is refused at the commit:
+    the part set the joiner makes of the bytes served is not the one
+    block 4's LastCommit signed. Both senders blamed, nothing above the
+    state saved, the heights asked for again, the node not halted."""
+    from tendermint_tpu.blocksync import fixture
+    from tendermint_tpu.metrics import BlockSyncMetrics, Registry
+
+    chain = fixture.build_chain(8, 4, 5)
+    served = fixture.corrupted_copy(chain, 2, 3)
+    metrics = BlockSyncMetrics(Registry())
+    reactor, errors, fatal, block_store = _fixture_reactor(chain, metrics)
+    honest, liar = "aa" * 20, "bb" * 20
+    _serve(reactor, {honest: [chain.block_store.load_block(h) for h in (1, 2, 4, 5)],
+                     liar: [served.load_block(3)]})
+    assert reactor._try_sync_one() is True and reactor._try_sync_one() is True
+    assert reactor._try_sync_one() is False
+    assert fatal == [] and {e.node_id for e in errors} == {honest, liar}
+    assert all("wrong block ID" in str(e.err) for e in errors)
+    assert block_store.height() == 2 == reactor.state.last_block_height
+    assert reactor.pool.peers == {} and reactor.pool.blocks == {}
+    assert not {3, 4, 5} & set(reactor.pool.requesters)
+    assert _samples(metrics.refusals) == {("commit",): 1.0}
+    # blocks 3, 4 and 5 were received and are gone with their senders
+    assert _samples(metrics.blocks_received)[()] == 5.0
+    assert _samples(metrics.blocks_dropped)[()] == 3.0
+
+
+def test_device_failure_inside_validate_block_is_fatal_not_a_lying_peer(monkeypatch):
+    """The validation before save_block blames peers for a verdict
+    alone: a device or runtime error inside it halts the node, bans
+    nobody, persists nothing."""
+    from tendermint_tpu.blocksync import fixture
+
+    chain = fixture.build_chain(5, 4, 3)
+    reactor, errors, fatal, block_store = _fixture_reactor(chain)
+    peer = "cc" * 20
+    _serve(reactor, {peer: [chain.block_store.load_block(h) for h in (1, 2, 3)]})
+
+    class XlaRuntimeError(RuntimeError):
+        pass
+
+    def broken_device(state, block):
+        raise XlaRuntimeError("INTERNAL: core halted unexpectedly")
+
+    monkeypatch.setattr(reactor.block_exec, "validate_block", broken_device)
+    reactor._pool_routine()  # returns by itself: the fatal path ends the loop
+    assert len(fatal) == 1 and isinstance(fatal[0], XlaRuntimeError)
+    assert reactor.sync_error is True
+    assert errors == [] and peer in reactor.pool.peers
+    assert reactor.pool.height == 1 and block_store.height() == 0
+
+
+def test_pool_counts_dropped_blocks_and_a_refused_peers_return():
+    from tendermint_tpu.metrics import BlockSyncMetrics, Registry
+
+    class FakeBlock:
+        def __init__(self, h):
+            self.header = type("H", (), {"height": h})()
+
+    metrics = BlockSyncMetrics(Registry())
+    pool = BlockPool(1, lambda h, p: None, metrics=metrics)
+    refused, other = "aa" * 20, "bb" * 20
+    pool.set_peer_range(refused, 1, 6)
+    pool._fill_requests()
+    for h in range(1, 5):
+        assert pool.add_block(refused, FakeBlock(h))
+    pool.set_peer_range(other, 1, 6)
+    assert _samples(metrics.blocks_received)[()] == 4.0
+    assert pool.redo_request(1) == refused
+    assert pool.blocks == {} and pool.blocks_dropped == 4
+    assert _samples(metrics.blocks_dropped)[()] == 4.0
+    # a peer that merely disconnects takes its blocks with it, and is no return
+    pool._fill_requests()
+    assert pool.add_block(other, FakeBlock(1))
+    pool.remove_peer(other)
+    assert pool.blocks_dropped == 5
+    pool.set_peer_range(other, 1, 6)
+    assert _samples(metrics.peer_returns) == {}
+    # the refused peer's next status brings it back, and is counted once
+    pool.set_peer_range(refused, 1, 6)
+    pool.set_peer_range(refused, 1, 6)
+    assert refused in pool.peers
+    assert _samples(metrics.peer_returns)[()] == 1.0
+    assert _samples(metrics.peer_out_seconds)[()] >= 0.0
+
+
+def test_verify_ahead_is_counted_used_or_stale():
+    """A launch dispatched one height ahead is used by the next
+    iteration, or found stale because a refusal took its blocks away."""
+    from tendermint_tpu.blocksync import fixture
+    from tendermint_tpu.metrics import BlockSyncMetrics, Registry
+
+    chain = fixture.build_chain(9, 4, 6)
+    metrics = BlockSyncMetrics(Registry())
+    reactor, errors, fatal, _ = _fixture_reactor(chain, metrics)
+    honest, forked = "aa" * 20, "bb" * 20
+    first, second = _forked_pair(chain, 2, "tail")
+    _serve(reactor, {honest: [chain.block_store.load_block(h) for h in (1, 4, 5, 6)],
+                     forked: [first, second]})
+    assert reactor._try_sync_one() is True  # 1 applied, 2 dispatched ahead
+    assert reactor._try_sync_one() is False  # the ahead is used; 3's goes out; 2 fails validation
+    assert _samples(metrics.verify_ahead) == {("used",): 1.0}
+    _serve(reactor, {honest: [chain.block_store.load_block(h) for h in (2, 3)]})
+    assert reactor._try_sync_one() is True  # the launch for the forked 3 is stale
+    assert _samples(metrics.verify_ahead) == {("used",): 1.0, ("stale",): 1.0}
+    assert fatal == [] and {e.node_id for e in errors} == {forked}
+
+
+def test_a_refusal_is_a_span_under_the_iteration_that_made_it():
+    """`blocksync.refuse` (height, stage, banned, dropped) under a
+    `blocksync.try_sync` that says `refused`; `blocksync.validate` before
+    `blocksync.save_block`; `blocksync.peer_out`, in hindsight, when a
+    refused peer's status brings it back."""
+    from tendermint_tpu import trace as T
+    from tendermint_tpu.blocksync import fixture
+
+    chain = fixture.build_chain(7, 4, 5)
+    reactor, errors, fatal, _ = _fixture_reactor(chain)
+    first, second = _forked_pair(chain, 2, "tail")
+    honest, forked = "aa" * 20, "bb" * 20
+    _serve(reactor, {honest: [chain.block_store.load_block(1)], forked: [first, second]})
+    was = T.enabled()
+    T.set_enabled(True)
+    T.clear()
+    try:
+        assert reactor._try_sync_one() is True and reactor._try_sync_one() is False
+        reactor.pool.set_peer_range(forked, 1, 5)
+        events = [ev for ev in T.export()["traceEvents"] if ev.get("ph") == "X"]
+    finally:
+        T.set_enabled(was)
+        T.clear()
+    by_name = {}
+    for ev in events:
+        by_name.setdefault(ev["name"], []).append(ev)
+    applied, refused = by_name["blocksync.try_sync"]
+    assert applied["args"]["applied"] is True and applied["args"]["refused"] is False
+    assert refused["args"]["applied"] is False and refused["args"]["refused"] is True
+    (refuse,) = by_name["blocksync.refuse"]
+    assert refuse["args"]["parent"] == refused["args"]["span"]
+    assert {k: refuse["args"][k] for k in ("height", "stage", "banned", "dropped")} == {
+        "height": 2, "stage": "block", "banned": 1, "dropped": 2}
+    validated = [ev["args"]["height"] for ev in by_name["blocksync.validate"]]
+    assert validated == [1, 2]
+    assert [ev["args"]["height"] for ev in by_name["blocksync.save_block"]] == [1]
+    (out,) = by_name["blocksync.peer_out"]
+    assert out["args"]["peer"] == forked and out["ts"] >= refuse["ts"] and out["dur"] >= 0
+    assert fatal == [] and {e.node_id for e in errors} == {forked}
