@@ -78,6 +78,14 @@ class BatchVerifier(ABC):
     def verify(self) -> tuple[bool, list[bool]]:
         """Returns (all_valid, per-job validity bitmap)."""
 
+    def engine_job(self):
+        """This verifier's batch as the engine takes it, (plane,
+        pubkeys, msgs, sigs, journey), for a caller that hands several
+        verifiers over in one call (crypto/batch.py
+        verify_async_together). None where the batch is empty or the
+        verifier does not submit to the engine: verify_async then."""
+        return None
+
     def verify_async(self):
         """Dispatch verification without blocking; returns a no-arg
         callable producing (all_valid, bitmap). Device-backed verifiers

@@ -29,3 +29,18 @@ def supports_batch_verifier(pk: PubKey | None) -> bool:
     if pk is None:
         return False
     return pk.type_name in (ED25519_TYPE, SR25519_TYPE)
+
+
+def verify_async_together(verifiers) -> list:
+    """verify_async for several BatchVerifiers at once: their batches
+    are handed to the engine in one call (ops/engine.py
+    submit_together), so those of one key type are one launch and not
+    one each; one completion callable a verifier, in order, each with
+    verify_async's contract. A verifier with nothing for the engine
+    (an empty batch) sends each on its own way."""
+    jobs = [bv.engine_job() for bv in verifiers]
+    if any(job is None for job in jobs):
+        return [bv.verify_async() for bv in verifiers]
+    from ..ops import engine as _engine
+
+    return _engine.verify_together_via_engine(jobs)

@@ -225,6 +225,11 @@ class Ed25519BatchVerifier(BatchVerifier):
     def verify(self) -> tuple[bool, list[bool]]:
         return self.verify_async()()
 
+    def engine_job(self):
+        if not self._sigs:
+            return None
+        return KEY_TYPE, self._pks, self._msgs, self._sigs, self.journey
+
     def verify_async(self):
         """Submit to the process-wide coalescing pipeline
         (ops/engine.py), the one place a batch's route is chosen: jobs
@@ -234,10 +239,9 @@ class Ed25519BatchVerifier(BatchVerifier):
         completion callable, so callers overlap the verification with
         host work (blocksync applies block h while h+1's commit
         verifies)."""
-        if not self._sigs:
+        job = self.engine_job()
+        if job is None:
             return lambda: (False, [])
         from ..ops import engine as _engine
 
-        return _engine.verify_async_via_engine(
-            KEY_TYPE, self._pks, self._msgs, self._sigs, journey=self.journey,
-        )
+        return _engine.verify_async_via_engine(*job)

@@ -359,14 +359,18 @@ class Sr25519BatchVerifier(BatchVerifier):
     def verify(self) -> tuple[bool, list[bool]]:
         return self.verify_async()()
 
+    def engine_job(self):
+        if not self._jobs:
+            return None
+        pks, msgs, sigs = zip(*self._jobs)
+        return KEY_TYPE, pks, msgs, sigs, self.journey
+
     def verify_async(self):
         """Submit to the engine (ops/engine.py), which chooses the
         route; same contract as Ed25519BatchVerifier.verify_async."""
-        if not self._jobs:
+        job = self.engine_job()
+        if job is None:
             return lambda: (False, [])
         from ..ops import engine as _engine
 
-        pks, msgs, sigs = zip(*self._jobs)
-        return _engine.verify_async_via_engine(
-            KEY_TYPE, pks, msgs, sigs, journey=self.journey,
-        )
+        return _engine.verify_async_via_engine(*job)

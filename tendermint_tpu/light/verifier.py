@@ -8,7 +8,10 @@ Two verification regimes:
     (verifier.go:33 VerifyNonAdjacent)
 
 Both commit checks run through the batched TPU verification plane
-(types/validation.py verify_commit_light / verify_commit_light_trusting).
+(types/validation.py): an adjacent step's one check as
+verify_commit_light, a non-adjacent step's two walked first and
+submitted together (verify_commit_light_trusting held, then
+verify_commit_light_after_trusting), one launch a step.
 """
 
 from __future__ import annotations
@@ -18,6 +21,7 @@ from ..types.light_block import SignedHeader
 from ..types.validation import (
     Fraction,
     verify_commit_light,
+    verify_commit_light_after_trusting,
     verify_commit_light_trusting,
 )
 from ..types.validator_set import NotEnoughVotingPowerError, ValidatorSet
@@ -109,17 +113,23 @@ def verify_non_adjacent(
 
     # enough trusted validators signed the NEW commit? (:70) — only a
     # POWER shortfall means "bisect"; invalid signatures etc. are final
-    # (the reference keys on ErrNotEnoughVotingPowerSigned, :74)
+    # (the reference keys on ErrNotEnoughVotingPowerSigned, :74). The
+    # tally is the host's: a refused jump submits nothing.
     try:
-        verify_commit_light_trusting(chain_id, trusted_vals, untrusted_header.commit, trust_level)
+        trusting = verify_commit_light_trusting(
+            chain_id, trusted_vals, untrusted_header.commit, trust_level, hold=True
+        )
     except NotEnoughVotingPowerError as e:
         raise ErrNewValSetCantBeTrusted(str(e))
     except Exception as e:
         raise ErrInvalidHeader(str(e))
 
-    # the new validator set signed its own header with 2/3 (:85)
+    # the new validator set signed its own header with 2/3 (:85): its
+    # batch and the trusting check's go to the engine together, one
+    # launch, and the trusting check's verdict is read first
     try:
-        verify_commit_light(
+        verify_commit_light_after_trusting(
+            trusting,
             chain_id,
             untrusted_vals,
             untrusted_header.commit.block_id,

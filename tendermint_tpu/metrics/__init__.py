@@ -758,6 +758,11 @@ class EngineMetrics:
         self.submitted_sigs = reg.counter(
             f"{ns}_submitted_sigs_total", "Signatures submitted to the engine", labels=("plane",)
         )
+        self.jobs_submitted_together = reg.counter(
+            f"{ns}_jobs_submitted_together_total",
+            "Jobs that entered the queue beside another in one call (submit_together)",
+            labels=("plane",),
+        )
         self.coalesced_group_size = reg.histogram(
             f"{ns}_coalesced_group_size",
             "Caller jobs merged per coalesced launch",

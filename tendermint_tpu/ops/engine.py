@@ -42,6 +42,7 @@ and never loads it.
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time as _time
@@ -384,42 +385,72 @@ class VerifyEngine:
         plane is "ed25519" or "sr25519"; returns a JobHandle whose
         result() yields this caller's bools in input order. `journey`
         optionally tags the job with a tmpath journey key so the
-        coalesced launch's spans stay height-attributable."""
-        if plane not in _HOST_VERIFY:
-            raise ValueError(f"unknown verification plane {plane!r}")
-        job = _Job(plane, list(pubkeys), list(msgs), list(sigs), journey=journey)
-        if len(job.pks) != job.n or len(job.msgs) != job.n:
-            # ragged inputs would silently truncate in the verify
-            # planes' zip()s, reporting unverified tail rows as accepted
-            # and shifting later coalesced callers' demux slices
-            raise ValueError(
-                f"ragged batch: {len(job.pks)} pubkeys / {len(job.msgs)} msgs "
-                f"/ {job.n} sigs"
-            )
-        if job.n == 0:
-            job.result = []
-            job.event.set()
-            return JobHandle(job)
+        coalesced launch's spans stay height-attributable. The one-job
+        case of submit_together."""
+        return self.submit_together([(plane, pubkeys, msgs, sigs, journey)])[0]
+
+    def submit_together(self, batches) -> list[JobHandle]:
+        """Queue several batches, each (plane, pubkeys, msgs, sigs,
+        journey), under one acquisition of the lock, with one wake-up
+        of the dispatch thread: _take_group then sees all of them, so
+        batches of one plane that fit MAX_COALESCE_ROWS together are one
+        launch even when the dispatch thread was idle, where two submit
+        calls a few hundred microseconds apart are two. Nothing waits
+        for a job that may never come: a caller with both its batches
+        in hand says so here, and every other caller's latency is what
+        it was. One JobHandle a batch, in order, each yielding its own
+        rows' bools. The group's route is chosen on its total rows, as
+        for any coalesced group, so two batches under
+        DEVICE_BATCH_CUTOVER may together pass it and launch. Every
+        batch is checked before any is queued: a ragged or unknown one
+        raises and nothing was submitted."""
+        jobs = []
+        for plane, pubkeys, msgs, sigs, journey in batches:
+            if plane not in _HOST_VERIFY:
+                raise ValueError(f"unknown verification plane {plane!r}")
+            job = _Job(plane, list(pubkeys), list(msgs), list(sigs), journey=journey)
+            if len(job.pks) != job.n or len(job.msgs) != job.n:
+                # ragged inputs would silently truncate in the verify
+                # planes' zip()s, reporting unverified tail rows as accepted
+                # and shifting later coalesced callers' demux slices
+                raise ValueError(
+                    f"ragged batch: {len(job.pks)} pubkeys / {len(job.msgs)} msgs "
+                    f"/ {job.n} sigs"
+                )
+            if job.n == 0:
+                job.result = []
+                job.event.set()
+            jobs.append(job)
+        queued = [job for job in jobs if job.n]
+        if not queued:
+            return [JobHandle(job) for job in jobs]
         self._ensure_started()
-        job.t_submit = _time.monotonic()
-        if _trace.enabled():
-            job.flow = _trace.new_flow()
-            sub_args = {"plane": plane, "rows": job.n, "flow": job.flow}
-            if journey:
-                sub_args["journey"] = journey
-            with _trace.span("engine.submit", "engine", **sub_args) as sp:
-                job.span, job.req = sp.id, sp.req
         m = _engine_metrics()
-        m.submitted_jobs.add(1, plane)
-        m.submitted_sigs.add(job.n, plane)
+        now = _time.monotonic()
+        together = len(queued) > 1
+        for job in queued:
+            job.t_submit = now
+            if _trace.enabled():
+                job.flow = _trace.new_flow()
+                sub_args = {"plane": job.plane, "rows": job.n, "flow": job.flow}
+                if job.journey:
+                    sub_args["journey"] = job.journey
+                if together:
+                    sub_args["together"] = len(queued)
+                with _trace.span("engine.submit", "engine", **sub_args) as sp:
+                    job.span, job.req = sp.id, sp.req
+            m.submitted_jobs.add(1, job.plane)
+            m.submitted_sigs.add(job.n, job.plane)
+            if together:
+                m.jobs_submitted_together.add(1, job.plane)
         with self._lock:
-            self._pending.append(job)
+            self._pending += queued
             # gauge set under the lock: an unlocked set here can lose
             # the race against the dispatch worker's set and leave a
             # phantom backlog on the scrape
             m.queue_depth.set(len(self._pending))
             self._have_jobs.notify()
-        return JobHandle(job)
+        return [JobHandle(job) for job in jobs]
 
     # ------------------------------------------------------------ dispatch
 
@@ -668,10 +699,16 @@ def verify_async_via_engine(plane: str, pubkeys, msgs, sigs, journey=None):
     planes: submit to the engine, return a completion callable yielding
     the (all_ok, per-signature bools) contract. `journey` tags the job
     for tmpath height attribution (see VerifyEngine.submit)."""
-    handle = get_engine().submit(plane, pubkeys, msgs, sigs, journey=journey)
+    return verify_together_via_engine([(plane, pubkeys, msgs, sigs, journey)])[0]
 
-    def complete():
-        bools = handle.result()
-        return all(bools), bools
 
-    return complete
+def verify_together_via_engine(batches) -> list:
+    """verify_async_via_engine for several batches handed over in one
+    call (VerifyEngine.submit_together): one completion callable a
+    batch, in order."""
+    return [functools.partial(_complete, h) for h in get_engine().submit_together(batches)]
+
+
+def _complete(handle: JobHandle):
+    bools = handle.result()
+    return all(bools), bools

@@ -70,13 +70,8 @@ def verify_commit_async(
     voting_power_needed = vals.total_voting_power() * 2 // 3
     ignore = lambda c: c.block_id_flag == 1  # absent
     count = lambda c: c.block_id_flag == 2  # commit
-    if _should_batch_verify(vals, commit):
-        return _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, ignore, count, True, True,
-            defer=True,
-        )
-    _verify_commit_single(chain_id, vals, commit, voting_power_needed, ignore, count, True, True)
-    return lambda: None
+    return _submit_walked(
+        _walk_commit(chain_id, vals, commit, voting_power_needed, ignore, count, True, True))
 
 
 def verify_commit_light(chain_id: str, vals: ValidatorSet, block_id: BlockID, height: int, commit: Commit) -> None:
@@ -97,23 +92,35 @@ def verify_commit_light_async(
     error surface when invoked. Lets blocksync verify height h+1 on the
     chip while height h applies host-side (the verify-ahead pipeline —
     a capability the reference's serial verify loop lacks)."""
+    return _submit_walked(_walk_commit_light(chain_id, vals, block_id, height, commit))
+
+
+def _walk_commit_light(
+    chain_id: str, vals: ValidatorSet, block_id: BlockID, height: int, commit: Commit
+) -> _WalkedBatch | None:
+    """verify_commit_light's host half: every check that needs no
+    signature verified raises NOW (see _walk_commit for the result)."""
     _verify_basic_vals_and_commit(vals, commit, height, block_id)
     voting_power_needed = vals.total_voting_power() * 2 // 3
     ignore = lambda c: c.block_id_flag != 2
     count = lambda c: True
-    if _should_batch_verify(vals, commit):
-        return _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, ignore, count, False, True,
-            defer=True,
-        )
-    _verify_commit_single(chain_id, vals, commit, voting_power_needed, ignore, count, False, True)
-    return lambda: None
+    return _walk_commit(chain_id, vals, commit, voting_power_needed, ignore, count, False, True)
 
 
-def verify_commit_light_trusting(chain_id: str, vals: ValidatorSet, commit: Commit, trust_level: Fraction) -> None:
+def verify_commit_light_trusting(
+    chain_id: str, vals: ValidatorSet, commit: Commit, trust_level: Fraction, hold: bool = False
+) -> _WalkedBatch | None:
     """Verify trustLevel of an arbitrary validator set signed, looking
     validators up by address (ref: VerifyCommitLightTrusting,
-    types/validation.go:96)."""
+    types/validation.go:96).
+
+    With hold=True only the host's half runs: arguments, address
+    lookup, double votes and the power tally raise NOW, a power
+    shortfall (NotEnoughVotingPowerError: the one failure a light
+    client answers by bisecting) among them, and the batch comes back
+    walked and not submitted, for verify_commit_light_after_trusting
+    with the same commit; None where the commit went the serial way and
+    is already verified."""
     if vals is None:
         raise ValueError("nil validator set")
     if trust_level.denominator == 0:
@@ -126,13 +133,97 @@ def verify_commit_light_trusting(chain_id: str, vals: ValidatorSet, commit: Comm
     voting_power_needed = product // trust_level.denominator
     ignore = lambda c: c.block_id_flag != 2
     count = lambda c: True
-    if _should_batch_verify(vals, commit):
-        _verify_commit_batch(chain_id, vals, commit, voting_power_needed, ignore, count, False, False)
-    else:
-        _verify_commit_single(chain_id, vals, commit, voting_power_needed, ignore, count, False, False)
+    walked = _walk_commit(chain_id, vals, commit, voting_power_needed, ignore, count, False, False)
+    if hold:
+        return walked
+    _submit_walked(walked)()
 
 
-def _verify_commit_batch(
+def verify_commit_light_after_trusting(
+    trusting: _WalkedBatch | None,
+    chain_id: str,
+    vals: ValidatorSet,
+    block_id: BlockID,
+    height: int,
+    commit: Commit,
+) -> None:
+    """The two checks of a light client's non-adjacent step as one
+    engine submission (ref: light/verifier.go:70-95). `trusting` is what
+    verify_commit_light_trusting(hold=True) returned for this commit
+    against the trusted set; this walks verify_commit_light against the
+    commit's own set, hands both batches to the engine in one call (one
+    launch where two blocking calls make two) and returns when both
+    verdicts are in. It raises what verify_commit_light_trusting's wait
+    and then verify_commit_light would raise, in that order: where the
+    light walk fails on the host the trusting batch is still verified,
+    alone, and its refusal comes first; where both batches hold a bad
+    signature the one reported is the trusting check's. No verdict is
+    shared: rows that both batches hold are verified in both."""
+    try:
+        light = _walk_commit_light(chain_id, vals, block_id, height, commit)
+    except Exception:
+        _submit_walked(trusting)()
+        raise
+    batches = [b for b in (trusting, light) if b is not None]
+    _dispatch_walked(batches)
+    for batch in batches:
+        batch.collect()
+
+
+class _WalkedBatch:
+    """A commit's signatures walked into a BatchVerifier, its power
+    tallied and found enough: all of a check that the host decides.
+    Held until _dispatch_walked submits it, alone or beside another;
+    collect() then waits for its verdicts."""
+
+    __slots__ = ("commit", "bv", "sig_idxs", "pending")
+
+    def __init__(self, commit: Commit, bv, sig_idxs: list[int]):
+        self.commit = commit
+        self.bv = bv
+        self.sig_idxs = sig_idxs  # row i of the batch is commit.signatures[sig_idxs[i]]
+        self.pending = None
+
+    def collect(self) -> None:
+        """ref: types/validation.go:245-255, without the serial pass."""
+        with _trace.span("verify.commit_collect", "verify",
+                         height=self.commit.height, nsigs=len(self.sig_idxs)):
+            ok, valid_sigs = self.pending()
+        if ok:
+            return
+        for i, sig_ok in enumerate(valid_sigs):
+            if not sig_ok:
+                idx = self.sig_idxs[i]
+                sig = self.commit.signatures[idx].signature
+                raise ValueError(f"wrong signature (#{idx}): {sig.hex().upper()}")
+        raise RuntimeError("BUG: batch verification failed with no invalid signatures")
+
+
+def _dispatch_walked(batches: list[_WalkedBatch]) -> None:
+    """Submit walked batches of one commit in one call, under one
+    verify.commit_dispatch span (`jobs` where there are several)."""
+    if not batches:
+        return
+    args = {"height": batches[0].commit.height, "nsigs": sum(len(b.sig_idxs) for b in batches)}
+    if len(batches) > 1:
+        args["jobs"] = len(batches)
+    with _trace.span("verify.commit_dispatch", "verify", **args):
+        pendings = crypto_batch.verify_async_together([b.bv for b in batches])
+    for batch, pending in zip(batches, pendings):
+        batch.pending = pending
+
+
+def _submit_walked(walked: _WalkedBatch | None):
+    """One walked batch on its way, and the no-arg callable that waits
+    for it and raises (or not) with the blocking check's errors; a
+    commit verified serially (None) has nothing left to wait for."""
+    if walked is None:
+        return lambda: None
+    _dispatch_walked([walked])
+    return walked.collect
+
+
+def _walk_commit(
     chain_id: str,
     vals: ValidatorSet,
     commit: Commit,
@@ -141,13 +232,33 @@ def _verify_commit_batch(
     count_sig: Callable[[CommitSig], bool],
     count_all_signatures: bool,
     look_up_by_index: bool,
-    defer: bool = False,
-):
-    """ref: verifyCommitBatch (types/validation.go:154).
+) -> _WalkedBatch | None:
+    """The host's half of a check, by the way the commit goes (ref:
+    types/validation.go:12-16): a batch walked, tallied and still to be
+    submitted, or None where the commit was verified serially, here and
+    now, and nothing is left to wait for."""
+    args = (chain_id, vals, commit, voting_power_needed, ignore_sig, count_sig,
+            count_all_signatures, look_up_by_index)
+    if _should_batch_verify(vals, commit):
+        return _walk_commit_batch(*args)
+    _verify_commit_single(*args)
+    return None
 
-    With defer=True the kernel is dispatched asynchronously and a no-arg
-    completion callable is returned (raising with the same errors the
-    synchronous path would); host-side failures still raise immediately."""
+
+def _walk_commit_batch(
+    chain_id: str,
+    vals: ValidatorSet,
+    commit: Commit,
+    voting_power_needed: int,
+    ignore_sig: Callable[[CommitSig], bool],
+    count_sig: Callable[[CommitSig], bool],
+    count_all_signatures: bool,
+    look_up_by_index: bool,
+) -> _WalkedBatch | None:
+    """ref: verifyCommitBatch (types/validation.go:154), up to the line
+    where the batch is verified: host-side failures raise here and the
+    batch comes back unsubmitted. None where a key could not join the
+    batch and the commit was verified serially instead."""
     with _trace.span("verify.commit_walk", "verify", height=commit.height) as walk:
         proposer = vals.get_proposer()
         bv = crypto_batch.create_batch_verifier(proposer.pub_key)
@@ -183,13 +294,11 @@ def _verify_commit_batch(
                 # instead — acceptance still requires every signature to
                 # verify, so no invalid commit is admitted.
                 walk.annotate(fallback="single")
-                single = _verify_commit_single(
+                _verify_commit_single(
                     chain_id, vals, commit, voting_power_needed,
                     ignore_sig, count_sig, count_all_signatures, look_up_by_index,
                 )
-                if defer:
-                    return lambda: single
-                return single
+                return None
             batch_sig_idxs.append(idx)
             if count_sig(commit_sig):
                 tallied += val.voting_power
@@ -200,27 +309,7 @@ def _verify_commit_batch(
         walk.annotate(nsigs=len(batch_sig_idxs), walked=idx + 1)
         if tallied <= voting_power_needed:
             raise NotEnoughVotingPowerError(got=tallied, needed=voting_power_needed)
-
-    with _trace.span("verify.commit_dispatch", "verify",
-                     height=commit.height, nsigs=len(batch_sig_idxs)):
-        pending = bv.verify_async()
-
-    def complete() -> None:
-        with _trace.span("verify.commit_collect", "verify",
-                         height=commit.height, nsigs=len(batch_sig_idxs)):
-            ok, valid_sigs = pending()
-        if ok:
-            return
-        for i, sig_ok in enumerate(valid_sigs):
-            if not sig_ok:
-                idx = batch_sig_idxs[i]
-                sig = commit.signatures[idx].signature
-                raise ValueError(f"wrong signature (#{idx}): {sig.hex().upper()}")
-        raise RuntimeError("BUG: batch verification failed with no invalid signatures")
-
-    if defer:
-        return complete
-    complete()
+    return _WalkedBatch(commit, bv, batch_sig_idxs)
 
 
 def _verify_commit_single(

@@ -276,6 +276,129 @@ def test_engine_ragged_batch_rejected():
         E.get_engine().submit("ed25519", pks, msgs[:2], sigs)
 
 
+# ------------------------------------------------- jobs entered together
+
+
+def _group_spy(monkeypatch):
+    """The job counts and rows of every group _dispatch_group is given."""
+    groups = []
+    real = E.VerifyEngine._dispatch_group
+
+    def spy(self, group, seq=0):
+        groups.append([j.n for j in group])
+        return real(self, group, seq)
+
+    monkeypatch.setattr(E.VerifyEngine, "_dispatch_group", spy)
+    return groups
+
+
+@pytest.mark.parametrize("bad", [None, "second", "first", "both"])
+def test_jobs_entered_together_are_one_group_and_each_reads_its_own_verdicts(monkeypatch, bad):
+    """An engine of its own, its dispatch thread parked on an empty
+    queue: two batches in one submit_together are one _dispatch_group
+    call, every time (they enter under one hold of the lock), and the
+    combined bitmap is cut back to each in order, a bad row in one
+    leaving the other's verdicts all true."""
+    from tendermint_tpu.metrics import engine_metrics
+
+    first = make_jobs(5, tamper_idx={1} if bad in ("first", "both") else ())
+    second = make_jobs(9, tamper_idx={0, 7} if bad in ("second", "both") else ())
+    groups = _group_spy(monkeypatch)
+    m = engine_metrics()
+    together = _counter_value(m.jobs_submitted_together)
+    submitted = _counter_value(m.submitted_jobs)
+    eng = E.VerifyEngine()
+    assert eng._pending == [] and not eng._started
+    handles = eng.submit_together([("ed25519", *first, None), ("ed25519", *second, None)])
+    got = [h.result(timeout=120) for h in handles]
+    assert groups == [[5, 9]]
+    assert got[0] == [i != 1 or bad not in ("first", "both") for i in range(5)]
+    assert got[1] == [i not in (0, 7) or bad not in ("second", "both") for i in range(9)]
+    assert _counter_value(m.jobs_submitted_together) == together + 2
+    assert _counter_value(m.submitted_jobs) == submitted + 2
+    # one job alone goes the way it went, and is not counted as entering beside another
+    assert eng.submit("ed25519", *first).result(timeout=120) == got[0]
+    assert groups == [[5, 9], [5]]
+    assert _counter_value(m.jobs_submitted_together) == together + 2
+
+
+def test_a_pair_past_the_row_cap_completes_as_two_groups(monkeypatch):
+    monkeypatch.setattr(E, "MAX_COALESCE_ROWS", 8)
+    groups = _group_spy(monkeypatch)
+    first, second = make_jobs(5), make_jobs(6, tamper_idx={2})
+    handles = E.VerifyEngine().submit_together(
+        [("ed25519", *first, None), ("ed25519", *second, None)])
+    assert [h.result(timeout=120) for h in handles] == [[True] * 5,
+                                                        [i != 2 for i in range(6)]]
+    assert groups == [[5], [6]]
+
+
+def test_submit_together_queues_nothing_when_one_batch_is_refused():
+    pks, msgs, sigs = make_jobs(3)
+    eng = E.VerifyEngine()
+    with pytest.raises(ValueError, match="ragged batch"):
+        eng.submit_together([("ed25519", pks, msgs, sigs, None),
+                             ("ed25519", pks[:2], msgs, sigs, None)])
+    with pytest.raises(ValueError, match="unknown verification plane"):
+        eng.submit_together([("ed25519", pks, msgs, sigs, None),
+                             ("secp256k1", pks, msgs, sigs, None)])
+    assert eng._pending == [] and not eng._started
+    # an empty batch beside a full one: answered at once, the other queued alone
+    empty, full = eng.submit_together([("ed25519", [], [], [], None),
+                                       ("ed25519", pks, msgs, sigs, None)])
+    assert empty.result(timeout=5) == [] and full.result(timeout=120) == [True] * 3
+
+
+def test_the_trace_says_how_many_jobs_a_launch_carried():
+    from tendermint_tpu import trace as T
+
+    was = T.enabled()
+    T.set_enabled(True)
+    T.clear()
+    try:
+        handles = E.VerifyEngine().submit_together(
+            [("ed25519", *make_jobs(4), "h7"), ("ed25519", *make_jobs(6), "h7")])
+        assert all(all(h.result(timeout=120)) for h in handles)
+        # the collect span closes after the callers are woken
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline:
+            spans = [e for e in T.export()["traceEvents"] if e.get("ph") == "X"]
+            if any(e["name"] == "engine.collect" for e in spans):
+                break
+            time.sleep(0.01)
+    finally:
+        T.set_enabled(was)
+        T.clear()
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e["name"], []).append(e["args"])
+    assert [(a["rows"], a["together"]) for a in by_name["engine.submit"]] == [(4, 2), (6, 2)]
+    for name in ("engine.dispatch", "engine.collect"):
+        (args,) = by_name[name]
+        assert (args["jobs"], args["rows"], args["journeys"]) == (2, 10, ["h7"])
+        assert args["reqs"] == [a["req"] for a in by_name["engine.submit"]]
+
+
+def test_the_batch_verifiers_hand_their_batches_over_in_one_call(monkeypatch):
+    """crypto/batch.py verify_async_together: two verifiers, one group,
+    verify_async's contract for each; an empty one sends each its own way."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+
+    def verifier(rows, tamper_idx=()):
+        bv = Ed25519BatchVerifier()
+        for pk, msg, sig in zip(*make_jobs(rows, tamper_idx)):
+            bv.add(Ed25519PubKey(pk), msg, sig)
+        return bv
+
+    groups = _group_spy(monkeypatch)
+    done = crypto_batch.verify_async_together([verifier(3), verifier(4, tamper_idx={3})])
+    assert [c() for c in done] == [(True, [True] * 3), (False, [True, True, True, False])]
+    assert groups == [[3, 4]]
+    done = crypto_batch.verify_async_together([verifier(0), verifier(2)])
+    assert [c() for c in done] == [(False, []), (True, [True, True])]
+    assert groups == [[3, 4], [2]]
+
+
 # ------------------------------------------------- exception propagation
 
 

@@ -186,6 +186,88 @@ def test_every_fetch_says_what_it_is_for_and_both_counters_count(traffic, host_r
     assert _grown(steps, _samples(m.verify_steps, "outcome")) == {"ok": 2, "bisect": 1}
 
 
+@pytest.mark.parametrize("route", ["host", "bitmap_cached"])
+def test_a_trust_step_is_one_launch_and_a_refused_jump_none(traffic, monkeypatch, route):
+    """Height 21 from a fresh client: the trust root (one check, one
+    job), a jump the trusting tally refuses (nothing reaches the
+    engine), then two steps, each both of its batches in one group."""
+    device, msm, pk_cache, path, _ = ROUTES[route]
+    monkeypatch.setattr(ed, "DEVICE_BATCH_CUTOVER", device)
+    monkeypatch.setattr(ed, "MSM_BATCH_CUTOVER", msm)
+    monkeypatch.setenv("TM_TPU_PK_CACHE", pk_cache)
+    together = _samples(V._engine_metrics().jobs_submitted_together, "plane")
+    was = trace.enabled()
+    trace.set_enabled(True)
+    trace.clear()
+    try:
+        traffic.new_client().verify_light_block_at_height(21)
+        events = [ev for ev in trace.export()["traceEvents"] if ev.get("ph") == "X"]
+    finally:
+        trace.set_enabled(was)
+        trace.clear()
+
+    def inside(step, name):
+        return [ev["args"] for ev in events if ev["name"] == name
+                and step["ts"] <= ev["ts"] <= step["ts"] + step["dur"]]
+
+    steps = [ev for ev in events if ev["name"] == "light.verify_step"]
+    assert [st["args"]["outcome"] for st in steps] == ["bisect", "ok", "ok"]
+    refused, *succeeded = steps
+    assert len(inside(refused, "verify.commit_walk")) == 1
+    for name in ("verify.commit_dispatch", "engine.submit", "engine.dispatch"):
+        assert inside(refused, name) == []
+    for step in succeeded:
+        assert len(inside(step, "verify.commit_walk")) == 2
+        (dispatch,) = inside(step, "verify.commit_dispatch")
+        assert dispatch["jobs"] == 2
+        assert [a["together"] for a in inside(step, "engine.submit")] == [2, 2]
+        (launch,) = inside(step, "engine.dispatch")
+        assert (launch["jobs"], launch["path"]) == (2, path)
+        assert launch["rows"] == dispatch["nsigs"] == sum(
+            a["nsigs"] for a in inside(step, "verify.commit_collect"))
+    # the root's one check is the only other group, and it came alone
+    assert sorted(ev["args"]["jobs"] for ev in events if ev["name"] == "engine.dispatch") == [1, 2, 2]
+    assert _grown(together, _samples(V._engine_metrics().jobs_submitted_together, "plane")) \
+        == {"ed25519": 4}
+
+
+@pytest.mark.parametrize("signing,error,jobs", [
+    (24, None, [2]),
+    (12, "ErrInvalidHeader", [1]),  # over a third of the trusted set, not over two thirds of its own
+    (8, "ErrNewValSetCantBeTrusted", []),
+])
+def test_only_the_trusting_tallys_shortfall_asks_for_a_bisection(
+        traffic, host_route, monkeypatch, signing, error, jobs):
+    """The light check's own shortfall refuses the header, with the
+    trusting batch verified alone first; the trusting check's asks for a
+    pivot and submits nothing (ref: light/verifier.go:70-95)."""
+    import copy
+
+    from tendermint_tpu.light import verifier as vf
+    from tendermint_tpu.ops.engine import VerifyEngine
+    from tendermint_tpu.types.block import CommitSig
+
+    primary = traffic.new_client().primary
+    trusted, new = primary.light_block(1), copy.deepcopy(primary.light_block(5))
+    sigs = new.signed_header.commit.signatures
+    sigs[signing:] = [CommitSig.new_absent() for _ in sigs[signing:]]
+    calls, real = [], VerifyEngine.submit_together
+    monkeypatch.setattr(VerifyEngine, "submit_together",
+                        lambda self, batches: calls.append(len(batches)) or real(self, batches))
+
+    def step():
+        vf.verify_non_adjacent(
+            "chain-churn", trusted.signed_header, trusted.validator_set, new.signed_header,
+            new.validator_set, 1209600 * 10**9, new.signed_header.header.time, 10 * 10**9)
+
+    if error is None:
+        step()
+    else:
+        with pytest.raises(getattr(vf, error), match="insufficient voting power"):
+            step()
+    assert calls == jobs
+
+
 def test_a_sequential_client_fetches_every_height_as_sequential(traffic, host_route):
     from tendermint_tpu.light.client import SEQUENTIAL
 
@@ -243,6 +325,31 @@ def test_a_fill_of_any_miss_count_loads_no_program_after_the_buckets_first(fille
     assert oks[slots].all() and oks.sum() == len(cache._lru)
     again, _, _ = cache.ensure_snapshot(batch)
     assert list(again) == list(slots) and devobs.status()["compiles"] == compiles
+
+
+def test_a_batch_that_holds_its_missing_keys_twice_fills_each_once(filled_cache):
+    """Two checks of one commit in one launch: a key the trusted set
+    holds beyond the light batch's prefix can be new to the cache in
+    both halves. One build, one slot a key, every row its key's slot,
+    and each row that waited for a table counted as missed."""
+    cache = filled_cache
+    fresh = _keys(70000, 70007)
+    batch = _keys(0, 30) + fresh[:5] + _keys(0, 60) + fresh  # 102 rows: 5 new keys twice, 2 once
+    m = V._engine_metrics()
+    launched = _samples(m.kernel_launches, "kernel").get("pk_table_build", 0.0)
+    rows = _samples(m.pk_cache_rows, "plane").get("churn_pk", 0.0)
+    missed = _samples(m.pk_cache_missed_rows, "plane").get("churn_pk", 0.0)
+    held = len(cache._lru)
+    slots, _, oks = cache.ensure_snapshot(batch)
+    assert _samples(m.kernel_launches, "kernel")["pk_table_build"] == launched + 1
+    assert [int(s) for s in slots] == [cache._lru[pk] for pk in batch]
+    assert len(cache._lru) == held + 7 and len({cache._lru[pk] for pk in fresh}) == 7
+    assert np.asarray(oks)[slots].all() and not cache._pending and not cache._pinned
+    assert _samples(m.pk_cache_rows, "plane")["churn_pk"] == rows + 102
+    assert _samples(m.pk_cache_missed_rows, "plane")["churn_pk"] == missed + 12
+    again, _, _ = cache.ensure_snapshot(batch)
+    assert list(again) == list(slots)
+    assert _samples(m.kernel_launches, "kernel")["pk_table_build"] == launched + 1
 
 
 def test_a_fills_programs_are_named_for_the_batchs_bucket(filled_cache):

@@ -449,7 +449,7 @@ def test_a_refused_step_shows_what_refused_it(tiny_chain, monkeypatch):
     def device_fault(*a, **kw):
         raise RuntimeError("device plane down")
 
-    monkeypatch.setattr(vf, "verify_commit_light", device_fault)
+    monkeypatch.setattr(vf, "verify_commit_light_after_trusting", device_fault)
     T.set_enabled(True)
     T.clear()
     with pytest.raises(vf.ErrInvalidHeader):
