@@ -34,31 +34,22 @@ Design constraints:
     engine.submit span as parent, and list every job's req as `reqs`
     when a group was coalesced). A layer's self time is its span's
     duration less what the spans naming it as parent cover.
+  - running or waiting: on the enabled path a span() also carries
+    `cpu_us`, its thread's CPU time inside it (time.thread_time_ns:
+    Python and native code alike, GIL held or released), and
+    `offcpu_us`, its duration less that, floored at 0: the thread was
+    blocked (a lock, the GIL among them, or a wait by design) or
+    runnable with no core. Where that clock is a dear call (a
+    sandboxed kernel: measured when tracing is switched on) only the
+    spans of _CPU_NAMED carry the two. A span entered while its
+    thread's stack is empty also carries `runq_us`, the run-queue share
+    of that, from the thread's /proc schedstat (left out where there is
+    none). complete() and instant() carry none of the three. While tracing is
+    on, a gc.callbacks hook records each collection as a `runtime.gc`
+    span on the thread that ran it.
 
-Span catalog (docs/observability.md): consensus.step (instant) /
-consensus.finalize_commit, state.apply_block / state.validate_block /
-state.finalize_block (state.commit_info under it: the last commit's
-CommitInfo, `source` state or store) / state.abci_commit,
-light.update / light.fetch / light.verify_step / light.header_checks /
-light.detect_divergence / light.store (one light-client update, from
-the caller to the store), verify.commit_walk (address lookup,
-sign-bytes, tally) / verify.commit_dispatch / verify.commit_collect,
-blocksync.try_sync (one block on the reactor's
-thread) / blocksync.parts / blocksync.verify_commit /
-blocksync.verify_ahead / blocksync.save_block / blocksync.apply /
-blocksync.starved / blocksync.settle (both retrospective),
-engine.submit / engine.coalesce / engine.dispatch /
-engine.host_verify / engine.collect, ops.verify_dispatch /
-ops.msm_dispatch with ops.prep / ops.rlc_scalars / ops.launch under
-them, ops.pk_cache_lookup (the cached kernel's slot lookup) with
-ops.pk_cache_fill (a miss's table build) under it, device.h2d (the
-staging calls) /
-device.wait (the collect thread blocked on the kernel) / device.d2h
-(the read-back alone) / device.compile, sharded.verify,
-mempool.admit_batch (coalesced tx admission: n/admitted/failed),
-journey.proposal_build / journey.proposal / journey.block_assembled /
-journey.quorum / journey.send / journey.recv (tmpath block-journey
-plane, docs/observability.md#tmpath).
+The span catalog, with every span's place and args, is
+docs/observability.md's and is kept there alone.
 
 Journey correlation: cross-node causality cannot use new_flow() ids
 (process-private counters) or clock alignment (perf_counter epochs are
@@ -71,6 +62,7 @@ cross-node flow arrows from the keys alone.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -96,6 +88,7 @@ __all__ = [
 
 _STATE = {
     "on": os.environ.get("TM_TPU_TRACE", "").strip().lower() in ("1", "on", "true", "yes"),
+    "cpu_every_span": True,  # set_enabled(True) measures it
 }
 try:
     _CAPACITY = int(os.environ.get("TM_TPU_TRACE_BUF", "65536"))
@@ -108,9 +101,11 @@ except ValueError:
 
 # Ring of finished events. Each entry is a dict already shaped like a
 # Chrome-trace event minus pid (stamped at export). deque.append is
-# atomic, but the lock also guards clear()/export() snapshots.
+# atomic, but the lock also guards clear()/export() snapshots. It is
+# re-entrant because a collection can start between two bytecodes of a
+# thread that holds it, and the collection's own span lands here too.
 _EVENTS: deque = deque(maxlen=_CAPACITY)
-_LOCK = threading.Lock()
+_LOCK = threading.RLock()
 _FLOW_IDS = itertools.count(1)
 _SPAN_IDS = itertools.count(1)  # args.span; 0 means "no span"
 _LOCAL = threading.local()
@@ -120,9 +115,51 @@ def enabled() -> bool:
     return _STATE["on"]
 
 
+# The spans that carry cpu_us where the thread's CPU clock is a dear
+# call: the requests' roots, their one wait by design and the kernel
+# dispatchers, which is what benchmark/window_spans.py reads.
+_CPU_NAMED = frozenset({"light.update", "blocksync.try_sync", "verify.commit_collect",
+                        "ops.verify_dispatch", "ops.msm_dispatch"})
+_CPU_CLOCK_CHEAP_NS = 2000  # a plain Linux answers in 0.3-0.6 us, a gVisor sandbox in 6
+
+
+def _cpu_clock_is_cheap() -> bool:
+    """The best of four batches of eight reads: a thread switched out
+    inside one batch does not make the clock dear."""
+    best = None
+    for _ in range(4):
+        t0 = time.perf_counter_ns()
+        for _ in range(8):
+            time.thread_time_ns()
+        took = time.perf_counter_ns() - t0
+        best = took if best is None else min(best, took)
+    return best / 8 < _CPU_CLOCK_CHEAP_NS
+
+
 def set_enabled(on: bool) -> None:
-    """Flip tracing at runtime (tests, bench stages, RPC debug)."""
-    _STATE["on"] = bool(on)
+    """Flip tracing at runtime (tests, bench stages, RPC debug). The
+    collector's hook is in gc.callbacks only while tracing is on; which
+    spans read the CPU clock is decided here, from what a read costs."""
+    _STATE["on"] = on = bool(on)
+    if on:
+        _STATE["cpu_every_span"] = _cpu_clock_is_cheap()
+    if on and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    elif not on and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """gc.callbacks hook: one `runtime.gc` span a collection, on the
+    thread that ran it, under whatever span is open there."""
+    if phase == "start":
+        _LOCAL.gc_t0 = _now_us()
+        return
+    t0 = getattr(_LOCAL, "gc_t0", None)
+    if t0 is not None:
+        _LOCAL.gc_t0 = None
+        complete("runtime.gc", "runtime", t0, _now_us() - t0, generation=info["generation"],
+                 collected=info["collected"], uncollectable=info["uncollectable"])
 
 
 def new_flow() -> int:
@@ -161,6 +198,30 @@ def _stack() -> list:
     return st
 
 
+# The thread's own schedstat: "<on-cpu ns> <run-queue ns> <timeslices>".
+_SCHEDSTAT = "/proc/self/task/%d/schedstat"
+
+
+def _runq_ns() -> int | None:
+    """Nanoseconds this thread has been runnable with no core, or None
+    where the kernel keeps no such file. A descriptor opened on a
+    thread's file stays that thread's, so each thread keeps its own;
+    the file object closes it when the thread's locals go."""
+    f = getattr(_LOCAL, "schedstat", None)
+    if f is None:
+        try:
+            f = open(_SCHEDSTAT % threading.get_native_id(), "rb", buffering=0)
+        except OSError:
+            f = False
+        _LOCAL.schedstat = f
+    if f is False:
+        return None
+    try:
+        return int(os.pread(f.fileno(), 64, 0).split()[1])
+    except (OSError, IndexError, ValueError):
+        return None
+
+
 def _stamp(args: dict) -> int:
     """Give an event's args its own id, its parent's and its
     request's (enabled path only). `parent` and `req` already in args
@@ -196,7 +257,7 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "id", "req", "_t0", "_tid", "_tname")
+    __slots__ = ("name", "cat", "args", "id", "req", "_t0", "_c0", "_rq0", "_tid", "_tname")
 
     def __init__(self, name: str, cat: str, args: dict):
         self.name = name
@@ -209,24 +270,38 @@ class _Span:
         self._tname = t.name
         self.id = _stamp(self.args)
         self.req = self.args["req"]
-        _stack().append(self)
+        st = _stack()
+        self._rq0 = None if st else _runq_ns()  # the thread's outermost span only
+        st.append(self)
+        clocked = _STATE["cpu_every_span"] or self.name in _CPU_NAMED
         self._t0 = _now_us()
+        self._c0 = time.thread_time_ns() if clocked else None
         return self
 
     def __exit__(self, *exc):
+        c1 = time.thread_time_ns() if self._c0 is not None else None
         t1 = _now_us()
         st = _stack()
         if st and st[-1] is self:
             st.pop()
+        dur = t1 - self._t0
+        args = self.args
+        if c1 is not None:
+            cpu = args["cpu_us"] = (c1 - self._c0) / 1000.0
+            args["offcpu_us"] = max(0.0, dur - cpu)
+        if self._rq0 is not None:
+            rq1 = _runq_ns()
+            if rq1 is not None:
+                args["runq_us"] = (rq1 - self._rq0) / 1000.0
         ev = {
             "name": self.name,
             "cat": self.cat or "tm",
             "ph": "X",
             "ts": self._t0,
-            "dur": t1 - self._t0,
+            "dur": dur,
             "tid": self._tid,
             "tname": self._tname,
-            "args": self.args,
+            "args": args,
         }
         with _LOCK:
             _EVENTS.append(ev)
@@ -363,3 +438,7 @@ def save(path: str) -> int:
     with open(path, "w") as f:
         json.dump(doc, f)
     return len(doc["traceEvents"])
+
+
+if _STATE["on"]:  # TM_TPU_TRACE=1: enabled at import, the collector's hook with it
+    set_enabled(True)

@@ -9,7 +9,12 @@ engine hot path, so "off" must stay free).
 
 from __future__ import annotations
 
+import gc
+import hashlib
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -29,11 +34,13 @@ def _reset_tracer():
 
 
 IDS = ("span", "parent", "req")
+CLOCKS = ("cpu_us", "offcpu_us", "runq_us")
 
 
 def _own(args: dict) -> dict:
-    """An event's args without the ids every enabled event carries."""
-    return {k: v for k, v in args.items() if k not in IDS}
+    """An event's args without the ids every enabled event carries and
+    the clocks every span does."""
+    return {k: v for k, v in args.items() if k not in IDS + CLOCKS}
 
 
 def test_disabled_records_nothing():
@@ -132,10 +139,10 @@ def test_ring_buffer_bounds_memory():
     cap = T._EVENTS.maxlen
     for i in range(cap + 100):
         T.instant(f"e{i}")
-    evs = [e for e in T.export()["traceEvents"] if e["ph"] == "i"]
-    assert len(evs) == cap
+    evs = [e for e in T.export()["traceEvents"] if e["ph"] != "M"]
+    assert len(evs) == cap  # a collection's span takes a place in the ring like any other
     # oldest events were dropped, newest survive
-    assert evs[-1]["name"] == f"e{cap + 99}"
+    assert [e for e in evs if e["ph"] == "i"][-1]["name"] == f"e{cap + 99}"
 
 
 def test_save_writes_loadable_json(tmp_path):
@@ -164,7 +171,7 @@ def test_concurrent_spans_all_recorded():
         t.start()
     for t in threads:
         t.join()
-    evs = [e for e in T.export()["traceEvents"] if e.get("ph") == "X"]
+    evs = [e for e in T.export()["traceEvents"] if e.get("ph") == "X" and e["cat"] == "mt"]
     assert len(evs) == n_threads * per
 
 
@@ -199,6 +206,143 @@ def test_flow_zero_sentinel_gets_no_arrows():
         pass
     evs = T.export()["traceEvents"]
     assert not [e for e in evs if e["ph"] in ("s", "f")]
+
+
+# ------------------------------------------------- running or waiting
+
+
+def _spin(seconds: float) -> None:
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def _hash_8mb() -> None:
+    hashlib.sha256(bytes(8 << 20)).digest()  # hashlib releases the GIL above 2 KiB
+
+
+@pytest.mark.parametrize("work,cpu_share", [
+    (lambda: _spin(0.2), (0.8, 1.01)),  # computing: CPU time is the wall's, a shared box's cut off
+    (lambda: time.sleep(0.2), (0.0, 0.1)),  # a wait: nearly none
+    (_hash_8mb, (0.8, 1.01)),  # native code with the GIL released is CPU time too
+], ids=["spins", "sleeps", "native_without_the_gil"])
+def test_a_span_splits_its_duration_into_cpu_and_off_cpu(work, cpu_share):
+    T.set_enabled(True)
+    for _ in range(5):  # another tenant's burst on a shared core is not the clock's doing
+        T.clear()
+        with T.span("work", "t"):
+            work()
+        (ev,) = _events(("X",))
+        dur, args = ev["dur"], ev["args"]
+        assert args["offcpu_us"] == pytest.approx(max(0.0, dur - args["cpu_us"]), abs=1e-6)
+        if cpu_share[0] <= args["cpu_us"] / dur <= cpu_share[1]:
+            return
+    pytest.fail(f"cpu_us {args['cpu_us']:.0f} of dur {dur:.0f}: not within {cpu_share}")
+
+
+def test_where_the_cpu_clock_is_dear_only_the_named_spans_read_it(monkeypatch):
+    """A sandboxed kernel answers `thread_time_ns` in 6 us: the
+    price is measured when tracing is switched on, and then only the
+    spans the readers need carry the two args."""
+    T.set_enabled(True)
+    assert T._STATE["cpu_every_span"]  # a plain Linux: every span
+    monkeypatch.setattr(T, "_CPU_CLOCK_CHEAP_NS", 0)
+    T.set_enabled(True)
+    assert not T._STATE["cpu_every_span"]
+    with T.span("blocksync.try_sync", "t"):
+        with T.span("blocksync.apply", "t"):
+            with T.span("verify.commit_collect", "t"):
+                pass
+    by = {e["name"]: e["args"] for e in _events(("X",))}
+    assert {"cpu_us", "offcpu_us"} <= set(by["blocksync.try_sync"])
+    assert {"cpu_us", "offcpu_us"} <= set(by["verify.commit_collect"])
+    assert not {"cpu_us", "offcpu_us"} & set(by["blocksync.apply"])
+    assert set(T._CPU_NAMED) == {"light.update", "blocksync.try_sync", "verify.commit_collect",
+                                 "ops.verify_dispatch", "ops.msm_dispatch"}
+    monkeypatch.undo()
+    T.set_enabled(True)
+    assert T._STATE["cpu_every_span"]
+
+
+def test_complete_and_instant_carry_no_clock_of_the_thread():
+    T.set_enabled(True)
+    T.instant("tick", "t")
+    T.complete("hindsight", "t", T.now_us() - 5.0, 5.0)
+    assert all(not set(CLOCKS) & set(e["args"]) for e in _events())
+
+
+def _nested_on(results: dict, key: str) -> None:
+    with T.span(key + ".outer", "t"):
+        with T.span(key + ".inner", "t"):
+            _spin(0.01)
+    results[key] = threading.get_native_id()
+
+
+def test_runq_us_is_on_a_threads_outermost_span_and_is_that_threads_own():
+    T.set_enabled(True)
+    if T._runq_ns() is None:
+        pytest.skip("no /proc schedstat on this kernel")
+    native = {}
+    _nested_on(native, "main")
+    t = threading.Thread(target=_nested_on, args=(native, "second"))
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive() and native["second"] != native["main"]
+    by = {e["name"]: e["args"] for e in _events(("X",))}
+    for key in ("main", "second"):
+        assert by[key + ".outer"]["runq_us"] >= 0.0 and "runq_us" not in by[key + ".inner"]
+    # each thread read its own file: the second thread's is not the first's descriptor
+    assert T._LOCAL.schedstat.name == T._SCHEDSTAT % native["main"]
+    # a root handed another thread's parent is still its own thread's outermost
+    with T.span("handed", "t", parent=7, req=3):
+        pass
+    assert "runq_us" in _events(("X",))[-1]["args"]
+
+
+def test_runq_us_is_left_out_where_the_kernel_keeps_no_schedstat(monkeypatch, tmp_path):
+    monkeypatch.setattr(T, "_SCHEDSTAT", str(tmp_path / "none-%d"))
+    seen = {}
+
+    def worker():  # a fresh thread: no descriptor opened before the patch
+        with T.span("outer", "t") as sp:
+            seen.update(sp.args)
+
+    T.set_enabled(True)
+    t = threading.Thread(target=worker)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    (ev,) = _events(("X",))
+    assert "runq_us" not in ev["args"] and ev["args"]["cpu_us"] >= 0.0
+
+
+def test_a_collection_is_a_span_under_the_open_span():
+    before = list(gc.callbacks)
+    T.set_enabled(True)
+    T.set_enabled(True)  # installed once
+    assert len(gc.callbacks) == len(before) + 1
+    with T.span("holder", "t") as holder:
+        gc.collect()
+    T.set_enabled(False)
+    assert gc.callbacks == before
+    gc.collect()  # tracing off: no span, no callback
+    (ev,) = [e for e in _events(("X",)) if e["name"] == "runtime.gc"]
+    assert ev["cat"] == "runtime" and ev["dur"] > 0
+    assert ev["args"]["parent"] == holder.id and ev["args"]["req"] == holder.req
+    assert ev["args"]["generation"] == 2
+    assert {"collected", "uncollectable"} <= set(ev["args"])
+    assert ev["tid"] == threading.get_ident()
+
+
+def test_a_process_that_never_enabled_tracing_carries_no_gc_callback():
+    code = ("import gc; from tendermint_tpu import trace; "
+            "assert not trace.enabled() and gc.callbacks == [], gc.callbacks")
+    env = {k: v for k, v in os.environ.items() if k != "TM_TPU_TRACE"}
+    subprocess.run([sys.executable, "-c", code], check=True, env=env, timeout=60)
+    env["TM_TPU_TRACE"] = "1"
+    code = ("import gc; from tendermint_tpu import trace; "
+            "assert trace.enabled() and trace._on_gc in gc.callbacks")
+    subprocess.run([sys.executable, "-c", code], check=True, env=env, timeout=60)
 
 
 # ------------------------------------------- the span that caused it
