@@ -993,6 +993,12 @@ class LightMetrics:
             "its proto with the part left as it arrived, read when such a part is decoded and built",
             labels=("part", "event"),
         )
+        self.part_rows = reg.counter(
+            f"{ns}_part_rows_total",
+            "Validators and commit signatures built when a light block's part was read, by how: direct "
+            "(from the part's bytes in one pass), message (from a proto message that was already decoded)",
+            labels=("part", "path"),
+        )
 
 
 class FlightMetrics:

@@ -17,6 +17,15 @@ Nothing interprets `fields` per message.
 A `lazy` sub-message is decoded when it is first read: `decode` checks
 its framing and keeps its slice of the buffer (`_Unread`), so a caller
 that reads a header out of a light block pays for no validator set.
+
+The decode source has a second target (`Message.decoder_to`): the same
+field loop, from the same generator, ending in a call of a builder the
+caller names instead of in a message. A caller that wants its own
+objects out of a buffer (types/validator_set.py, types/block.py) is
+handed the decoded values once, "decoded once": no message is made to be
+copied from and thrown away. What such a decoder accepts, skips and
+refuses on any buffer is what `decode` does, because the loop is the
+same text; only what is made at the end differs.
 """
 
 from __future__ import annotations
@@ -119,6 +128,13 @@ class _Unread(Deferred):
         return self.cls.decode(self.buf[self.start : self.end])
 
 
+def held(msg: Message, name: str):
+    """What the lazy field `name` of `msg` holds as it stands, reading
+    nothing: the `_Unread` that `decode` left (`buf`, `start`, `end`), or
+    the message or None that it was read as or set to."""
+    return msg.__dict__["_" + name]
+
+
 class Message:
     """Base class; subclasses set `fields = [Field(...), ...]`."""
 
@@ -164,6 +180,19 @@ class Message:
     def decode_delimited(cls, buf: bytes, offset: int = 0):
         body, pos = wire.unmarshal_delimited(buf, offset)
         return cls.decode(body), pos
+
+    @classmethod
+    def decoder_to(cls, build, **subs):
+        """A `decode(buf, pos, end)` that reads the `cls` lying in
+        `buf[pos:end]` (`buf` is `bytes`) as `cls.decode` reads it and
+        returns `build(v0, v1, ...)`, one value a field in the order of
+        `fields`, where `decode` would make a `cls` and set them on it.
+        `subs` gives, by field name, the decoder that reads a message
+        field's sub-message in place, as a rule another class's
+        `decoder_to`; a sub-message that has one and that the buffer does
+        not carry is None. A message field not named is decoded into its
+        message."""
+        return (cls._codec or _codec_of(cls)).decoder_to(build, subs)
 
     # -- niceties ---------------------------------------------------------
 
@@ -225,7 +254,8 @@ class _Codec:
     sub-message class is compiled when the first message of it is met:
     until then the name the generated code calls is a stub that compiles
     the class and rebinds itself, which is also what lets recursive
-    schemas resolve.
+    schemas resolve. `decoder_to` compiles the decode source's second
+    target, for the classes that are asked for one.
     """
 
     def __init__(self, cls):
@@ -255,6 +285,7 @@ class _Codec:
         self.decode = self._compile("decode", self._decode_source())
         self.encode = self._compile("encode", self._encode_source())
         self.init = self._compile("init", self._init_source())
+        self._decoder_makers = {}
 
     def _compile(self, name: str, source: str):
         code = compile(source, f"<{self.cls.__module__}.{self.cls.__qualname__} codec>", "exec")
@@ -301,7 +332,25 @@ class _Codec:
 
     # -- decode -------------------------------------------------------------
 
-    def _decode_source(self) -> str:
+    def decoder_to(self, build, subs: dict):
+        """`Message.decoder_to`: the source is compiled once for each set
+        of names in `subs`, as a function of `build` and the decoders."""
+        fields = self.cls.fields
+        at = tuple(i for i, f in enumerate(fields) if f.name in subs and f.ftype == "message" and not f.lazy)
+        for name in subs.keys() - {fields[i].name for i in at}:
+            raise TypeError(f"{self.cls.__name__}.{name}: only a sub-message that is not lazy is read by a decoder")
+        with _compile_lock:  # `_compile` passes the function through the one namespace
+            make = self._decoder_makers.get(at)
+            if make is None:
+                lines = [f"def make({', '.join(['build'] + [f'sub{i}' for i in at])}):"]
+                lines += ["    " + line for line in self._decode_source(at).split("\n")] + ["    return decode"]
+                make = self._decoder_makers[at] = self._compile("make", "\n".join(lines))
+        return make(build, *(subs[fields[i].name] for i in at))
+
+    def _decode_source(self, subs: tuple | None = None) -> str:
+        """The source of `decode(buf, pos, end)`. With `subs`, the fields
+        that `sub{i}` decodes in place, the second target: the same lines
+        up to the end of the loop, then `build(f0, f1, ...)`."""
         fields = self.cls.fields
         out = ["def decode(buf, pos, end):"]
         if any(f.ftype != "message" and _scalar(f.ftype)[2] in ("fixed64", "fixed32") for f in fields):
@@ -325,19 +374,24 @@ class _Codec:
         branch = "if"
         for number in sorted(by_number):
             out.append(f"        {branch} num == {number}:")
-            out += self._decode_field_lines(*by_number[number], " " * 12)
+            out += self._decode_field_lines(*by_number[number], " " * 12, subs or ())
             branch = "elif"
         skip = "pos = skip(buf, pos, end, tag & 7)"
         out += ["        else:", f"            {skip}"] if by_number else [f"        {skip}"]
+        if subs is not None:
+            values = [f"M{i}() if f{i} is None else f{i}" if i in late and i not in subs else f"f{i}" for i in range(len(fields))]
+            return "\n".join(out + [f"    return build({', '.join(values)})"])
         out.append("    msg = new(cls)")
         for i, f in enumerate(fields):
             out.append(f"    msg.{f.name} = M{i}() if f{i} is None else f{i}" if i in late else f"    msg.{f.name} = f{i}")
         out.append("    return msg")
         return "\n".join(out)
 
-    def _decode_field_lines(self, i: int, f: Field, ind: str) -> list[str]:
+    def _decode_field_lines(self, i: int, f: Field, ind: str, subs: tuple = ()) -> list[str]:
         if f.ftype == "message":
-            if f.lazy:
+            if i in subs:
+                store = [ind + (f"f{i}.append(sub{i}(buf, pos, e))" if f.repeated else f"f{i} = sub{i}(buf, pos, e)")]
+            elif f.lazy:
                 # only the last occurrence is kept unread: one it replaces is decoded here, for its verdict
                 store = [f"{ind}if f{i} is not None:", f"{ind}    f{i}.read()", f"{ind}f{i} = Unread(M{i}, buf, pos, e)"]
             else:

@@ -1,11 +1,21 @@
 """SignedHeader and LightBlock (ref: types/light.go).
 
 A light block's two large parts, the commit and the validator set, are
-built from their proto the first time something reads them (`_Deferred`):
-`from_proto` builds the header alone. A block that is only compared by
-its header's hash (a witness's copy, light/client.py `_cross_reference`)
-never pays for the parts; a block that is validated, verified or stored
-reads both in `validate_basic` and is an ordinary object from then on.
+built the first time something reads them (`_Deferred`): `from_proto`
+builds the header alone. A block that is only compared by its header's
+hash (a witness's copy, light/client.py `_cross_reference`) never pays
+for the parts; a block that is validated, verified or stored reads both
+in `validate_basic` and is an ordinary object from then on.
+
+A part that is read is decoded once. Where it still lies in the buffer
+the block came in (proto/message.py `lazy`), `Commit.from_bytes` and
+`ValidatorSet.from_bytes` go from those bytes to the objects in one
+pass: no `pb.Commit` or `pb.ValidatorSet` is made on the way. A
+validator built so keeps its merkle leaf from that pass, and the leaf
+is built from the values decoded (the key, the power), not copied from
+the peer's bytes: `validate_basic` hashes the same leaves and holds the
+root to the header as before. A part that is already a `pb` message (a
+block made in this process) goes through `from_proto`.
 """
 
 from __future__ import annotations
@@ -15,31 +25,41 @@ from dataclasses import dataclass
 from .. import trace as _trace
 from ..metrics import light_metrics as _light_metrics
 from ..proto import messages as pb
-from ..proto.message import Deferred, DeferredAttr
+from ..proto.message import Deferred, DeferredAttr, _Unread, held
 from .block import Commit, Header
 from .validator_set import ValidatorSet
 
 
 class _Deferred(Deferred):
     """A part not yet built: the proto message that carries it (whose
-    own field may still be bytes, proto/message.py `lazy`) and the
-    `from_proto` that builds it."""
+    own field may still be bytes, proto/message.py `lazy`) and the class
+    (`Commit`, `ValidatorSet`) that builds it."""
 
-    __slots__ = ("source", "part", "build")
+    __slots__ = ("source", "part", "cls")
 
-    def __init__(self, source, part: str, build):
-        self.source, self.part, self.build = source, part, build
+    def __init__(self, source, part: str, cls):
+        self.source, self.part, self.cls = source, part, cls
         _light_metrics().block_parts.add(1, part, "deferred")
 
     def read(self):
-        """Decode and build the part; malformed bytes raise the
+        """Build the part: straight from its bytes where the message has
+        not decoded them (`path="direct"`), from the `pb` message where
+        it holds one (`path="message"`). Malformed bytes raise the
         ValueError that decoding the whole block used to raise. A part
         the message does not carry reads as None, which `validate_basic`
         refuses."""
-        with _trace.span("light.decode_part", "light", part=self.part):
-            p = getattr(self.source, self.part)
-            value = None if p is None else self.build(p)
-        _light_metrics().block_parts.add(1, self.part, "read")
+        part = self.part
+        with _trace.span("light.decode_part", "light", part=part):
+            p = held(self.source, part)
+            if p.__class__ is _Unread:
+                path, value = "direct", self.cls.from_bytes(p.buf, p.start, p.end)
+            else:
+                path, value = "message", None if p is None else self.cls.from_proto(p)
+            rows = 0 if value is None else value.size()
+            _trace.annotate(path=path, rows=rows)
+        metrics = _light_metrics()
+        metrics.block_parts.add(1, part, "read")
+        metrics.part_rows.add(rows, part, path)
         return value
 
 
@@ -77,7 +97,7 @@ class SignedHeader:
 
     @classmethod
     def from_proto(cls, p: pb.SignedHeader) -> "SignedHeader":
-        return cls(header=Header.from_proto(p.header), commit=_Deferred(p, "commit", Commit.from_proto))
+        return cls(header=Header.from_proto(p.header), commit=_Deferred(p, "commit", Commit))
 
 
 @dataclass
@@ -118,5 +138,5 @@ class LightBlock:
     def from_proto(cls, p: pb.LightBlock) -> "LightBlock":
         return cls(
             signed_header=SignedHeader.from_proto(p.signed_header),
-            validator_set=_Deferred(p, "validator_set", ValidatorSet.from_proto),
+            validator_set=_Deferred(p, "validator_set", ValidatorSet),
         )

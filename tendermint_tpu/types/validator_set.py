@@ -9,12 +9,15 @@ every (height, round) and the identical post-update set, so the arithmetic
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 from ..crypto import PubKey, encoding
+from ..crypto.ed25519 import Ed25519PubKey
 from ..crypto.merkle import hash_from_byte_slices
 from ..metrics import hash_metrics
 from ..proto import messages as pb
+from ..proto import wire
 
 # ref: types/validator_set.go:25 — cap so priority arithmetic can't overflow.
 MAX_TOTAL_VOTING_POWER = (2**63 - 1) // 8
@@ -419,6 +422,64 @@ class ValidatorSet:
         if p.proposer is not None:
             vs.proposer = Validator.from_proto(p.proposer)
         return vs
+
+    @classmethod
+    def from_bytes(cls, buf: bytes, start: int = 0, end: int | None = None) -> "ValidatorSet":
+        """`from_proto(pb.ValidatorSet.decode(buf[start:end]))` in one pass
+        over the bytes: an equal set, or the same error. No `pb` message is
+        made, and an ed25519 validator comes with its merkle leaf (`bytes()`)."""
+        if buf.__class__ is not bytes:
+            buf = bytes(buf)
+        return _wire_decoder()(buf, start, len(buf) if end is None else end)
+
+
+# -- from the wire in one pass (proto/message.py `decoder_to`) ---------------
+
+# SimpleValidator{pub_key: PublicKey{ed25519: <32 bytes>}}: field 1, 34 bytes of field 1, 32 bytes
+_ED25519_LEAF = b"\x0a\x22\x0a\x20"
+_POWER_TAG = b"\x10"  # SimpleValidator.voting_power, left out when 0
+
+
+def _pub_key_at(buf: bytes, pos: int, end: int):
+    """A validator's `pub_key` sub-message, read where it lies. Exactly
+    the ed25519 arm with 32 bytes (`0a 20` and the key): the key.
+    Anything else (another arm, two arms, another length) is decoded as
+    `pb.Validator.decode` decodes it, for `pubkey_from_proto` to judge."""
+    if end - pos == 34 and buf[pos] == 0x0A and buf[pos + 1] == 0x20:
+        return buf[pos + 2 : end]
+    return pb.PublicKey.decode(buf[pos:end])
+
+
+def _validator_row(*values):
+    return values
+
+
+def _validator_of(address, key, voting_power, proposer_priority) -> Validator:
+    """What `Validator.from_proto` makes of the same four fields. The
+    leaf is joined from the decoded key and power, never copied from the
+    buffer: however a peer spelled them, the set hashes what they mean."""
+    if key.__class__ is bytes:
+        pub_key = Ed25519PubKey(key)
+        leaf = _ED25519_LEAF + key
+        if voting_power:
+            leaf += _POWER_TAG + wire.encode_varint(voting_power)
+        return Validator(address, pub_key, voting_power, proposer_priority, (pub_key, voting_power, leaf))
+    return Validator(address, encoding.pubkey_from_proto(pb.PublicKey() if key is None else key), voting_power, proposer_priority)
+
+
+def _validator_set_of(rows, proposer, _total_voting_power) -> ValidatorSet:
+    # built after the last byte is read, validators first: a key that is
+    # refused is refused after everything the decoder refuses, as in two passes
+    vs = ValidatorSet(validators=[_validator_of(*row) for row in rows])
+    if proposer is not None:
+        vs.proposer = _validator_of(*proposer)
+    return vs
+
+
+@functools.cache
+def _wire_decoder():
+    row = pb.Validator.decoder_to(_validator_row, pub_key=_pub_key_at)
+    return pb.ValidatorSet.decoder_to(_validator_set_of, validators=row, proposer=row)
 
 
 def _process_changes(orig_changes: list[Validator]) -> tuple[list[Validator], list[Validator]]:

@@ -13,10 +13,12 @@ prints and copies alike, and that two threads may read one part.
 from __future__ import annotations
 
 import copy
+import gc
 import json
 import os
 import sys
 import threading
+import weakref
 
 import pytest
 
@@ -101,10 +103,15 @@ def test_a_decoded_block_has_read_neither_part_and_hashes_its_header(blocks, n):
     assert unread(lb) == {"commit", "validator_set"}  # none of that read a part
     lb.validate_basic(chain.chain_id)
     assert unread(lb) == set()
-    # read, the block holds objects and nothing of the buffer it came from
-    assert not isinstance(p.__dict__["_validator_set"], _Unread)
-    assert not isinstance(p.signed_header.__dict__["_commit"], _Unread)
+    # read straight from the bytes: the message never decoded its parts
+    assert isinstance(p.__dict__["_validator_set"], _Unread)
+    assert isinstance(p.signed_header.__dict__["_commit"], _Unread)
     assert len(lb.validator_set.validators) == len(lb.signed_header.commit.signatures) == n
+    # and the block holds objects, nothing of the message or the buffer it came from
+    message = weakref.ref(p)
+    del p
+    gc.collect()
+    assert message() is None
 
 
 @pytest.mark.parametrize("part", ["validator_set", "commit"])
