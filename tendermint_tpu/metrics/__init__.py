@@ -864,6 +864,26 @@ class EngineMetrics:
             "before the launch, or the batch fell back to the uncached kernel",
             labels=("plane",),
         )
+        self.pk_cache_fills = reg.counter(
+            f"{ns}_pk_cache_fills_total",
+            "Pubkey-cache fills: a table build and its publish for the keys a batch missed",
+            labels=("plane",),
+        )
+        self.pk_cache_filled_keys = reg.counter(
+            f"{ns}_pk_cache_filled_keys_total",
+            "Keys whose tables a fill built (the batch's distinct misses)",
+            labels=("plane",),
+        )
+        self.pk_cache_fill_rows = reg.counter(
+            f"{ns}_pk_cache_fill_rows_total",
+            "Rows a fill's two programs ran at: the launch bucket of the batch that missed",
+            labels=("plane",),
+        )
+        self.pk_cache_fill_seconds = reg.counter(
+            f"{ns}_pk_cache_fill_seconds_total",
+            "Wall seconds of fills, table build and publish",
+            labels=("plane",),
+        )
 
     def observe_path(self, plane: str, path: str, bools) -> None:
         """Fold one launch's per-row outcomes into the path counters."""

@@ -25,6 +25,7 @@ Split of labor:
 from __future__ import annotations
 
 import hashlib
+import time
 
 import numpy as np
 
@@ -253,6 +254,7 @@ class PubkeyCache:
             # A rotating validator set misses 1, 3, 13 keys of a batch;
             # shapes cut to the miss count compiled inside each update.
             m, rows = len(missing), _pad_pow2(len(pubkeys))
+            t0 = time.perf_counter()
             try:
                 enc_p = np.zeros((rows, 32), np.uint8)
                 enc_p[:m] = np.frombuffer(b"".join(missing), np.uint8).reshape(-1, 32)
@@ -289,6 +291,7 @@ class PubkeyCache:
             event.set()
             built = set(missing)
             self._count(len(pubkeys), sum(pk in built for pk in pubkeys))
+            self._count_fill(m, rows, time.perf_counter() - t0)
             return slots, tables, oks
 
     def _count(self, rows: int, missed: int) -> None:
@@ -298,6 +301,15 @@ class PubkeyCache:
         m = _engine_metrics()
         m.pk_cache_rows.add(rows, self.plane)
         m.pk_cache_missed_rows.add(missed, self.plane)
+
+    def _count_fill(self, keys: int, rows: int, seconds: float) -> None:
+        """One fill: the keys it built tables for, the padded rows its
+        programs ran at, and its wall time, build and publish."""
+        m = _engine_metrics()
+        m.pk_cache_fills.add(1, self.plane)
+        m.pk_cache_filled_keys.add(keys, self.plane)
+        m.pk_cache_fill_rows.add(rows, self.plane)
+        m.pk_cache_fill_seconds.add(seconds, self.plane)
 
     def _unpin(self, keys) -> None:
         """Drop one eviction pin per key (lock held by caller)."""

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
 
+from .. import trace as _trace
 from ..types.block import Block, BlockID, Commit, Header
 from ..types.genesis import GenesisDoc
 from ..types.params import ConsensusParams, default_consensus_params
@@ -67,43 +68,45 @@ class State:
         """Fold one decided block into the state (ref: State.Update,
         internal/state/execution.go:527). AppHash is filled by the caller
         after ABCI Commit."""
-        n_val_set = self.next_validators.copy()
-        last_height_vals_changed = self.last_height_validators_changed
-        if validator_updates:
-            n_val_set.update_with_change_set(validator_updates)
-            # Changes at H apply starting H+2 (execution.go:545).
-            last_height_vals_changed = header.height + 1 + 1
-        n_val_set.increment_proposer_priority(1)
+        with _trace.span("state.update", "state", height=header.height,
+                         changes=len(validator_updates or ())):
+            n_val_set = self.next_validators.copy()
+            last_height_vals_changed = self.last_height_validators_changed
+            if validator_updates:
+                n_val_set.update_with_change_set(validator_updates)
+                # Changes at H apply starting H+2 (execution.go:545).
+                last_height_vals_changed = header.height + 1 + 1
+            n_val_set.increment_proposer_priority(1)
 
-        next_params = self.consensus_params
-        last_height_params_changed = self.last_height_consensus_params_changed
-        version_app = self.version_app
-        if consensus_param_updates is not None:
-            # consensus_param_updates is a pb.ConsensusParamsUpdate with only
-            # the changed sections set (ref: UpdateConsensusParams,
-            # types/params.go:413).
-            next_params = self.consensus_params.update_consensus_params(consensus_param_updates)
-            next_params.validate_consensus_params()
-            version_app = next_params.version.app_version
-            last_height_params_changed = header.height + 1
+            next_params = self.consensus_params
+            last_height_params_changed = self.last_height_consensus_params_changed
+            version_app = self.version_app
+            if consensus_param_updates is not None:
+                # consensus_param_updates is a pb.ConsensusParamsUpdate with only
+                # the changed sections set (ref: UpdateConsensusParams,
+                # types/params.go:413).
+                next_params = self.consensus_params.update_consensus_params(consensus_param_updates)
+                next_params.validate_consensus_params()
+                version_app = next_params.version.app_version
+                last_height_params_changed = header.height + 1
 
-        return State(
-            chain_id=self.chain_id,
-            initial_height=self.initial_height,
-            last_block_height=header.height,
-            last_block_id=block_id,
-            last_block_time=header.time,
-            next_validators=n_val_set,
-            validators=self.next_validators.copy(),
-            last_validators=self.validators.copy(),
-            last_height_validators_changed=last_height_vals_changed,
-            consensus_params=next_params,
-            last_height_consensus_params_changed=last_height_params_changed,
-            last_results_hash=results_hash,
-            app_hash=b"",
-            version_block=self.version_block,
-            version_app=version_app,
-        )
+            return State(
+                chain_id=self.chain_id,
+                initial_height=self.initial_height,
+                last_block_height=header.height,
+                last_block_id=block_id,
+                last_block_time=header.time,
+                next_validators=n_val_set,
+                validators=self.next_validators.copy(),
+                last_validators=self.validators.copy(),
+                last_height_validators_changed=last_height_vals_changed,
+                consensus_params=next_params,
+                last_height_consensus_params_changed=last_height_params_changed,
+                last_results_hash=results_hash,
+                app_hash=b"",
+                version_block=self.version_block,
+                version_app=version_app,
+            )
 
     def make_block(
         self,

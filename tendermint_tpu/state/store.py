@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import json
 
+from .. import trace as _trace
 from ..proto import messages as pb
 from ..store.kv import KVStore
 from ..types.block import BlockID, PartSetHeader
@@ -121,13 +122,20 @@ class StateStore:
     def save(self, state: State) -> None:
         """Persist state + the validator set / params it implies for the
         next height (ref: store.go Save:157)."""
+        with _trace.span("state.save", "state", height=state.last_block_height) as sp:
+            sp.annotate(full_sets_written=self._save(state))
+
+    def _save(self, state: State) -> int:
+        """save's work; returns the full validator sets it encoded."""
+        full = 0
         # At genesis the "next" height is initial_height, not 1
         # (ref: store.go Save:165 nextHeight = state.InitialHeight).
         next_height = state.last_block_height + 1
         if state.last_block_height == 0:
             next_height = state.initial_height
             # initial state: bootstrap the current set
-            self.save_validator_sets(state.initial_height, state.last_height_validators_changed, state.validators)
+            full += self.save_validator_sets(state.initial_height, state.last_height_validators_changed,
+                                             state.validators)
         # The next-height entry carries last_height_validators_changed —
         # a SPARSE pointer while the set is unchanged, exactly like the
         # reference (store.go Save:169). Storing a full set here at
@@ -138,9 +146,11 @@ class StateStore:
         # deleted height 1 while heights above still pointed at it —
         # the first post-prune LoadValidators crashed consensus (found
         # by the ISSUE-14 soak harness driving retain_blocks).
-        self.save_validator_sets(next_height + 1, state.last_height_validators_changed, state.next_validators)
+        full += self.save_validator_sets(next_height + 1, state.last_height_validators_changed,
+                                         state.next_validators)
         self._save_params(next_height, state.last_height_consensus_params_changed, state.consensus_params)
         self._db.set(KEY_STATE, json.dumps(state_to_json(state)).encode())
+        return full
 
     def bootstrap(self, state: State) -> None:
         """ref: store.go Bootstrap — used by statesync."""
@@ -162,13 +172,17 @@ class StateStore:
 
     # ------------------------------------------------- validator sets
 
-    def save_validator_sets(self, height: int, last_height_changed: int, val_set: ValidatorSet) -> None:
+    def save_validator_sets(self, height: int, last_height_changed: int, val_set: ValidatorSet) -> bool:
+        """The set at `height`, or a pointer to the height it last
+        changed at; True where the full set was encoded."""
         if last_height_changed > height:
             last_height_changed = height
         doc = {"last_height_changed": last_height_changed}
-        if height == last_height_changed:
+        full = height == last_height_changed
+        if full:
             doc["validator_set"] = _b64(val_set.to_proto().encode())
         self._db.set(_hkey(KEY_VALIDATORS, height), json.dumps(doc).encode())
+        return full
 
     def load_validators(self, height: int) -> ValidatorSet | None:
         """ref: store.go LoadValidators — follow the sparse pointer, then
