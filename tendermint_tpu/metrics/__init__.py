@@ -937,7 +937,7 @@ class HashMetrics:
         )
         self.cache_events = reg.counter(
             f"{ns}_cache_events_total",
-            "Structural-hash memo events (hit/miss/invalidate) by site",
+            "Structural-hash and validator-row encoding memo events (hit/miss/invalidate) by site",
             labels=("site", "event"),
         )
 

@@ -51,7 +51,17 @@ def _events_from_json(docs: list):
     ]
 
 
-def state_to_json(state: State) -> dict:
+def _set_b64(val_set: ValidatorSet, tally: list[int] | None) -> str:
+    """The set's wire bytes (`ValidatorSet.to_bytes`) in base64; adds the
+    rows written and the rows kept to `tally` where one is given."""
+    raw, rows, kept = val_set.encode_counted()
+    if tally is not None:
+        tally[0] += rows
+        tally[1] += kept
+    return _b64(raw)
+
+
+def state_to_json(state: State, tally: list[int] | None = None) -> dict:
     return {
         "chain_id": state.chain_id,
         "initial_height": state.initial_height,
@@ -62,9 +72,9 @@ def state_to_json(state: State) -> dict:
             "psh_hash": _b64(state.last_block_id.part_set_header.hash),
         },
         "last_block_time": state.last_block_time.unix_ns(),
-        "validators": _b64(state.validators.to_proto().encode()),
-        "next_validators": _b64(state.next_validators.to_proto().encode()),
-        "last_validators": _b64(state.last_validators.to_proto().encode()),
+        "validators": _set_b64(state.validators, tally),
+        "next_validators": _set_b64(state.next_validators, tally),
+        "last_validators": _set_b64(state.last_validators, tally),
         "last_height_validators_changed": state.last_height_validators_changed,
         "consensus_params": _params_to_json(state.consensus_params),
         "last_height_consensus_params_changed": state.last_height_consensus_params_changed,
@@ -123,10 +133,12 @@ class StateStore:
         """Persist state + the validator set / params it implies for the
         next height (ref: store.go Save:157)."""
         with _trace.span("state.save", "state", height=state.last_block_height) as sp:
-            sp.annotate(full_sets_written=self._save(state))
+            tally = [0, 0]
+            sp.annotate(full_sets_written=self._save(state, tally), rows=tally[0], rows_kept=tally[1])
 
-    def _save(self, state: State) -> int:
-        """save's work; returns the full validator sets it encoded."""
+    def _save(self, state: State, tally: list[int]) -> int:
+        """save's work; returns the full validator sets it encoded and
+        adds the validator rows it wrote, and those kept, to `tally`."""
         full = 0
         # At genesis the "next" height is initial_height, not 1
         # (ref: store.go Save:165 nextHeight = state.InitialHeight).
@@ -135,7 +147,7 @@ class StateStore:
             next_height = state.initial_height
             # initial state: bootstrap the current set
             full += self.save_validator_sets(state.initial_height, state.last_height_validators_changed,
-                                             state.validators)
+                                             state.validators, tally)
         # The next-height entry carries last_height_validators_changed —
         # a SPARSE pointer while the set is unchanged, exactly like the
         # reference (store.go Save:169). Storing a full set here at
@@ -147,9 +159,9 @@ class StateStore:
         # the first post-prune LoadValidators crashed consensus (found
         # by the ISSUE-14 soak harness driving retain_blocks).
         full += self.save_validator_sets(next_height + 1, state.last_height_validators_changed,
-                                         state.next_validators)
+                                         state.next_validators, tally)
         self._save_params(next_height, state.last_height_consensus_params_changed, state.consensus_params)
-        self._db.set(KEY_STATE, json.dumps(state_to_json(state)).encode())
+        self._db.set(KEY_STATE, json.dumps(state_to_json(state, tally)).encode())
         return full
 
     def bootstrap(self, state: State) -> None:
@@ -172,15 +184,17 @@ class StateStore:
 
     # ------------------------------------------------- validator sets
 
-    def save_validator_sets(self, height: int, last_height_changed: int, val_set: ValidatorSet) -> bool:
+    def save_validator_sets(self, height: int, last_height_changed: int, val_set: ValidatorSet,
+                            tally: list[int] | None = None) -> bool:
         """The set at `height`, or a pointer to the height it last
-        changed at; True where the full set was encoded."""
+        changed at; True where the full set was encoded (its rows added
+        to `tally`, as `state_to_json` adds them)."""
         if last_height_changed > height:
             last_height_changed = height
         doc = {"last_height_changed": last_height_changed}
         full = height == last_height_changed
         if full:
-            doc["validator_set"] = _b64(val_set.to_proto().encode())
+            doc["validator_set"] = _set_b64(val_set, tally)
         self._db.set(_hkey(KEY_VALIDATORS, height), json.dumps(doc).encode())
         return full
 

@@ -30,6 +30,14 @@ MAX_VOTES_COUNT = 10000
 _INT64_MAX = 2**63 - 1
 _INT64_MIN = -(2**63)
 
+# the tags ValidatorSet.to_bytes writes: pb.ValidatorSet's validators (1),
+# proposer (2) and total_voting_power (3), pb.Validator's proposer_priority (4)
+_ROW_TAG = b"\x0a"
+_PROPOSER_TAG = b"\x12"
+_TOTAL_TAG = b"\x18"
+_PRIORITY_TAG = b"\x20"
+_uvarint = wire.encode_varint
+
 
 def _clip64(v: int) -> int:
     """int64 saturating clamp (ref: safeAddClip/safeSubClip, types/utils.go)."""
@@ -62,6 +70,13 @@ class Validator:
     # encoding does not, so the encode survives the per-block
     # State.copy() churn.
     _bytes_cache: tuple | None = field(default=None, compare=False, repr=False)
+    # Guarded memo of the fixed part of the `pb.Validator` row: fields
+    # 1-3 (address, pub_key, voting_power), which ValidatorSet.to_bytes
+    # writes before the priority. Kept as (address, pub_key,
+    # voting_power, bytes); every read re-checks address (equality),
+    # pub_key (identity) and voting_power, and copy() carries it, as
+    # _bytes_cache.
+    _row_cache: tuple | None = field(default=None, compare=False, repr=False)
 
     @classmethod
     def new(cls, pub_key: PubKey, voting_power: int) -> "Validator":
@@ -70,7 +85,7 @@ class Validator:
     def copy(self) -> "Validator":
         return Validator(
             self.address, self.pub_key, self.voting_power, self.proposer_priority,
-            self._bytes_cache,
+            self._bytes_cache, self._row_cache,
         )
 
     def validate_basic(self) -> None:
@@ -105,6 +120,19 @@ class Validator:
             pub_key=encoding.pubkey_to_proto(self.pub_key), voting_power=self.voting_power
         ).encode()
         self._bytes_cache = (self.pub_key, self.voting_power, enc)
+        return enc
+
+    def _fixed_row(self) -> bytes:
+        """Fields 1-3 of `to_proto().encode()` (the priority left at 0),
+        built by the codec whatever the key type and kept in _row_cache;
+        ValidatorSet.to_bytes reads the memo itself and calls this where
+        the guard fails."""
+        enc = pb.Validator(
+            address=self.address, pub_key=encoding.pubkey_to_proto(self.pub_key),
+            voting_power=self.voting_power,
+        ).encode()
+        # tmcheck: ok[shared-mutation] idempotent lazy memo: racing fills store the same bytes, and every read re-checks the three fields
+        self._row_cache = (self.address, self.pub_key, self.voting_power, enc)
         return enc
 
     def to_proto(self) -> pb.Validator:
@@ -415,6 +443,44 @@ class ValidatorSet:
             proposer=self.proposer.to_proto() if self.proposer else None,
             total_voting_power=self.total_voting_power() if self.validators else 0,
         )
+
+    def to_bytes(self) -> bytes:
+        """`to_proto().encode()` in one pass, with no `pb` message made."""
+        return self.encode_counted()[0]
+
+    def encode_counted(self) -> tuple[bytes, int, int]:
+        """`to_bytes()`, the rows it wrote (validators, then the proposer
+        where there is one) and the rows whose fixed part was kept from
+        an earlier encode (`Validator._row_cache`). A row is that part and
+        field 4, the priority, left out when 0 as the codec leaves it."""
+        parts: list[bytes] = []
+        add = parts.append
+        kept = 0
+        rows = self.validators if self.proposer is None else [*self.validators, self.proposer]
+        last = len(self.validators)
+        for i, v in enumerate(rows):
+            c = v._row_cache
+            if c is not None and c[1] is v.pub_key and c[2] == v.voting_power and c[0] == v.address:
+                row = c[3]
+                kept += 1
+            else:
+                row = v._fixed_row()
+            p = v.proposer_priority
+            if p:
+                row += _PRIORITY_TAG + _uvarint(p)
+            add(_ROW_TAG if i < last else _PROPOSER_TAG)
+            add(_uvarint(len(row)))
+            add(row)
+        if self.validators:
+            total = self.total_voting_power()
+            if total:
+                add(_TOTAL_TAG)
+                add(_uvarint(total))
+        if kept:
+            hash_metrics().cache_events.add(kept, "validator_row", "hit")
+        if len(rows) > kept:
+            hash_metrics().cache_events.add(len(rows) - kept, "validator_row", "miss")
+        return b"".join(parts), len(rows), kept
 
     @classmethod
     def from_proto(cls, p: pb.ValidatorSet) -> "ValidatorSet":
