@@ -798,12 +798,12 @@ class EngineMetrics:
         )
         self.path_rows = reg.counter(
             f"{ns}_path_rows_total",
-            "Signature rows by verification path and outcome",
+            "Signature rows by verification path (host, bitmap, two_phase_msm, sharded: split across the process's chips) and outcome",
             labels=("plane", "path", "status"),
         )
         self.launches = reg.counter(
             f"{ns}_launches_total",
-            "Verification launches by path",
+            "Verification launches by path (host, bitmap, two_phase_msm, sharded)",
             labels=("plane", "path"),
         )
         self.device_batch_cutover = reg.gauge(
@@ -845,7 +845,7 @@ class EngineMetrics:
         )
         self.sharded_launches = reg.counter(
             f"{ns}_sharded_launches_total",
-            "Mesh-sharded launches by path",
+            "Mesh-sharded launches by program (bitmap: the per-signature program, the engine's sharded route among them; rlc)",
             labels=("path",),
         )
         self.kernel_launches = reg.counter(

@@ -35,9 +35,9 @@ arxiv 2112.02229). This module is that pipeline:
 
 This is the only router: both BatchVerifiers and the mempool's
 pre-verifier submit here, and VerifyEngine._dispatch_group alone chooses
-host / per-signature / two-phase MSM for a batch. The module imports no
-jax; a node pinned to the host (TM_TPU_CRYPTO=off) runs the host plane
-and never loads it.
+host / split across the chips / per-signature / two-phase MSM for a
+batch. The module imports no jax; a node pinned to the host
+(TM_TPU_CRYPTO=off) runs the host plane and never loads it.
 """
 
 from __future__ import annotations
@@ -55,6 +55,32 @@ from ..metrics import engine_metrics as _engine_metrics
 # double buffer absorbs them); bounds both padding waste and the jit
 # shape zoo.
 MAX_COALESCE_ROWS = 8192
+
+# A chip's share of a group from which the group is split across the
+# process's chips: the per-signature program is row-bound from 512 rows
+# (scripts/route_prices.py), so a smaller share would pay a launch a
+# chip for rows one chip runs in about the same time.
+SHARD_MIN_ROWS = 512
+
+
+def splits(chips: int, rows: int) -> bool:
+    """The sharded route's rule: a device-routed group of `rows` goes
+    over `chips` chips when every chip's share is at least
+    SHARD_MIN_ROWS."""
+    return chips > 1 and rows >= chips * SHARD_MIN_ROWS
+
+
+def _local_tpu_mesh():
+    """A mesh over the process's own TPU chips where it has more than
+    one, else None: the chips a group can be split over."""
+    import jax
+
+    devices = jax.local_devices()
+    if len(devices) < 2 or devices[0].platform != "tpu":
+        return None
+    from ..parallel.sharded_verify import make_mesh
+
+    return make_mesh(devices=devices)
 
 
 # ------------------------------------------------------------------ autotune
@@ -341,7 +367,11 @@ class VerifyEngine:
     the tm-engine prefix (allow-listed by utils/leaktest.py — engine
     lifetime is the process, not a test body)."""
 
-    def __init__(self):
+    def __init__(self, mesh=None):
+        # the chips a large group is split over (the sharded route):
+        # the process's TPU chips, found on first use, unless a mesh is
+        # handed in (the tests' virtual CPU devices)
+        self._mesh, self._mesh_found = mesh, mesh is not None
         self._lock = threading.Lock()
         self._have_jobs = threading.Condition(self._lock)
         self._pending: list[_Job] = []
@@ -513,12 +543,18 @@ class VerifyEngine:
                 m.inflight_batches.set(len(self._inflight))
                 self._have_inflight.notify()
 
+    def _split_mesh(self):
+        if not self._mesh_found:
+            self._mesh, self._mesh_found = _local_tpu_mesh(), True
+        return self._mesh
+
     def _dispatch_group(self, group, seq: int = 0):
-        """Coalesce one group's rows, decide the plane (device bitmap /
-        two-phase MSM / host C), run prep + the async launch NOW, and
-        return (collect thunk producing the combined (rows,) bools,
-        path name for telemetry). seq tags this batch's recorded stage
-        intervals so its own collect never counts them as overlap."""
+        """Coalesce one group's rows, decide the plane (split across the
+        chips / device bitmap / two-phase MSM / host C), run prep + the
+        async launch NOW, and return (collect thunk producing the
+        combined (rows,) bools, path name for telemetry). seq tags this
+        batch's recorded stage intervals so its own collect never counts
+        them as overlap."""
         from ..crypto import ed25519 as ed
 
         plane = group[0].plane
@@ -553,6 +589,14 @@ class VerifyEngine:
 
             future = _host_pool().submit(host_verify)
             return future.result, "host"  # .result raises the worker's exception
+
+        mesh = self._split_mesh()
+        if mesh is not None and splits(mesh.devices.size, total):
+            from ..parallel import sharded_verify as sharded
+
+            handle = sharded.dispatch(mesh, pks, msgs, sigs, plane)
+            if handle is not None:  # else the mesh cache is full: one chip's routes
+                return (lambda: [bool(b) for b in sharded.collect(handle)]), "sharded"
 
         if plane == "ed25519":
             from . import verify as dev

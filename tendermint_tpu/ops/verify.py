@@ -141,11 +141,20 @@ class PubkeyCache:
     which creates a NEW device array — in-flight async batches keep
     referencing the buffers they were dispatched with."""
 
-    def __init__(self, capacity: int = 4096, build_fn=None, plane: str = "pk"):
+    def __init__(self, capacity: int = 4096, build_fn=None, plane: str = "pk", sharding=None,
+                 fill_rows: int | None = None):
         import collections
         import threading
 
         self.capacity = capacity
+        # the rows a fill's two programs run at: the launch bucket of the
+        # batch that missed, or one fixed count (at most `capacity`)
+        self.fill_rows = fill_rows
+        # where the arrays live: the default device, or (the sharded
+        # route's cache) every chip of a mesh, replicated, so that a
+        # launch reads its rows' tables on its own chip
+        self._put = jnp.asarray if sharding is None else (
+            lambda x: jax.device_put(x, sharding))
         self.plane = plane  # devobs compile-attribution + residency label
         self._build = build_fn or build_pk_tables_split  # sr25519 plugs in its decoder
         self._lock = threading.Lock()  # reactors verify concurrently
@@ -167,8 +176,8 @@ class PubkeyCache:
         #   re-serialize hit-only verifiers behind the build).
         self._pending: "dict[bytes, threading.Event]" = {}
         self._pinned: "dict[bytes, int]" = {}
-        self.tables = jnp.zeros((capacity, PK_SPLITS, 16, 4, 32), jnp.int16)
-        self.oks = jnp.zeros((capacity,), bool)
+        self.tables = self._put(jnp.zeros((capacity, PK_SPLITS, 16, 4, 32), jnp.int16))
+        self.oks = self._put(jnp.zeros((capacity,), bool))
 
     def ensure(self, pubkeys):
         """Map pubkeys -> slot indices, inserting misses in one batched
@@ -248,12 +257,13 @@ class PubkeyCache:
                 continue  # retry: the fills we waited on moved the LRU
             # ---- build OUTSIDE the lock (the expensive device call)
             # Both programs of a fill run at the launch bucket of the
-            # batch that missed, whatever the number of misses: the
-            # rows past the misses decode a zero key and scatter to
-            # slot `capacity`, out of range, which the publish drops.
-            # A rotating validator set misses 1, 3, 13 keys of a batch;
-            # shapes cut to the miss count compiled inside each update.
-            m, rows = len(missing), _pad_pow2(len(pubkeys))
+            # batch that missed (or at `fill_rows`), whatever the number
+            # of misses: the rows past the misses decode a zero key and
+            # scatter to slot `capacity`, out of range, which the
+            # publish drops. A rotating validator set misses 1, 3, 13
+            # keys of a batch; shapes cut to the miss count compiled
+            # inside each update.
+            m, rows = len(missing), self.fill_rows or _pad_pow2(len(pubkeys))
             t0 = time.perf_counter()
             try:
                 enc_p = np.zeros((rows, 32), np.uint8)
@@ -263,7 +273,7 @@ class PubkeyCache:
                 fid = _devobs.next_flow() if _devobs.enabled() else 0
                 with _trace.span("ops.pk_cache_fill", "ops", misses=m, rows=rows, flow=fid):
                     with _devobs.transfer_span("h2d", enc_p.nbytes + idx_p.nbytes, flow=fid):
-                        enc_dev, idx_dev = jnp.asarray(enc_p), jnp.asarray(idx_p)
+                        enc_dev, idx_dev = self._put(enc_p), self._put(idx_p)
                     with _devobs.attribution(
                         fn=f"{self.plane}_table_build", rows=rows, flow=fid,
                     ):

@@ -22,10 +22,8 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..ops import verify as V
 from . import sharded_verify as sv
 
 
@@ -90,24 +88,35 @@ def verify_batch_sharded_local(mesh, pubkeys, msgs, sigs, key_type: str = "ed255
     verify_batch_sharded."""
     if jax.process_count() == 1:
         return sv.verify_batch_sharded(mesh, pubkeys, msgs, sigs, key_type)
-    plane, kernel_impl = sv._PLANES[key_type]
+    from jax.experimental import multihost_utils
+
+    plane, kernel_impl, _ = sv._plane(key_type)
     n = len(sigs)
-    a, r, s, k, precheck = plane.prepare_batch(pubkeys, msgs, sigs)
+    _, r, s, k, precheck = plane.prepare_batch(pubkeys, msgs, sigs)
+    # the pubkey tables are replicated on every chip of the job, so every
+    # process fills its mesh cache with the whole job's keys in one order
+    # (the global batch's): a key then has one slot on every host
+    keys = np.frombuffer(b"".join(pk if len(pk) == 32 else b"\x00" * 32 for pk in pubkeys),
+                         np.uint8).reshape(n, 32)
+    job_keys = multihost_utils.process_allgather(keys).reshape(-1, 32)
+    slots, tables, oks = sv.mesh_cache(mesh, key_type).ensure_snapshot(
+        [row.tobytes() for row in job_keys])
+    if slots is None:
+        raise ValueError(f"the mesh's pubkey cache cannot take the job's keys "
+                         f"({sv.CACHE_SLOTS} slots)")
+    slots = slots[jax.process_index() * n:(jax.process_index() + 1) * n]
     # pad the LOCAL shard to an equal per-process size (collective
-    # contract: same n on every process keeps shapes static)
+    # contract: same n on every process keeps shapes static); a padded
+    # row takes the batch's last slot, as in sv.dispatch
     n_local_dev = len(mesh.local_devices)
-    per_dev = -(-n // n_local_dev)
-    per_dev = V._pad_pow2(per_dev, floor=8) if per_dev <= 256 else -(-per_dev // 256) * 256
-    pad = per_dev * n_local_dev - n
+    pad = sv.chip_rows(n, n_local_dev) * n_local_dev - n
     if pad:
-        a, r, s, k = (np.pad(x, ((0, pad), (0, 0))) for x in (a, r, s, k))
+        r, s, k = (np.pad(x, ((0, pad), (0, 0))) for x in (r, s, k))
+        slots = np.pad(slots, (0, pad), mode="edge")
     sharding = NamedSharding(mesh, P(sv.AXIS))
-    args = [
-        jax.make_array_from_process_local_data(sharding, jnp.asarray(x))
-        for x in (a, r, s, k)
-    ]
+    args = [jax.make_array_from_process_local_data(sharding, x) for x in (slots, r, s, k)]
     fn = sv.sharded_verify_fn(mesh, kernel_impl)
-    bitmap, device_all_valid = fn(*args)
+    bitmap, device_all_valid = fn(tables, oks, *args)
     # addressable slice of the global bitmap = this process's rows;
     # addressable_shards iteration order is not contractually sorted by
     # global index, so order explicitly by each shard's global row start
@@ -118,7 +127,5 @@ def verify_batch_sharded_local(mesh, pubkeys, msgs, sigs, key_type: str = "ed255
     local &= precheck
     # global all-valid must also fold every process's HOST precheck
     # (one tiny DCN allgather; device checks are already psum-reduced)
-    from jax.experimental import multihost_utils
-
     flags = multihost_utils.process_allgather(np.asarray([precheck.all()]))
     return local, bool(device_all_valid) and bool(flags.all())

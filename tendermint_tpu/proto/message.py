@@ -353,8 +353,6 @@ class _Codec:
         up to the end of the loop, then `build(f0, f1, ...)`."""
         fields = self.cls.fields
         out = ["def decode(buf, pos, end):"]
-        if any(f.ftype != "message" and _scalar(f.ftype)[2] in ("fixed64", "fixed32") for f in fields):
-            out.append("    start = pos")
         # a message the buffer does not carry is made after the loop, not before it and thrown away
         late = {i for i, f in enumerate(fields) if f.ftype == "message" and f.always_emit and not f.repeated}
         for i, f in enumerate(fields):
@@ -400,13 +398,13 @@ class _Codec:
             return _read_length(ind, "end") + store + [f"{ind}pos = e"]
         kind = _scalar(f.ftype)[2]
         if not f.repeated:
-            return _read_scalar(kind, ind, "end", "start", f"f{i}")
-        single = _read_scalar(kind, ind, "end", "start", "v") + [f"{ind}f{i}.append(v)"]
+            return _read_scalar(kind, ind, "end", f"f{i}")
+        single = _read_scalar(kind, ind, "end", "v") + [f"{ind}f{i}.append(v)"]
         if kind in ("bytes", "string"):  # every other scalar may come packed
             return single
         packed = _read_length(ind + "    ", "end")
-        packed += [f"{ind}    body = pos", f"{ind}    while pos < e:"]
-        packed += _read_scalar(kind, ind + " " * 8, "e", "body", "v") + [f"{ind}        f{i}.append(v)"]
+        packed += [f"{ind}    while pos < e:"]
+        packed += _read_scalar(kind, ind + " " * 8, "e", "v") + [f"{ind}        f{i}.append(v)"]
         packed.append(f"{ind}    pos = e")
         return [f"{ind}if tag & 7 == 2:"] + packed + [f"{ind}else:"] + [f"    {line}" for line in single]
 
@@ -498,14 +496,14 @@ def _read_length(ind: str, end: str) -> list[str]:
     ]
 
 
-def _read_scalar(kind: str, ind: str, end: str, start: str, v: str) -> list[str]:
-    """Lines that read one scalar at `pos` into `v`. `start` and `end` bound
-    the buffer the scalar lies in: a short fixed-width read raises what
-    `struct` raises on that buffer alone."""
+def _read_scalar(kind: str, ind: str, end: str, v: str) -> list[str]:
+    """Lines that read one scalar at `pos` into `v`. `end` bounds the
+    buffer the scalar lies in: a fixed-width read that would cross it is
+    refused like any other truncated field."""
     if kind in ("fixed64", "fixed32"):
         return [
             f"{ind}if pos + {8 if kind == 'fixed64' else 4} > {end}:",
-            f"{ind}    {kind}(buf[{start}:{end}], pos - {start})",
+            f"{ind}    raise ValueError('truncated {kind} field')",
             f"{ind}{v}, pos = {kind}(buf, pos)",
         ]
     if kind in ("bytes", "string"):

@@ -94,6 +94,8 @@ def encode_fixed64(value: int) -> bytes:
 
 
 def decode_fixed64(buf: bytes, offset: int = 0) -> tuple[int, int]:
+    if offset + 8 > len(buf):
+        raise ValueError("truncated fixed64 field")
     return struct.unpack_from("<q", buf, offset)[0], offset + 8
 
 
@@ -102,6 +104,8 @@ def encode_fixed32(value: int) -> bytes:
 
 
 def decode_fixed32(buf: bytes, offset: int = 0) -> tuple[int, int]:
+    if offset + 4 > len(buf):
+        raise ValueError("truncated fixed32 field")
     return struct.unpack_from("<i", buf, offset)[0], offset + 4
 
 

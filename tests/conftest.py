@@ -8,6 +8,8 @@ imported.
 import os
 import sys
 
+import pytest
+
 # Hard assignment: unit tests run on the virtual CPU mesh whatever the
 # machine offers (a chip belongs to one process; the test session must
 # not claim it).
@@ -31,3 +33,15 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from tendermint_tpu.ops import enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
+
+
+@pytest.fixture
+def small_mesh_cache(monkeypatch):
+    """The sharded route's pubkey cache at 128 slots, made afresh: its
+    size on the chip (16384 slots, 256 MiB a device, filled at that many
+    rows) replicated over the virtual devices would hold gigabytes here
+    and build for minutes."""
+    from tendermint_tpu.parallel import sharded_verify
+
+    monkeypatch.setattr(sharded_verify, "CACHE_SLOTS", 128)
+    monkeypatch.setattr(sharded_verify, "_CACHES", {})

@@ -129,7 +129,7 @@ def test_single_verify_pubkey():
     assert len(priv.pub_key().address()) == 20
 
 
-def test_sharded_verify_8_devices():
+def test_sharded_verify_8_devices(small_mesh_cache):
     import jax
 
     from tendermint_tpu.parallel import sharded_verify as S
@@ -144,7 +144,7 @@ def test_sharded_verify_8_devices():
     assert all_valid2 and bitmap2.all()
 
 
-def test_sharded_verify_sr25519_8_devices():
+def test_sharded_verify_sr25519_8_devices(small_mesh_cache):
     """The sr25519 plane shards over the mesh exactly like ed25519:
     per-shard kernels, psum AND-reduce, fault localization."""
     from tendermint_tpu.crypto import sr25519 as sr
@@ -165,6 +165,23 @@ def test_sharded_verify_sr25519_8_devices():
     assert not bitmap[37] and bitmap.sum() == n - 1  # fault localized
 
 
+def test_multihost_entry_single_controller(small_mesh_cache):
+    """parallel.multihost: on a single controller the local entry is
+    exactly the sharded path, and initialize() is a safe no-op."""
+    import jax
+    from tendermint_tpu.parallel import multihost as mh
+    from tendermint_tpu.parallel import sharded_verify as sv
+
+    mh.initialize()  # no coordinator: no-op
+    mesh = mh.global_mesh()
+    assert mesh.devices.size == len(jax.devices())
+    pks, msgs, sigs = make_jobs(16, tamper_idx=(3,))
+    bm, ok = mh.verify_batch_sharded_local(mesh, pks, msgs, sigs)
+    bm2, ok2 = sv.verify_batch_sharded(mesh, pks, msgs, sigs)
+    assert [bool(b) for b in bm] == [bool(b) for b in bm2]
+    assert ok == ok2 == False  # noqa: E712
+
+
 def test_split_cached_plane_agrees_with_the_uncached_kernel_and_the_oracle():
     """The split-ladder cached kernel accepts exactly what the uncached
     verify_kernel and the pure-Python oracle accept: the same batch
@@ -181,54 +198,6 @@ def test_split_cached_plane_agrees_with_the_uncached_kernel_and_the_oracle():
     want = [ref.verify(p, m, s, zip215=True) for p, m, s in zip(pks, msgs, sigs)]
     assert [bool(b) for b in got_split] == [bool(b) for b in got_uncached] == want
     assert want == [True, False, True, True, True]
-
-
-def test_sharded_cached_matches_sharded_uncached():
-    """The replicated-cache sharded plane (verify_batch_sharded_cached)
-    and the uncached sharded plane agree, incl. fault localization and
-    the all-valid ICI verdict with padded rows (n=37 not divisible by
-    the mesh)."""
-    import jax
-    from tendermint_tpu.parallel import sharded_verify as sv
-
-    mesh = sv.make_mesh(len(jax.devices()))
-    n = 37
-    pks, msgs, sigs = make_jobs(n, tamper_idx=(5,))
-    bm_u, ok_u = sv.verify_batch_sharded(mesh, pks, msgs, sigs)
-    bm_c, ok_c = sv.verify_batch_sharded_cached(mesh, pks, msgs, sigs)
-    assert [bool(b) for b in bm_u] == [bool(b) for b in bm_c]
-    assert ok_u == ok_c == False  # noqa: E712
-    assert [i for i, b in enumerate(bm_c) if not b] == [5]
-    # all-valid verdict with padding: fix the tampered sig
-    pks2, msgs2, sigs2 = make_jobs(n)
-    bm_c2, ok_c2 = sv.verify_batch_sharded_cached(mesh, pks2, msgs2, sigs2)
-    assert ok_c2 and all(bool(b) for b in bm_c2)
-    # sr25519 plane rides the same path
-    from tendermint_tpu.crypto import sr25519 as sr
-
-    spriv = sr.Sr25519PrivKey.generate(b"\x05" * 32)
-    spk = spriv.pub_key().bytes()
-    smsgs = [b"shard-sr-%d" % i for i in range(10)]
-    ssigs = [spriv.sign(m) for m in smsgs]
-    bm_s, ok_s = sv.verify_batch_sharded_cached(mesh, [spk] * 10, smsgs, ssigs, key_type="sr25519")
-    assert ok_s and all(bool(b) for b in bm_s)
-
-
-def test_multihost_entry_single_controller():
-    """parallel.multihost: on a single controller the local entry is
-    exactly the sharded path, and initialize() is a safe no-op."""
-    import jax
-    from tendermint_tpu.parallel import multihost as mh
-    from tendermint_tpu.parallel import sharded_verify as sv
-
-    mh.initialize()  # no coordinator: no-op
-    mesh = mh.global_mesh()
-    assert mesh.devices.size == len(jax.devices())
-    pks, msgs, sigs = make_jobs(16, tamper_idx=(3,))
-    bm, ok = mh.verify_batch_sharded_local(mesh, pks, msgs, sigs)
-    bm2, ok2 = sv.verify_batch_sharded(mesh, pks, msgs, sigs)
-    assert [bool(b) for b in bm] == [bool(b) for b in bm2]
-    assert ok == ok2 == False  # noqa: E712
 
 
 def test_pubkey_cache_fill_does_not_block_hits():

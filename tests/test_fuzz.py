@@ -17,7 +17,7 @@ import pytest
 # skip of exactly this module.
 pytest.importorskip("hypothesis")
 
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 from tendermint_tpu.proto import wire
 from tendermint_tpu.proto import messages as pb
@@ -55,7 +55,21 @@ def test_wire_zigzag_roundtrip(v):
     assert dec == v and pos == len(enc)
 
 
+@pytest.mark.parametrize("decode,width,message", [
+    (wire.decode_fixed64, 8, "truncated fixed64 field"),
+    (wire.decode_fixed32, 4, "truncated fixed32 field"),
+])
+def test_wire_fixed_width_decoder_refuses_a_short_buffer(decode, width, message):
+    assert decode(bytes(range(1, width + 2)), 1) == (decode(bytes(range(2, width + 2)))[0], width + 1)
+    for n in range(width):
+        with pytest.raises(ValueError, match=message):
+            decode(bytes(n))
+        with pytest.raises(ValueError, match=message):
+            decode(bytes(width + 1), 2 + n)
+
+
 @given(_bytes)
+@example(b"\xc0>")  # field 1000 of ConsensusMessage, the fixed64 origin_ns, with no bytes after its tag
 @settings(max_examples=400, deadline=None)
 def test_proto_message_decoders_never_crash(data):
     """Arbitrary bytes against the heaviest message schemas: reject or

@@ -136,11 +136,16 @@ def test_proxy_proofs_batch_verifies_before_relaying(proxy, node, monkeypatch):
     from tendermint_tpu.rpc.core import multiproof_from_json
 
     # commit a burst of txs so ONE height carries a multi-leaf tree
-    # (the index-substitution case below needs >= 2 provable indices)
+    # (the index-substitution case below needs >= 2 provable indices):
+    # they are admitted under the mempool's lock, which the proposer's
+    # reap takes too, so that a reap sees all three or none
     direct = HTTPClient(f"http://{node.rpc_address[0]}:{node.rpc_address[1]}")
-    for i in range(3):
-        res = direct.call("broadcast_tx_sync", tx=f"lpk{i}=lpv{i}".encode().hex())
-        assert res["code"] == 0
+    node.mempool.lock()
+    try:
+        for i in range(3):
+            assert node.mempool.check_tx(f"lpk{i}=lpv{i}".encode()).is_ok
+    finally:
+        node.mempool.unlock()
     height = None
     deadline = time.monotonic() + 30
     while time.monotonic() < deadline and height is None:

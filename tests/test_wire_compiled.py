@@ -121,8 +121,12 @@ def _ref_decode_scalar(ftype: str, buf: bytes, pos: int):
         raw, pos = ref_decode_varint(buf, pos)
         return (raw >> 1) ^ -(raw & 1), pos
     if ftype in _FIXED64:
+        if pos + 8 > len(buf):
+            raise ValueError("truncated fixed64 field")
         return struct.unpack_from("<q", buf, pos)[0], pos + 8
     if ftype in _FIXED32:
+        if pos + 4 > len(buf):
+            raise ValueError("truncated fixed32 field")
         return struct.unpack_from("<i", buf, pos)[0], pos + 4
     if ftype == "bytes":
         return ref_decode_bytes(buf, pos)
