@@ -19,7 +19,7 @@ import time
 from .. import trace as _trace
 from ..p2p.types import CHANNEL_BLOCKSYNC, ChannelDescriptor, PEER_STATUS_UP, PeerError
 from ..proto import messages as pb
-from ..types.block import Block, BlockID
+from ..types.block import Block, BlockID, commit_reads
 from ..types.validation import verify_commit_light, verify_commit_light_async
 from ..types.validator_set import NotEnoughVotingPowerError
 from .pool import BlockPool
@@ -105,6 +105,14 @@ def decode_blocksync_msg(data: bytes):
         r = env.status_response
         return StatusResponse(r.base or 0, r.height or 0)
     raise ValueError(f"empty or unknown blocksync oneof: {kind}")
+
+
+def _annotate_reads(sp, since: tuple[int, int]) -> None:
+    """The commit reads (types/block.py commit_reads) this thread made
+    since `since`, on the span `sp`: `encoded` whole-commit encodes and
+    `kept` reads served from bytes already made."""
+    encoded, kept = commit_reads()
+    sp.annotate(encoded=encoded - since[0], kept=kept - since[1])
 
 
 def blocksync_channel_descriptor() -> ChannelDescriptor:
@@ -385,9 +393,11 @@ class BlockSyncReactor:
                 first_parts, first_id = ahead[4], ahead[5]  # reuse dispatch-time work
                 ahead[6]()  # completes the dispatched kernel; raises as sync would
             else:
-                with _trace.span("blocksync.parts", "blocksync", height=first.header.height):
+                with _trace.span("blocksync.parts", "blocksync", height=first.header.height) as sp:
+                    reads = commit_reads()
                     first_parts = first.make_part_set()
                     first_id = BlockID(hash=first.hash(), part_set_header=first_parts.header)
+                    _annotate_reads(sp, reads)
                 with _trace.span("blocksync.verify_commit", "blocksync",
                                  height=first.header.height):
                     verify_commit_light(
@@ -445,10 +455,12 @@ class BlockSyncReactor:
         # separate writes would leave a block whose restart
         # reconstruction (consensus/state.py) requires an EC that is
         # not there — a permanent halt.
-        with _trace.span("blocksync.save_block", "blocksync", height=height):
+        with _trace.span("blocksync.save_block", "blocksync", height=height) as sp:
+            reads = commit_reads()
             self.block_store.save_block(
                 first, first_parts, second.last_commit, extended_commit=ec
             )
+            _annotate_reads(sp, reads)
         with _trace.span("blocksync.apply", "blocksync", height=height):
             self.state = self.block_exec.apply_block(self.state, first_id, first)
         self.blocks_synced += 1
@@ -589,9 +601,11 @@ class BlockSyncReactor:
             next_vals = self.state.next_validators
             second_parts = second_id = None
             try:
-                with _trace.span("blocksync.parts", "blocksync", height=height):
+                with _trace.span("blocksync.parts", "blocksync", height=height) as sp:
+                    reads = commit_reads()
                     second_parts = second.make_part_set()
                     second_id = BlockID(hash=second.hash(), part_set_header=second_parts.header)
+                    _annotate_reads(sp, reads)
                 complete = verify_commit_light_async(
                     self.state.chain_id,
                     next_vals,

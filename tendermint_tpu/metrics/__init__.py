@@ -763,6 +763,12 @@ class EngineMetrics:
             "Jobs that entered the queue beside another in one call (submit_together)",
             labels=("plane",),
         )
+        self.coalesce_held_jobs = reg.counter(
+            f"{ns}_coalesce_held_jobs_total",
+            "Jobs kept out of a group they fit because it would launch at a row bucket "
+            "no program of the plane has run at in this process",
+            labels=("plane",),
+        )
         self.coalesced_group_size = reg.histogram(
             f"{ns}_coalesced_group_size",
             "Caller jobs merged per coalesced launch",

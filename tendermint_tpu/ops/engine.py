@@ -218,11 +218,14 @@ def _autotune_probe(dev_pinned: bool, msm_pinned: bool) -> None:
 class _Job:
     __slots__ = (
         "plane", "pks", "msgs", "sigs", "n", "event", "result", "error",
-        "flow", "span", "req", "t_submit", "journey",
+        "flow", "span", "req", "t_submit", "journey", "call",
     )
 
-    def __init__(self, plane, pks, msgs, sigs, journey=None):
+    def __init__(self, plane, pks, msgs, sigs, journey=None, call=None):
         self.plane = plane
+        # the submit_together call that queued it, shared by every job of
+        # that call (a token: a lone job is a call of its own)
+        self.call = object() if call is None else call
         self.pks = pks
         self.msgs = msgs
         self.sigs = sigs
@@ -434,11 +437,11 @@ class VerifyEngine:
         DEVICE_BATCH_CUTOVER may together pass it and launch. Every
         batch is checked before any is queued: a ragged or unknown one
         raises and nothing was submitted."""
-        jobs = []
+        jobs, call = [], object()
         for plane, pubkeys, msgs, sigs, journey in batches:
             if plane not in _HOST_VERIFY:
                 raise ValueError(f"unknown verification plane {plane!r}")
-            job = _Job(plane, list(pubkeys), list(msgs), list(sigs), journey=journey)
+            job = _Job(plane, list(pubkeys), list(msgs), list(sigs), journey=journey, call=call)
             if len(job.pks) != job.n or len(job.msgs) != job.n:
                 # ragged inputs would silently truncate in the verify
                 # planes' zip()s, reporting unverified tail rows as accepted
@@ -485,20 +488,58 @@ class VerifyEngine:
     # ------------------------------------------------------------ dispatch
 
     def _take_group(self):
-        """Pop a coalescable group: the oldest pending job plus every
-        other queued job on the same plane, up to MAX_COALESCE_ROWS.
-        Called with the lock held."""
-        first = self._pending.pop(0)
-        group, rows = [first], first.n
-        keep = []
+        """Pop a coalescable group: the oldest pending job plus queued
+        jobs on its plane, up to MAX_COALESCE_ROWS. The jobs of the
+        oldest job's submit_together call come along in order as far as
+        the cap allows. Another call's jobs (a lone submit is a call of
+        one) join all together or not at all, and only where the group
+        then loads no program (_loads_nothing); else they wait for a
+        later group at their own bucket. Returns (group, held), held the
+        jobs kept back for that alone. Called with the lock held."""
+        first = self._pending[0]
+        plane = first.plane
+        joined, rows = set(), 0
         for j in self._pending:
-            if j.plane == first.plane and rows + j.n <= MAX_COALESCE_ROWS:
-                group.append(j)
+            if j.call is not first.call or j.plane != plane:
+                continue
+            if not joined or rows + j.n <= MAX_COALESCE_ROWS:
+                joined.add(j)
                 rows += j.n
+        held, seen = 0, {first.call}
+        for j in self._pending:
+            if j.plane != plane or j.call in seen:
+                continue
+            seen.add(j.call)
+            call = [k for k in self._pending if k.call is j.call and k.plane == plane]
+            n = sum(k.n for k in call)
+            if rows + n > MAX_COALESCE_ROWS:
+                continue
+            if self._loads_nothing(plane, rows + n):
+                joined.update(call)
+                rows += n
             else:
-                keep.append(j)
-        self._pending = keep
-        return group
+                held += len(call)
+        group = [j for j in self._pending if j in joined]
+        self._pending = [j for j in self._pending if j not in joined]
+        return group, held
+
+    def _loads_nothing(self, plane: str, rows: int) -> bool:
+        """Whether a group of `rows` on `plane` launches without loading
+        a program: at a bucket some launch of the plane has already run
+        at in this process (ops/launched.py), or on a route that has no
+        bucket to load, the host's or the one split across the chips."""
+        from ..crypto import ed25519 as ed
+        from . import launched
+
+        if launched.used(plane, launched.pad_pow2(rows)):
+            return True
+        try:
+            if not (ed._use_device() and rows >= ed.DEVICE_BATCH_CUTOVER):
+                return True
+            mesh = self._split_mesh()
+        except Exception:  # noqa: BLE001 - the group's dispatch meets it and delivers it
+            return True
+        return mesh is not None and splits(mesh.devices.size, rows)
 
     def _dispatch_loop(self) -> None:
         while True:
@@ -506,9 +547,12 @@ class VerifyEngine:
             with self._lock:
                 while not self._pending:
                     self._have_jobs.wait()
-                with _trace.span("engine.coalesce", "engine"):
-                    group = self._take_group()
+                with _trace.span("engine.coalesce", "engine") as sp:
+                    group, held = self._take_group()
+                    sp.annotate(held=held)
                 m.queue_depth.set(len(self._pending))
+            if held:
+                m.coalesce_held_jobs.add(held, group[0].plane)
             rows = sum(j.n for j in group)
             t0 = _time.monotonic()
             # metric writes never raise (metrics._never_raise), so none

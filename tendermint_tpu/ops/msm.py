@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import curve as C
+from . import launched
 from .. import devobs as _devobs
 from .. import trace as _trace
 from ..metrics import engine_metrics as _engine_metrics
@@ -198,7 +199,7 @@ def verify_batch_rlc_sr_async(pubkeys, msgs, sigs, z_raw: bytes | None = None):
     the per-signature sr25519 bitmap kernel is the failure fallback)."""
     from . import verify_sr as VS
 
-    return _dispatch_rlc(VS.prepare_batch, msm_verify_sr_kernel, pubkeys, msgs, sigs, z_raw)
+    return _dispatch_rlc("sr25519", VS.prepare_batch, msm_verify_sr_kernel, pubkeys, msgs, sigs, z_raw)
 
 
 def _rlc_scalars_py(s_rows, k_rows, n, z_raw):
@@ -300,7 +301,7 @@ def _launch_rlc(kernel, rows, zs_row, n, fid):
             return kernel(*dev_args)
 
 
-def _dispatch_rlc(prepare, kernel, pubkeys, msgs, sigs, z_raw):
+def _dispatch_rlc(plane, prepare, kernel, pubkeys, msgs, sigs, z_raw):
     """Shared RLC dispatch for both signature planes: prep, precheck
     refusal (None -> caller goes straight to its bitmap plane, exactly
     like the reference's early return on AddWithError), randomizer
@@ -316,6 +317,7 @@ def _dispatch_rlc(prepare, kernel, pubkeys, msgs, sigs, z_raw):
         a_enc, r_enc, s_rows, k_rows = prepped
         zk, z_out, zs_row = _scalars_rlc(s_rows, k_rows, n, z_raw)
         handle = _launch_rlc(kernel, [a_enc, r_enc, zk, z_out], zs_row, n, fid)
+    launched.note(plane, _pad_pow2(n))
     _engine_metrics().kernel_launches.add(1, "rlc")
     return handle
 
@@ -323,7 +325,7 @@ def _dispatch_rlc(prepare, kernel, pubkeys, msgs, sigs, z_raw):
 def verify_batch_rlc_async(pubkeys, msgs, sigs, z_raw: bytes | None = None):
     """Dispatch the ed25519 RLC check without blocking. Returns an
     opaque handle for collect_rlc, or None on precheck refusal."""
-    return _dispatch_rlc(prepare_batch, msm_verify_kernel, pubkeys, msgs, sigs, z_raw)
+    return _dispatch_rlc("ed25519", prepare_batch, msm_verify_kernel, pubkeys, msgs, sigs, z_raw)
 
 
 def collect_rlc(dispatched) -> bool:

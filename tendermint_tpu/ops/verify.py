@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 
 from . import curve as C
+from . import launched
+from .launched import pad_pow2 as _pad_pow2
 from .. import devobs as _devobs
 from .. import trace as _trace
 from ..metrics import engine_metrics as _engine_metrics
@@ -156,6 +158,8 @@ class PubkeyCache:
         self._put = jnp.asarray if sharding is None else (
             lambda x: jax.device_put(x, sharding))
         self.plane = plane  # devobs compile-attribution + residency label
+        # the signature plane a fill's programs are noted under (ops/launched.py)
+        self.signature_plane = plane.removesuffix("_pk")
         self._build = build_fn or build_pk_tables_split  # sr25519 plugs in its decoder
         self._lock = threading.Lock()  # reactors verify concurrently
         self._lru: "collections.OrderedDict[bytes, int]" = collections.OrderedDict()
@@ -292,6 +296,7 @@ class PubkeyCache:
                 with _devobs.attribution(fn=f"{self.plane}_table_publish", rows=rows, flow=fid):
                     self.tables, self.oks = _publish_pk_tables(
                         self.tables, self.oks, idx_dev, new_tables, new_oks)
+                launched.note(self.signature_plane, rows)
                 for pk in missing:
                     if self._pending.get(pk) is event:
                         del self._pending[pk]
@@ -339,13 +344,6 @@ def pubkey_cache() -> PubkeyCache:
     if _PK_CACHE is None:
         _PK_CACHE = PubkeyCache(plane="ed25519_pk")
     return _PK_CACHE
-
-
-def _pad_pow2(n: int, floor: int = 8) -> int:
-    size = floor
-    while size < n:
-        size *= 2
-    return size
 
 
 def pad_pow2_rows(arrays, n: int):
@@ -460,6 +458,7 @@ def verify_batch_async(pubkeys, msgs, sigs):
                 )
             with _devobs.attribution(fn="ed25519_bitmap", rows=padded, flow=fid):
                 ok_dev = verify_kernel(a_dev, r_dev, s_dev, k_dev)
+    launched.note("ed25519", padded)
     _engine_metrics().kernel_launches.add(1, "bitmap")
     return ok_dev, precheck, n, fid
 
@@ -525,6 +524,7 @@ def dispatch_cached(cache, prepare, cached_kernel, uncached_async, pubkeys, msgs
                 )
             with _devobs.attribution(fn=fn_label, rows=padded, flow=fid):
                 ok_dev = cached_kernel(tables, oks, slots_dev, r_dev, s_dev, k_dev)
+    launched.note(cache.signature_plane, padded)
     _engine_metrics().kernel_launches.add(1, "bitmap_cached")
     return ok_dev, precheck, n, fid
 

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from . import curve as C
+from . import launched
 from . import ristretto as R
 
 L = 2**252 + 27742317777372353535851937790883648493
@@ -147,6 +148,7 @@ def verify_batch_async(pubkeys, msgs, sigs):
         )
     with _devobs.attribution(fn="sr25519_bitmap", rows=_pad_pow2(n), flow=fid):
         ok_dev = verify_sr_kernel(a_dev, r_dev, s_dev, k_dev)
+    launched.note("sr25519", _pad_pow2(n))
     return ok_dev, precheck, n, fid
 
 

@@ -97,7 +97,9 @@ class BlockStore:
         part-gossip straight from disk. extended_commit (pb.ExtendedCommit,
         from VoteSet.make_extended_commit so its block_id is the maj23
         block) is written in the SAME batch so a crash cannot separate
-        the block from its extended commit."""
+        the block from its extended commit. The commits are written from
+        their kept bytes (Commit.encode), and the block's size is read
+        from the parts where the block made them (Block.encoded_size)."""
         if block is None:
             raise ValueError("BlockStore can only save a non-nil block")
         height = block.header.height
@@ -109,7 +111,7 @@ class BlockStore:
             block_id = BlockID(hash=block.hash(), part_set_header=part_set.header)
             meta = BlockMeta(
                 block_id=block_id,
-                block_size=len(block.to_proto().encode()),
+                block_size=block.encoded_size(part_set),
                 header=block.header,
                 num_txs=len(block.txs),
             )
@@ -119,8 +121,8 @@ class BlockStore:
             for i in range(part_set.total()):
                 part = part_set.get_part(i)
                 batch.set(_h(KEY_PART, height) + b":" + i.to_bytes(4, "big"), part.to_proto().encode())
-            batch.set(_h(KEY_COMMIT, height - 1), block.last_commit.to_proto().encode() if block.last_commit else b"")
-            batch.set(_h(KEY_SEEN_COMMIT, height), seen_commit.to_proto().encode())
+            batch.set(_h(KEY_COMMIT, height - 1), block.last_commit.encode() if block.last_commit else b"")
+            batch.set(_h(KEY_SEEN_COMMIT, height), seen_commit.encode())
             if extended_commit is not None:
                 batch.set(_h(KEY_EXT_COMMIT, height), extended_commit.encode())
             batch.write()
@@ -147,7 +149,7 @@ class BlockStore:
 
     def save_seen_commit(self, height: int, seen_commit: Commit) -> None:
         with self._mu:
-            self._db.set(_h(KEY_SEEN_COMMIT, height), seen_commit.to_proto().encode())
+            self._db.set(_h(KEY_SEEN_COMMIT, height), seen_commit.encode())
 
     # -------------------------------------------------------------- reads
 

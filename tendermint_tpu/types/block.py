@@ -10,7 +10,9 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import threading
 from dataclasses import dataclass, field
+from operator import attrgetter
 
 from ..crypto.merkle import hash_from_byte_slices, sha256_batch
 from ..metrics import hash_metrics
@@ -29,6 +31,19 @@ MAX_HEADER_BYTES = 626
 BLOCK_ID_FLAG_ABSENT = pb.BLOCK_ID_FLAG_ABSENT
 BLOCK_ID_FLAG_COMMIT = pb.BLOCK_ID_FLAG_COMMIT
 BLOCK_ID_FLAG_NIL = pb.BLOCK_ID_FLAG_NIL
+
+# Field tags of the messages a commit is encoded from by hand (CommitSig.encode,
+# Commit._encoding, Block.encode): pb.Timestamp, pb.CommitSig, pb.Commit, pb.Block.
+_TS_SECONDS = wire.encode_tag(1, wire.WIRE_VARINT)
+_TS_NANOS = wire.encode_tag(2, wire.WIRE_VARINT)
+_SIG_FLAG = wire.encode_tag(1, wire.WIRE_VARINT)
+_SIG_ADDRESS = wire.encode_tag(2, wire.WIRE_BYTES)
+_SIG_TIMESTAMP = wire.encode_tag(3, wire.WIRE_BYTES)
+_SIG_SIGNATURE = wire.encode_tag(4, wire.WIRE_BYTES)
+_COMMIT_SIGNATURE = wire.encode_tag(4, wire.WIRE_BYTES)
+_BLOCK_LAST_COMMIT = wire.encode_tag(4, wire.WIRE_BYTES)
+_uvarint = wire.encode_varint
+_ONE_BYTE = wire.ONE_BYTE
 
 
 def cdc_encode(item) -> bytes:
@@ -62,6 +77,25 @@ def txs_hash(txs: list[bytes]) -> bytes:
     stages run on the batched plane: one native call hashes every tx,
     a second merkles the digests."""
     return hash_from_byte_slices(sha256_batch(txs), site="txs")
+
+
+class _CommitReads(threading.local):
+    """This thread's reads of commits' bytes (Commit._encoding): `encoded`
+    the reads that encoded a whole commit, `kept` those served from bytes
+    already made. A span counts what it wraps as a difference of two
+    commit_reads()."""
+
+    encoded = 0
+    kept = 0
+
+
+_READS = _CommitReads()
+_KEPT_LEAF = attrgetter("_enc")
+
+
+def commit_reads() -> tuple[int, int]:
+    """(encoded, kept): this thread's commit reads so far (_CommitReads)."""
+    return _READS.encoded, _READS.kept
 
 
 def validate_hash(h: bytes) -> None:
@@ -273,6 +307,42 @@ class CommitSig:
     timestamp: Time = field(default_factory=Time)
     signature: bytes = b""
 
+    # Its wire bytes, `to_proto().encode()`: the merkle leaf Commit.hash
+    # takes, kept by the commit's one pass (Commit._encoding). A class
+    # attribute, not a field; every field write clears it (__setattr__),
+    # as Header's _hash_cache, so a slot changed in place is encoded anew.
+    _enc = None
+
+    def __init__(self, block_id_flag: int = BLOCK_ID_FLAG_ABSENT, validator_address: bytes = b"",
+                 timestamp: Time | None = None, signature: bytes = b""):
+        # straight into the instance dict: a commit decodes thousands of
+        # these, and nothing is kept yet that a write would have to clear
+        d = self.__dict__
+        d["block_id_flag"] = block_id_flag
+        d["validator_address"] = validator_address
+        d["timestamp"] = Time() if timestamp is None else timestamp
+        d["signature"] = signature
+
+    def __setattr__(self, name, value):
+        if name != "_enc":
+            object.__setattr__(self, "_enc", None)
+        object.__setattr__(self, name, value)
+
+    def encode(self) -> bytes:
+        """`to_proto().encode()` with no `pb` message made: fields 1-4 in
+        order, a zero flag and empty bytes left out as the codec leaves
+        them, the timestamp always emitted."""
+        ts = self.timestamp
+        t = ((_TS_SECONDS + _uvarint(ts.seconds) if ts.seconds else b"")
+             + (_TS_NANOS + _uvarint(ts.nanos) if ts.nanos else b""))
+        flag, addr, sig = self.block_id_flag, self.validator_address, self.signature
+        return b"".join((
+            _SIG_FLAG + _uvarint(flag) if flag else b"",
+            _SIG_ADDRESS + _uvarint(len(addr)) + addr if addr else b"",
+            _SIG_TIMESTAMP + _uvarint(len(t)) + t,
+            _SIG_SIGNATURE + _uvarint(len(sig)) + sig if sig else b"",
+        ))
+
     @classmethod
     def new_absent(cls) -> "CommitSig":
         return cls()
@@ -341,20 +411,26 @@ class Commit:
     round: int = 0
     block_id: BlockID = field(default_factory=BlockID)
     signatures: list[CommitSig] = field(default_factory=list)
-    # Guarded memo of hash(): (signatures list identity, length, root).
-    # Unlike ValidatorSet (invalidator contract) and Header (__setattr__
-    # clears), Commit's fields are mutated only by EXTERNAL code — so
-    # the memo re-checks its inputs on every read (the Validator.bytes
-    # discipline): replacing or resizing `signatures` can never serve a
-    # stale root. In-place mutation of an individual CommitSig still
-    # bypasses the guard (nothing in-tree does that; pinned by
-    # test_hash_cache).
-    _hash: tuple | None = field(default=None, compare=False, repr=False)
     # ((chain_id, height, round, block_id), make_commit, make_nil)
     # sign-bytes template cache — everything but the timestamp is
     # commit-invariant, and the guard re-checks every baked-in input so
     # a mutated commit re-templates instead of signing for stale fields
     _sb_tmpl: tuple | None = field(default=None, compare=False, repr=False)
+
+    # The kept encoding, [leaves, bytes, root]: each slot's wire bytes
+    # (the merkle leaves), `to_proto().encode()`, and the leaves' merkle
+    # root once hash() has asked for it. Made in one pass by _encoding
+    # and read by encode(), hash(), Block.encode and the block store. A
+    # class attribute, not a field: a write to any field clears it
+    # (__setattr__), and every read checks the leaves against the slots'
+    # own kept bytes (CommitSig._enc, cleared by any write to a slot), so
+    # a list changed in place or a slot changed in place is encoded anew.
+    _kept = None
+
+    def __setattr__(self, name, value):
+        if name != "_kept" and name != "_sb_tmpl":
+            object.__setattr__(self, "_kept", None)
+        object.__setattr__(self, name, value)
 
     def size(self) -> int:
         return len(self.signatures)
@@ -407,18 +483,51 @@ class Commit:
             raise ValueError(f"unknown BlockIDFlag: {cs.block_id_flag}")
         return make(cs.timestamp.seconds, cs.timestamp.nanos)
 
+    def _encoding(self) -> list:
+        """The kept encoding (_kept), made here in one pass where it is
+        missing or stale: each slot's bytes (a slot's own kept bytes where
+        it has them), then the commit's bytes framed around them. One read
+        of the commit's bytes, counted as `kept` or `encoded` on this
+        thread (commit_reads) and as a `commit_bytes` hit or miss."""
+        sigs = self.signatures
+        leaves = list(map(_KEPT_LEAF, sigs))
+        kept = self._kept
+        if kept is not None and leaves == kept[0]:
+            _READS.kept += 1
+            hash_metrics().cache_events.add(1, "commit_bytes", "hit")
+            return kept
+        parts = [pb.Commit(height=self.height, round=self.round,
+                           block_id=self.block_id.to_proto()).encode()]
+        add = parts.append
+        for i, leaf in enumerate(leaves):
+            if leaf is None:
+                cs = sigs[i]
+                # tmcheck: ok[shared-mutation] idempotent lazy memo: racing encodes store equal bytes, and any field write clears it
+                leaf = leaves[i] = cs._enc = cs.encode()
+            n = len(leaf)
+            add(_COMMIT_SIGNATURE)
+            add(_ONE_BYTE[n] if n < 128 else _uvarint(n))
+            add(leaf)
+        # tmcheck: ok[shared-mutation] idempotent lazy memo: racing encodes store equal bytes, and every read re-checks the leaves
+        kept = self._kept = [leaves, b"".join(parts), None]
+        _READS.encoded += 1
+        hash_metrics().cache_events.add(1, "commit_bytes", "miss")
+        return kept
+
+    def encode(self) -> bytes:
+        """`to_proto().encode()`, kept (_encoding): made once, with the
+        merkle leaves, however often it is read."""
+        return self._encoding()[1]
+
     def hash(self) -> bytes:
-        """Merkle root of CommitSig encodings (ref: types/block.go:900).
-        Guarded memo: served only while `signatures` is the same list
-        at the same length (see _hash above)."""
-        c = self._hash
-        if c is not None and c[0] is self.signatures and c[1] == len(self.signatures):
+        """Merkle root of CommitSig encodings (ref: types/block.go:900),
+        built from the kept leaves and kept beside them (_encoding)."""
+        kept = self._encoding()
+        root = kept[2]
+        if root is not None:
             hash_metrics().cache_events.add(1, "commit", "hit")
-            return c[2]
-        root = hash_from_byte_slices(
-            [cs.to_proto().encode() for cs in self.signatures], site="commit"
-        )
-        self._hash = (self.signatures, len(self.signatures), root)
+            return root
+        root = kept[2] = hash_from_byte_slices(kept[0], site="commit")
         hash_metrics().cache_events.add(1, "commit", "miss")
         return root
 
@@ -541,20 +650,43 @@ class Block:
     def make_part_set(self, part_size: int = BLOCK_PART_SIZE_BYTES):
         from .part_set import PartSet
 
-        return PartSet.from_data(self.encode(), part_size)
+        ps = PartSet.from_data(self.encode(), part_size)
+        ps.source_block = self
+        return ps
+
+    def encoded_size(self, part_set) -> int:
+        """len(encode()). Read from `part_set` where this block made it
+        (make_part_set): the parts hold these bytes, the last commit's
+        among them, and the read counts as one of the commit's bytes
+        served kept (commit_reads). Else the block is encoded."""
+        if part_set.source_block is self:
+            if self.last_commit is not None:
+                _READS.kept += 1
+            return part_set.byte_size
+        return len(self.encode())
 
     def encode(self) -> bytes:
-        return self.to_proto().encode()
+        """`to_proto().encode()`, with the last commit's kept bytes
+        (Commit.encode) as field 4 in place of encoding it again."""
+        self.fill_header()
+        head = self._proto(None).encode()
+        if self.last_commit is None:
+            return head
+        commit = self.last_commit.encode()
+        return b"".join((head, _BLOCK_LAST_COMMIT, _uvarint(len(commit)), commit))
 
     def to_proto(self) -> pb.Block:
+        self.fill_header()
+        return self._proto(self.last_commit.to_proto() if self.last_commit else None)
+
+    def _proto(self, last_commit: pb.Commit | None) -> pb.Block:
         from .evidence import evidence_to_proto
 
-        self.fill_header()
         return pb.Block(
             header=self.header.to_proto(),
             data=pb.Data(txs=list(self.txs)),
             evidence=pb.EvidenceList(evidence=[evidence_to_proto(e) for e in self.evidence]),
-            last_commit=self.last_commit.to_proto() if self.last_commit else None,
+            last_commit=last_commit,
         )
 
     @classmethod

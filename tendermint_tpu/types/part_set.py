@@ -52,6 +52,9 @@ class PartSet:
         self.parts: list[Part | None] = [None] * header.total
         self.count = 0
         self.byte_size = 0
+        # the Block whose encoding these parts are, where that block made
+        # them (Block.make_part_set); None for parts that arrived
+        self.source_block = None
 
     @classmethod
     def from_data(cls, data: bytes, part_size: int) -> "PartSet":

@@ -49,8 +49,8 @@ def test_take_group_coalesces_same_plane_in_order():
         E._Job("ed25519", [b"c"] * 3, [b"m"] * 3, [b"s"] * 3),
     ]
     eng._pending = list(jobs)
-    group = eng._take_group()
-    assert group == [jobs[0], jobs[2]]
+    group, held = eng._take_group()
+    assert group == [jobs[0], jobs[2]] and held == 0
     assert eng._pending == [jobs[1]]
 
 
@@ -59,8 +59,8 @@ def test_take_group_respects_row_cap(monkeypatch):
     eng = E.VerifyEngine()
     jobs = [E._Job("ed25519", [b"x"] * 3, [b"m"] * 3, [b"s"] * 3) for _ in range(3)]
     eng._pending = list(jobs)
-    group = eng._take_group()
-    assert group == [jobs[0]]  # 3 + 3 > 4: second job waits
+    group, held = eng._take_group()
+    assert group == [jobs[0]] and held == 0  # 3 + 3 > 4: second job waits
     assert eng._pending == [jobs[1], jobs[2]]
 
 
@@ -787,3 +787,66 @@ def test_engine_trace_and_telemetry_integration(monkeypatch):
         "tendermint_engine_host_pool_busy_seconds_total",
     ):
         assert series in text, f"{series} missing from engine telemetry"
+
+
+# --------------------------------------- coalescing into a bucket no program has run at
+
+
+@pytest.mark.parametrize("case,groups,held", [
+    # apart, and no launch of the plane has run at 2048 rows: two groups at 1024
+    ("apart", [[667], [1000]], 1),
+    # apart, after a launch at 2048 rows: one group there
+    ("apart_after_2048", [[667, 1000]], 0),
+    # in one submit_together call: one group whatever has run
+    ("together", [[667, 1000]], 0),
+], ids=["apart", "apart_after_2048", "together"])
+def test_jobs_queued_apart_join_only_at_a_bucket_already_run(monkeypatch, case, groups, held):
+    """The verify-ahead's 667-row proof and the full check's 1000 rows,
+    queued while the dispatch thread was held: joined, they would launch
+    at 2048 rows and load that program inside the caller's wait
+    (ops/launched.py, VerifyEngine._take_group)."""
+    from tendermint_tpu import trace as T
+    from tendermint_tpu.metrics import engine_metrics
+    from tendermint_tpu.ops import launched
+
+    monkeypatch.setattr(launched, "_ROWS", {})
+    monkeypatch.setenv("TM_TPU_CRYPTO", "on")
+    monkeypatch.setattr(ed, "DEVICE_BATCH_CUTOVER", 64)
+    monkeypatch.setattr(ed, "MSM_BATCH_CUTOVER", 1 << 30)
+    seen = []
+
+    def dispatch(self, group, seq=0):  # the route's bookkeeping, with no kernel
+        rows = sum(j.n for j in group)
+        seen.append(([j.n for j in group], launched.pad_pow2(rows)))
+        launched.note(group[0].plane, launched.pad_pow2(rows))
+        return (lambda: [True] * rows), "bitmap"
+
+    monkeypatch.setattr(E.VerifyEngine, "_dispatch_group", dispatch)
+    if case == "apart_after_2048":
+        launched.note("ed25519", 2048)
+    m = engine_metrics()
+    before = _counter_value(m.coalesce_held_jobs)
+    batches = [("ed25519", [b"k"] * n, [b"m"] * n, [b"s"] * n, None) for n in (667, 1000)]
+    eng = E.VerifyEngine()
+    eng._started = True  # the dispatch thread held: both jobs queue before it looks
+    if case == "together":
+        handles = eng.submit_together(batches)
+    else:
+        handles = [eng.submit_together([b])[0] for b in batches]
+    assert [j.n for j in eng._pending] == [667, 1000]
+    was = T.enabled()
+    T.set_enabled(True)
+    T.clear()
+    try:
+        eng._started = False
+        eng._ensure_started()
+        assert [h.result(timeout=60) for h in handles] == [[True] * 667, [True] * 1000]
+        coalesce = [e["args"] for e in T.export()["traceEvents"]
+                    if e.get("ph") == "X" and e["name"] == "engine.coalesce"]
+    finally:
+        T.set_enabled(was)
+        T.clear()
+    assert [g for g, _ in seen] == groups
+    assert [b for _, b in seen] == ([1024, 1024] if held else [2048])
+    assert coalesce[0]["held"] == held
+    assert _counter_value(m.coalesce_held_jobs) == before + held
